@@ -72,11 +72,15 @@ _DEVICE_CHILD = textwrap.dedent("""
 
 
 def _device_rows() -> list[tuple]:
-    """Sharded-engine sweep, one subprocess per simulated device count."""
+    """Sharded-engine sweep, one subprocess per simulated device count.
+
+    The children simulate CPU devices and are pinned to the CPU: on a chip
+    host the parent already holds the accelerator, and a child reaching
+    for it would fail or hang."""
     rows = []
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     for d in DEVICE_COUNTS:
-        env = dict(os.environ,
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
                    XLA_FLAGS=f"--xla_force_host_platform_device_count={d}",
                    PYTHONPATH=os.path.join(root, "src"))
         r = subprocess.run([sys.executable, "-c", _DEVICE_CHILD, str(d),

@@ -17,17 +17,21 @@ microseconds per call for both paths and their jaxpr dispatch counts
 (``roofline/analysis.dispatch_count`` — the fused path is a single
 ``pallas_call`` where the chain is dozens of primitives).  On CPU the
 fused timing is the *interpreter's* (orders of magnitude slower — the
-win this benchmark audits is dispatches and bytes, not CPU wall time);
-the tok/s comparison under the production dispatch lives in
+win this benchmark audits is dispatches and bytes, not CPU wall time), and
+the interpreter is used only when asked for with
+``REPRO_KERNEL_IMPL=interpret``; on a ref backend the kernel cells refuse
+to run.  The tok/s comparison under the production dispatch lives in
 ``serve_throughput.py``.
 
 ``--smoke`` additionally runs the engine-level gates CI pins in both
 kernel modes: fused decode tokens (``REPRO_FUSED_DECODE=on``) bit-identical
-to unfused (``off``) on a float32 smoke engine, and — outside the
-interpret CI mode — the fused whole-decode-step dispatch count strictly
-below the unfused one.
+to unfused (``off``) on a float32 smoke engine, and — where the decode
+step runs the compiled kernels — the fused whole-decode-step dispatch count
+strictly below the unfused one.
 
-Run:  PYTHONPATH=src python benchmarks/paged_decode_bench.py [--smoke]
+Run:  REPRO_KERNEL_IMPL=interpret PYTHONPATH=src \
+          python benchmarks/paged_decode_bench.py [--smoke]   # CPU
+      PYTHONPATH=src python benchmarks/paged_decode_bench.py   # TPU
 """
 
 from __future__ import annotations
@@ -82,14 +86,19 @@ def _bench_cell(bs: int, slots: int, kv_dtype: str, quiet: bool):
 
     import jax
     import jax.numpy as jnp
-    from repro.kernels import paged_attn, ref
+    from repro.kernels import ops, paged_attn, ref
     from repro.roofline import analysis
 
     q, ck, cv, table, pos, scale, out_scale = _make_case(bs, slots, kv_dtype)
-    interpret = jax.default_backend() != "tpu"
+    impl = ops._resolve("auto")
+    if impl == "ref":
+        raise RuntimeError(
+            "the fused kernel runs compiled on a TPU or, with "
+            "REPRO_KERNEL_IMPL=interpret, in the Pallas interpreter; this "
+            "backend resolves to the ref oracle, so there is no kernel to time")
     fused = functools.partial(paged_attn.paged_decode_attention,
                               window=0, scale=scale, out_scale=out_scale,
-                              interpret=interpret)
+                              interpret=(impl == "interpret"))
     unfused = jax.jit(functools.partial(ref.paged_decode, window=0,
                                         scale=scale, out_scale=out_scale))
     out_f = np.asarray(fused(q, ck, cv, table, pos))
@@ -152,6 +161,7 @@ def _run_smoke_engine(fused: str):
 
 
 def _smoke_gates() -> None:
+    from repro.kernels import ops
     from repro.roofline import report
 
     toks_on, audit_on = _run_smoke_engine("on")
@@ -161,7 +171,7 @@ def _smoke_gates() -> None:
     print(report.serve_decode_header())
     print(report.serve_decode_row("decode/fused", audit_on))
     print(report.serve_decode_row("decode/unfused", audit_off))
-    if os.environ.get("REPRO_KERNEL_IMPL", "") != "interpret":
+    if ops._resolve("auto") == "pallas":
         assert audit_on["dispatches"] < audit_off["dispatches"], (
             f"fused decode step dispatches ({audit_on['dispatches']}) not "
             f"below unfused ({audit_off['dispatches']})")

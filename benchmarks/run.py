@@ -22,6 +22,7 @@ from benchmarks import (bank_scaling, fig4_functional, fig5_montecarlo,
                         fig6_xnornet, incremental_verify, paged_decode_bench,
                         roofline_bench, serve_replicated, serve_throughput,
                         serve_workloads, table1_latency, verify_throughput)
+from repro.launch import compile_cache
 
 SUITES = [
     ("fig4", fig4_functional),
@@ -68,6 +69,7 @@ def _json_rows(tag: str, name: str, us: float, derived, commit: str) -> list:
 
 
 def main(argv=None) -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", default="",
                     help="also write records to this JSON file "

@@ -35,34 +35,16 @@ from typing import NamedTuple
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax._src import core as jax_core
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import bitpack, cim
 from repro.kernels import ops
 
 
-def _under_trace(operands) -> bool:
-    """True when the caller is being traced (jit/vmap/...).
-
-    ``trace_state_clean`` is the precise check but lives in private jax
-    namespaces that move across releases; try its known homes, then fall
-    back to sniffing the operands for tracers.  The fallback misses ops
-    traced purely through closed-over constants — those account once at
-    trace time, which is also what the constant-folded op costs.
-    """
-    try:
-        return not jax.core.trace_state_clean()
-    except AttributeError:
-        pass
-    try:
-        from jax._src import core as _src_core
-        return not _src_core.trace_state_clean()
-    except Exception:
-        pass
-    try:
-        return any(isinstance(b, jax.core.Tracer) for b in operands)
-    except AttributeError:
-        return False
+def _under_trace() -> bool:
+    """True when the caller is being traced (jit/vmap/...)."""
+    return not jax_core.trace_state_clean()
 
 
 class BankGeometry(NamedTuple):
@@ -146,7 +128,7 @@ class CimEngine:
         return -(-nbits // self.geometry.bits_per_cycle)
 
     def _account_raw(self, cycles: int, bit_ops: int,
-                     *operands: jnp.ndarray, op: str = "bulk") -> None:
+                     op: str = "bulk") -> None:
         """Record stats exactly once per *execution*, not per trace.
 
         Cycle/op counts derive from static shapes, so they are known at
@@ -156,7 +138,7 @@ class CimEngine:
         compiled function instead (call :func:`jax.effects_barrier` before
         reading stats that jitted calls produced).
         """
-        if _under_trace(operands):
+        if _under_trace():
             jax.debug.callback(
                 lambda: self.stats.account(cycles, bit_ops, op))
         else:
@@ -164,7 +146,7 @@ class CimEngine:
 
     def _account(self, *buffers: jnp.ndarray, op: str = "bulk") -> None:
         nbits = max(b.size * b.dtype.itemsize * 8 for b in buffers)
-        self._account_raw(self.cycles_for(nbits), nbits, *buffers, op=op)
+        self._account_raw(self.cycles_for(nbits), nbits, op=op)
 
     # -- engine path: packed uint32 buffers ----------------------------------
 
@@ -335,7 +317,7 @@ class CimEngine:
         state = cim.make_array(cells)
         row_a = 2 * jnp.arange(pairs)
         out = cim.compute(state, row_a, row_a + 1, op)     # (banks, P, C)
-        self._account_raw(pairs, n * c, bits_a, op="simulate")
+        self._account_raw(pairs, n * c, op="simulate")
         return out.reshape(banks * pairs, c)[:n]
 
 
@@ -383,13 +365,15 @@ class ShardedCimEngine(CimEngine):
         and the digest width for digests) so per-device row blocks line up
         with the unsharded layout; chunks are contiguous, so device ``d``
         holds global words ``[d*per, (d+1)*per)`` — the slice the cipher's
-        counter offset and the output un-pad below rely on.
+        counter offset and the output un-pad below rely on.  The fold is
+        placed on the mesh explicitly: a buffer committed to one device
+        (a serve replica's resident params) is not moved implicitly.
         """
         n = words.shape[0]
         dev = self.geometry.devices
         per = -(-max(n, 1) // (dev * align)) * align
         w2 = jnp.pad(words, (0, dev * per - n)).reshape(dev, per)
-        return w2, n
+        return jax.device_put(w2, NamedSharding(self.mesh, P(self.axis))), n
 
     def _sharded(self, key, build):
         """Cache shard_map-wrapped jitted callables per (op, static args)."""

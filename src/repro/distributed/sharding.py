@@ -21,22 +21,11 @@ DEFAULT_RULES = {"fsdp": "data", "tp": "model", "ep": "model"}
 
 
 def shard_map(f, mesh: Mesh, in_specs, out_specs, manual_axes: set[str]):
-    """Version-portable shard_map, manual over ``manual_axes`` only.
-
-    Newer jax spells this ``jax.shard_map(..., axis_names=manual_axes,
-    check_vma=False)``; jax 0.4.x spells it ``auto = mesh axes - manual``.
-    """
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs,
-                                 axis_names=set(manual_axes), check_vma=False)
-        except TypeError:  # mid-window jax: top-level symbol, old kwargs
-            pass
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False,
-                      auto=frozenset(mesh.axis_names) - set(manual_axes))
+    """``jax.shard_map`` manual over ``manual_axes`` only (the other mesh
+    axes stay automatic)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=set(manual_axes),
+                         check_vma=False)
 
 
 def pxor(x: jax.Array, axis_name: str) -> jax.Array:

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
 from repro.kernels import online_softmax as osm
 
 NEG_INF = osm.NEG_INF
@@ -95,7 +94,7 @@ def flash_attention(q, k, v, *, causal: bool = True, bq: int = 256,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, dh), jnp.float32),
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

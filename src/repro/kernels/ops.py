@@ -20,6 +20,7 @@ from repro.core import bitpack
 from repro.kernels import bulk as _bulk
 from repro.kernels import cipher as _cipher
 from repro.kernels import pack as _pack
+from repro.kernels import paged_attn as _paged_attn
 from repro.kernels import parity as _parity
 from repro.kernels import ref
 from repro.kernels import xnor_gemm as _xnor_gemm
@@ -69,6 +70,10 @@ def _pad_rows(x: jnp.ndarray, mult: int) -> jnp.ndarray:
     return jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1)) if pad else x
 
 
+def _round8(n: int) -> int:
+    return -(-n // 8) * 8
+
+
 def _pad_cols(x: jnp.ndarray, mult: int, value=0) -> jnp.ndarray:
     pad = (-x.shape[-1]) % mult
     if not pad:
@@ -92,16 +97,19 @@ def xnor_matmul(pa: jnp.ndarray, pb: jnp.ndarray, valid_k: int,
     impl = _resolve(impl)
     if impl == "ref":
         return ref.xnor_gemm(pa, pb, valid_k)
-    bm = blocks.get("bm", 128)
-    bn = blocks.get("bn", 128)
-    bk = blocks.get("bk", 64)
     m, n = pa.shape[0], pb.shape[0]
+    # row tiles never exceed the 8-row-rounded operand (a decode batch of 8
+    # is not padded to 128 rows); bk defaults to one 128-lane tile
+    bm = min(blocks.get("bm", 128), _round8(m))
+    bn = min(blocks.get("bn", 128), _round8(n))
+    bk = blocks.get("bk", 128)
     pa2, pb2 = _pad_rows(pa, bm), _pad_rows(pb, bn)
     kw = pa2.shape[1]
     # pad kw up to a multiple of bk rather than collapsing the tile to bk=1
-    # on non-divisible packed widths (e.g. kw=96 with bk=64): pad words are
-    # zero in both operands and the kpad-valid_k correction below removes
-    # their bias exactly, so the grid stays ceil(kw/bk) steps.
+    # on non-divisible packed widths (e.g. kw=160 with bk=128): pad words
+    # are zero in both operands and the kpad-valid_k correction below
+    # removes their bias exactly, so the grid stays ceil(kw/bk) steps.  A
+    # narrower operand takes bk = kw, the full (lane-legal) width.
     bk = min(bk, kw)
     pa2, pb2 = _pad_cols(pa2, bk), _pad_cols(pb2, bk)
     # pad words are 0 in both operands => popcount contribution 0; the
@@ -154,7 +162,7 @@ def xnor_linear_fused(x: jnp.ndarray, pb: jnp.ndarray, beta: jnp.ndarray,
     # column pads are 0.0: they pack to 1-bits, matching pb's word-tail pad
     # bits, so the kernel's valid_k accounting stays exact (see _fused_kernel)
     xp = _pad_cols(x, bitpack.WORD)
-    bm, bn = min(bm, m), min(bn, n)
+    bm, bn = min(bm, _round8(m)), min(bn, _round8(n))
     xp, pb2 = _pad_rows(xp, bm), _pad_rows(pb, bn)
     beta2 = jnp.pad(beta, (0, pb2.shape[0] - n))
     out = _xnor_gemm.xnor_linear_fused(xp, pb2, beta2, valid_k=valid_k,
@@ -175,11 +183,29 @@ def digest(buf: jnp.ndarray, digest_width: int = 128, impl: str = "auto",
         return ref.parity_digest(words, digest_width)
     # pad rows rather than shrink the tile (zero rows are XOR-neutral for the
     # fold): shrinking to br=1 on non-divisible row counts explodes the grid
-    # to one row per step.
-    br = min(br, words.shape[0])
+    # to one row per step.  The kernel's halving fold needs a power of two.
+    br = min(br, 1 << (words.shape[0] - 1).bit_length())
     words = _pad_rows(words, br)
     return _parity.parity_digest(words, digest_width=digest_width, br=br,
                                  interpret=(impl == "interpret"))
+
+
+def paged_decode(q, ck, cv, table, pos, *, window: int = 0,
+                 scale: float = 1.0, out_scale: float = 1.0,
+                 impl: str = "auto") -> jnp.ndarray:
+    """Fused block-paged decode attention (DESIGN.md §18).
+
+    ``impl="ref"`` runs the one-shot oracle :func:`ref.paged_decode`; the
+    kernel is interpreted only when ``interpret`` is asked for.  Shapes as
+    in :func:`repro.kernels.paged_attn.paged_decode_attention`.
+    """
+    impl = _resolve(impl)
+    if impl == "ref":
+        return ref.paged_decode(q, ck, cv, table, pos, window=window,
+                                scale=scale, out_scale=out_scale)
+    return _paged_attn.paged_decode_attention(
+        q, ck, cv, table, pos, window=window, scale=scale,
+        out_scale=out_scale, interpret=(impl == "interpret"))
 
 
 def bulk_op(a: jnp.ndarray, b: jnp.ndarray, op: str = "xor",
@@ -282,17 +308,19 @@ def as_words(buf: jnp.ndarray) -> jnp.ndarray:
         return jax.lax.bitcast_convert_type(flat, jnp.uint32)
     if size == 8:
         return jax.lax.bitcast_convert_type(flat, jnp.uint32).reshape(-1)
+    # narrow dtypes pack by strided slices of the flat stream: a (n, 2) or
+    # (n, 4) intermediate would pad its minor axis to 128 lanes on a TPU
     if size == 2:
         u16 = jax.lax.bitcast_convert_type(flat, jnp.uint16)
         if u16.shape[0] % 2:
             u16 = jnp.pad(u16, (0, 1))
-        u16 = u16.reshape(-1, 2).astype(jnp.uint32)
-        return u16[:, 0] | (u16[:, 1] << 16)
+        return (u16[0::2].astype(jnp.uint32)
+                | (u16[1::2].astype(jnp.uint32) << 16))
     if size == 1:
         u8 = jax.lax.bitcast_convert_type(flat, jnp.uint8)
         pad = (-u8.shape[0]) % 4
         if pad:
             u8 = jnp.pad(u8, (0, pad))
-        u8 = u8.reshape(-1, 4).astype(jnp.uint32)
-        return u8[:, 0] | (u8[:, 1] << 8) | (u8[:, 2] << 16) | (u8[:, 3] << 24)
+        b = [u8[i::4].astype(jnp.uint32) << (8 * i) for i in range(4)]
+        return b[0] | b[1] | b[2] | b[3]
     raise ValueError(f"unsupported dtype {buf.dtype}")

@@ -6,9 +6,11 @@ mirroring the paper's sense amplifier producing the digital bit in the same
 cycle that reads the cell.  Without fusion this costs three passes
 (sign, pack, abs-mean); fused it is exactly one read of x.
 
-The 32->1 pack is expressed as a (bm, Kw, 32) reshape + weighted sum over the
-last axis.  Bits are disjoint powers of two, so an integer sum equals the
-bitwise OR; Mosaic lowers the small trailing reduction to lane shuffles.
+The 32->1 pack (:func:`pack_words`) works word-major: the block is
+transposed so K runs along sublanes, split into (Kw, 32, bm), shifted and
+summed over the 32-row axis.  Bits are disjoint powers of two, so an int32
+sum equals the bitwise OR (Mosaic has no unsigned reductions, and splitting
+the lane axis into words is a shape cast it does not lower).
 """
 
 from __future__ import annotations
@@ -22,12 +24,22 @@ from jax.experimental import pallas as pl
 from repro.core.bitpack import WORD
 
 
-def _kernel(x_ref, p_ref, a_ref, *, block_k: int):
-    x = x_ref[...].astype(jnp.float32)           # (bm, K)
+def pack_words(x: jnp.ndarray) -> jnp.ndarray:
+    """(bm, K) float -> (Kw, bm) uint32 sign words, word-major.
+
+    Word ``w`` of row ``m`` lands at ``[w, m]``; transposing gives the
+    :func:`repro.core.bitpack.pack_bits` layout.
+    """
     bm, k = x.shape
-    bits = (x >= 0).astype(jnp.uint32).reshape(bm, k // WORD, WORD)
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, 1, WORD), 2)
-    p_ref[...] = jnp.sum(bits << shifts, axis=-1, dtype=jnp.uint32)
+    bits = (x.T >= 0).astype(jnp.int32).reshape(k // WORD, WORD, bm)
+    shifts = jax.lax.broadcasted_iota(jnp.int32, (1, WORD, 1), 1)
+    return jax.lax.bitcast_convert_type(jnp.sum(bits << shifts, axis=1),
+                                        jnp.uint32)
+
+
+def _kernel(x_ref, p_ref, a_ref):
+    x = x_ref[...].astype(jnp.float32)           # (bm, K)
+    p_ref[...] = pack_words(x).T
     a_ref[...] = jnp.mean(jnp.abs(x), axis=-1, keepdims=True)
 
 
@@ -43,7 +55,7 @@ def pack(x: jnp.ndarray, *, bm: int = 256, interpret: bool = False):
     assert m % bm == 0 and k % WORD == 0, (x.shape, bm)
     grid = (m // bm,)
     planes, alpha = pl.pallas_call(
-        functools.partial(_kernel, block_k=k),
+        _kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((bm, k), lambda i: (i, 0))],
         out_specs=[

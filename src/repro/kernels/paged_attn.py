@@ -56,7 +56,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
 from repro.kernels import online_softmax as osm
 
 NEG_INF = osm.NEG_INF
@@ -148,7 +147,7 @@ def paged_decode_attention(q, ck, cv, table, pos, *, window: int = 0,
                           scale=scale, out_scale=out_scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kv, g, dh), q.dtype),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(table, pos, q, ck, cv)

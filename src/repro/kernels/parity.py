@@ -16,14 +16,23 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
+
+def _xor_fold(v: jnp.ndarray) -> jnp.ndarray:
+    """XOR-fold the rows of a (2^n, D) block to (1, D) by halving.
+
+    An explicit tree of ``^``: Mosaic has no xor reduction primitive.
+    """
+    while v.shape[0] > 1:
+        h = v.shape[0] // 2
+        v = v[:h] ^ v[h:]
+    return v
 
 
 def _kernel(w_ref, d_ref):
     i = pl.program_id(0)
-    chunk = w_ref[...]                                   # (br, D) uint32
-    fold = jnp.bitwise_xor.reduce(chunk, axis=0)[None, :]  # (1, D)
+    fold = _xor_fold(w_ref[...])                         # (1, D)
 
     @pl.when(i == 0)
     def _init():
@@ -39,10 +48,12 @@ def parity_digest(words: jnp.ndarray, *, digest_width: int = 128,
                   br: int = 512, interpret: bool = False) -> jnp.ndarray:
     """Fold a (R, digest_width) uint32 buffer to a (digest_width,) digest.
 
-    R % br == 0 (ops.digest pads flat buffers with XOR-neutral zeros).
+    R % br == 0 and br a power of two (ops.digest pads flat buffers with
+    XOR-neutral zeros).
     """
     r, d = words.shape
     assert d == digest_width and r % br == 0, (words.shape, digest_width, br)
+    assert br & (br - 1) == 0, br
     grid = (r // br,)
     out = pl.pallas_call(
         _kernel,
@@ -50,7 +61,7 @@ def parity_digest(words: jnp.ndarray, *, digest_width: int = 128,
         in_specs=[pl.BlockSpec((br, d), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((1, d), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, d), jnp.uint32),
-        compiler_params=compat.CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(words)
     return out[0]

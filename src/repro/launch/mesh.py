@@ -15,17 +15,10 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
-
-try:  # AxisType landed after jax 0.4.x; Auto is that jax's only behavior
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _make_mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 

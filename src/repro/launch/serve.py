@@ -38,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 import repro.configs as configs
+from repro.launch import compile_cache
 from repro.models import lm
 from repro.serve import (ClassifierService, ServeEngine,
                          TranscriptionService, synthetic_audio_trace,
@@ -143,6 +144,7 @@ def _run_replicated(cfg, params, trace, s_max, args) -> int:
 
 
 def main() -> int:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")

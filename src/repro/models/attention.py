@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed.ctx import constrain
-from repro.kernels import ops, paged_attn
+from repro.kernels import ops
 from repro.models import layers
 from repro.models.params import ParamDef
 
@@ -407,13 +407,14 @@ def paged_attention(cfg, p: dict, x: jnp.ndarray, cache: PagedKVCache,
         # §18) — the gather/mask/softmax/PV chain below is its reference
         # twin (exact in real arithmetic, allclose in floats), kept as the
         # production path on ref/interpret backends so cross-layout token
-        # pins stay bitwise.  Chunked prefill (c > 1) always takes the
+        # pins stay bitwise.  Forced on a ref backend, ops.paged_decode runs
+        # the one-shot oracle; the kernel is interpreted only under an
+        # explicit interpret impl.  Chunked prefill (c > 1) always takes the
         # unfused path.
-        out = paged_attn.paged_decode_attention(
+        out = ops.paged_decode(
             q[:, 0], ck, cv, table, qpos[:, 0], window=window,
             scale=float(scale),
-            out_scale=float(1.0 / cfg.kv_i8_scale) if i8 else 1.0,
-            interpret=ops._resolve("auto") != "pallas")
+            out_scale=float(1.0 / cfg.kv_i8_scale) if i8 else 1.0)
         out = out.reshape(b, 1, cfg.n_heads * cfg.d_head).astype(x.dtype)
         return layers.linear(out, p["wo"], cfg.quant), PagedKVCache(ck, cv)
 

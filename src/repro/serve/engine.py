@@ -168,19 +168,25 @@ class ServeEngine:
         state cannot be rebuilt from cached blocks (recurrent carries,
         local window rings) — ``engine.prefix_caching`` reports the
         effective setting.
+      device: the device that holds the resident params and decode state
+        (None: leave them where JAX puts them).  Every program then runs
+        there, since host inputs follow committed arrays.
     """
 
     def __init__(self, cfg, params, *, slots: int, s_max: int,
                  eos_id: int | None = None, temperature: float = 0.0,
                  seed: int = 0, pack: bool = True, paged: bool = True,
                  block_size: int = 0, prefill_chunk: int = 0,
-                 n_blocks: int = 0, prefix_cache: bool = True):
+                 n_blocks: int = 0, prefix_cache: bool = True,
+                 device=None):
         self.cfg = cfg
         self.slots = slots
         self.s_max = s_max
         self.eos_id = eos_id
         self.temperature = float(temperature)
         self.params = lm.pack_params(cfg, params) if pack else params
+        if device is not None:
+            self.params = jax.device_put(self.params, device)
         self.pool = SlotPool(slots)
         self.sessions: dict[int, Session] = {}
         self._key = jax.random.PRNGKey(seed)
@@ -229,6 +235,8 @@ class ServeEngine:
             self._dense_prefill_lens: set[int] = set()
             self._prefix = None
             self._share_cls = None
+        if device is not None:
+            self._state = jax.device_put(self._state, device)
         # host-side mirrors of the device batch (tiny, moved every step)
         self._tokens = np.zeros((slots, 1), np.int32)
         self._active = np.zeros((slots,), bool)
@@ -393,6 +401,11 @@ class ServeEngine:
         self._tokens[slot, 0] = t
         self._active[slot] = True
         return True
+
+    def resident_devices(self) -> set:
+        """Devices holding any leaf of the resident params or decode state."""
+        return {d for leaf in jax.tree.leaves((self.params, self._state))
+                for d in leaf.devices()}
 
     @property
     def prefix_caching(self) -> bool:
@@ -810,6 +823,45 @@ class ServeEngine:
                                 for c, t in self._tables.items()}
         return self._dev_tables
 
+    def _decode_args(self) -> tuple:
+        """The paged decode program's arguments for the live batch."""
+        return (self.params, jnp.asarray(self._tokens), self._state,
+                self._device_tables(), jnp.asarray(self._active), self._key,
+                jnp.zeros((self.slots,), jnp.int32))
+
+    def decode_program_text(self) -> str:
+        """Compiled HLO text of the paged decode program this engine runs,
+        e.g. to check which kernels the step contains.  Nothing runs; the
+        program compiles again unless a compilation cache holds it."""
+        self._require_paged("decode_program_text")
+        return self._paged_decode_program.lower(
+            *self._decode_args()).compile().as_text()
+
+    def peek_logits(self, depth: int | None = None) -> jax.Array:
+        """(slots, 1, padded vocab) logits the next decode step samples
+        from, computed without advancing the engine (the resident state is
+        not donated).  Traced afresh per call, so it follows the
+        ``REPRO_FUSED_DECODE`` setting in force at the call.
+
+        ``depth`` evaluates only the first ``depth`` layers of a
+        single-segment arch — the same weights and cached state, cut — so
+        two numerics can be compared before depth compounds their
+        differences.
+        """
+        self._require_paged("peek_logits")
+        cfg = self.cfg
+        params, tokens, state, tables, active = self._decode_args()[:5]
+        if depth is not None:
+            ((kind, _),) = cfg.segments()
+            name = f"seg0_{kind}"
+            cut = lambda tree: jax.tree.map(lambda x: x[:depth], tree)
+            params = {**params, name: cut(params[name])}
+            state = state._replace(seg_states=(cut(state.seg_states[0]),))
+            cfg = dataclasses.replace(cfg, n_layers=depth)
+        step = jax.jit(lambda p, t, s, tb, a: lm.paged_decode_step(
+            cfg, p, t, s, tb, active=a)[0])
+        return step(params, tokens, state, tables, active)
+
     def decode_roofline(self) -> dict:
         """AOT roofline audit of this engine's decode step (nothing runs).
 
@@ -836,9 +888,7 @@ class ServeEngine:
 
         args = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-            (self.params, jnp.asarray(self._tokens), self._state,
-             self._device_tables(), jnp.asarray(self._active), self._key,
-             jnp.zeros((self.slots,), jnp.int32)))
+            self._decode_args())
         self.stats.decode_dispatches = analysis.dispatch_count(
             jax.make_jaxpr(fn)(*args))
         r = analysis.roofline(jax.jit(fn).lower(*args).compile())
@@ -866,9 +916,7 @@ class ServeEngine:
             seeds[slot] = self._seed_for(sess.request.rid, len(sess.tokens))
         if self.paged:
             toks, self._state = self._paged_decode_program(
-                self.params, jnp.asarray(self._tokens), self._state,
-                self._device_tables(), jnp.asarray(self._active), self._key,
-                jnp.asarray(seeds))
+                *self._decode_args()[:-1], jnp.asarray(seeds))
             if self.blocks is not None:
                 self.stats.observe_blocks(self.blocks.in_use)
         else:

@@ -46,6 +46,7 @@ from repro.core.incremental import DigestCache
 from repro.core.verify import leaf_key
 from repro.distributed.fault import StragglerPolicy
 from repro.launch.mesh import make_replica_meshes
+from repro.models import lm
 from repro.serve.engine import ServeEngine
 from repro.serve.session import Request, Session
 from repro.serve.stats import EngineStats, ServeReport
@@ -220,16 +221,19 @@ class Router:
         self.epoch_steps = int(epoch_steps)
         self.replicas: list[ReplicaHandle] = []
         meshes = make_replica_meshes(n_replicas)
+        # pack once, then copy the packed tree to each replica's device: a
+        # committed array ignores jax.default_device, so only an explicit
+        # device_put moves params and resident state off the first device
+        resident = lm.pack_params(cfg, params) if pack else params
         for i, mesh in enumerate(meshes):
             dev = mesh.devices.flat[0]
-            with jax.default_device(dev):
-                eng = ServeEngine(cfg, params, slots=slots, s_max=s_max,
-                                  eos_id=eos_id, temperature=temperature,
-                                  seed=seed, pack=pack, paged=True,
-                                  block_size=block_size,
-                                  prefill_chunk=prefill_chunk,
-                                  n_blocks=n_blocks,
-                                  prefix_cache=prefix_cache)
+            eng = ServeEngine(cfg, resident, slots=slots, s_max=s_max,
+                              eos_id=eos_id, temperature=temperature,
+                              seed=seed, pack=pack, paged=True,
+                              block_size=block_size,
+                              prefill_chunk=prefill_chunk,
+                              n_blocks=n_blocks,
+                              prefix_cache=prefix_cache, device=dev)
             self.replicas.append(ReplicaHandle(
                 index=i, engine=eng, mesh=mesh, device=dev,
                 policy=StragglerPolicy(straggler_factor=straggler_factor),

@@ -212,17 +212,18 @@ def test_binarize_grid_never_degenerates_to_one_row(monkeypatch):
 
 
 def test_xnor_matmul_tile_never_degenerates_to_bk_one(monkeypatch):
-    """kw=96 packed words with bk=64 must pad kw to 128 (k-grid of 2), not
-    shrink to bk=1 (k-grid of 96); valid_k keeps the result exact."""
+    """kw=160 packed words with the lane-legal default bk=128 must pad kw
+    to 256 (k-grid of 2), not shrink to bk=1 (k-grid of 160); valid_k
+    keeps the result exact."""
     seen = _spy(monkeypatch, ops._xnor_gemm, "xnor_gemm")
-    k = 96 * 32                               # kw = 96 words
+    k = 160 * 32                              # kw = 160 words
     a, b = _rand(16, k), _rand(8, k)
     pa = bitpack.pack_bits(jnp.asarray(a))
     pb = bitpack.pack_bits(jnp.asarray(b))
-    got = ops.xnor_matmul(pa, pb, k, impl="interpret")   # default bk=64
-    assert seen["bk"] == 64, seen
+    got = ops.xnor_matmul(pa, pb, k, impl="interpret")   # default bk=128
+    assert seen["bk"] == 128, seen
     assert seen["shape"][1] % seen["bk"] == 0
-    assert seen["shape"][1] // seen["bk"] == 2           # k-grid of 2, not 96
+    assert seen["shape"][1] // seen["bk"] == 2           # k-grid of 2, not 160
     want = ref.xnor_dot_float(jnp.asarray(a), jnp.asarray(b))
     assert np.array_equal(np.asarray(got), np.asarray(want))
 

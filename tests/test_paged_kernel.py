@@ -127,6 +127,27 @@ def test_kernel_is_one_dispatch():
     assert nu > nf
 
 
+@pytest.mark.parametrize("impl", ["ref", "interpret"])
+def test_ops_paged_decode_dispatch(impl, monkeypatch):
+    """ops.paged_decode runs the oracle under ref and the kernel only when
+    the interpreter is asked for — never an implicit interpreter."""
+    monkeypatch.setattr(ops, "_FORCE", "")
+    q, ck, cv, table, scale, out_scale = _case()
+    pos = jnp.asarray([3, 17, 39], jnp.int32)
+    want = ref.paged_decode(q, ck, cv, table, pos, scale=scale)
+    got = ops.paged_decode(q, ck, cv, table, pos, scale=scale, impl=impl)
+    nf = analysis.dispatch_count(jax.make_jaxpr(
+        lambda *a: ops.paged_decode(*a, scale=scale, impl=impl))(
+            q, ck, cv, table, pos))
+    if impl == "ref":
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+        assert nf > 1
+    else:
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)
+        assert nf == 1
+
+
 def test_fused_mode_resolution(monkeypatch):
     monkeypatch.delenv("REPRO_FUSED_DECODE", raising=False)
     # with no override, CPU backends keep the bit-exact unfused twin

@@ -403,3 +403,43 @@ def test_restore_packed_passthrough_unquantized(tmp_path):
     assert jax.tree.structure(got) == jax.tree.structure(params)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(params)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_engine_device_places_params_and_state():
+    """``device=`` commits the resident params and decode state there, and
+    the engine still serves the same tokens as an unplaced one."""
+    cfg, params = _setup("qwen3-4b")
+    dev = jax.devices()[-1]
+    trace = synthetic_trace(3, cfg.vocab, seed=2)
+    toks = []
+    for device in (None, dev):
+        eng = ServeEngine(cfg, params, slots=2, s_max=24, device=device)
+        if device is not None:
+            assert eng.resident_devices() == {dev}
+        for r in trace:
+            eng.submit(r)
+        rep = eng.run()
+        toks.append({r.rid: rep.tokens(r.rid).tolist() for r in trace})
+    assert toks[0] == toks[1]
+
+
+@pytest.mark.parametrize("name", ["qwen3-4b", "qwen2-7b+xnor"])
+def test_peek_logits_is_what_the_next_step_samples(name):
+    """peek_logits leaves the engine untouched and its greedy argmax is the
+    token the next decode step emits; the full-depth cut is the model."""
+    cfg, params = _setup(name)
+    eng = ServeEngine(cfg, params, slots=2, s_max=24)
+    for r in synthetic_trace(2, cfg.vocab, seed=4, prompt_lens=(4,),
+                             new_tokens=(6,)):
+        eng.submit(r)
+    while not eng.stats.decode_steps:
+        eng.step()
+    logits = np.asarray(eng.peek_logits(), np.float32)[:, 0, :cfg.vocab]
+    np.testing.assert_array_equal(
+        logits, np.asarray(eng.peek_logits(depth=cfg.n_layers),
+                           np.float32)[:, 0, :cfg.vocab])
+    assert eng.peek_logits(depth=1).shape == (2, 1, cfg.padded_vocab)
+    before = {rid: list(s.tokens) for rid, s in eng.sessions.items()}
+    eng.step()
+    for rid, s in eng.sessions.items():
+        assert s.tokens[len(before[rid])] == int(np.argmax(logits[s.slot]))

@@ -137,3 +137,13 @@ def test_engine_mesh_axis_is_bank():
     assert mesh.axis_names == ("bank",)
     with pytest.raises(ValueError):
         mesh_mod.make_engine_mesh(len(jax.devices()) + 1)
+
+
+def test_sharded_digest_of_committed_buffer(sharded, single):
+    """A buffer committed to one device (a serve replica's resident params)
+    digests on the mesh without an implicit cross-device move."""
+    buf = jax.device_put(
+        jnp.asarray(RNG.integers(0, 2**32, 5000, dtype=np.uint32)),
+        jax.devices()[0])
+    assert np.array_equal(np.asarray(sharded.digest(buf)),
+                          np.asarray(single.digest(buf)))
