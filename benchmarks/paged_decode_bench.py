@@ -26,8 +26,10 @@ to run.  The tok/s comparison under the production dispatch lives in
 ``--smoke`` additionally runs the engine-level gates CI pins in both
 kernel modes: fused decode tokens (``REPRO_FUSED_DECODE=on``) bit-identical
 to unfused (``off``) on a float32 smoke engine, and — where the decode
-step runs the compiled kernels — the fused whole-decode-step dispatch count
-strictly below the unfused one.
+step contains the kernel, compiled or interpreted (one ``pallas_call``
+either way) — the fused whole-decode-step dispatch count strictly below
+the unfused one.  On a ref backend the fused step runs the one-shot
+oracle instead, so there is no dispatch gap to pin.
 
 Run:  REPRO_KERNEL_IMPL=interpret PYTHONPATH=src \
           python benchmarks/paged_decode_bench.py [--smoke]   # CPU
@@ -171,7 +173,7 @@ def _smoke_gates() -> None:
     print(report.serve_decode_header())
     print(report.serve_decode_row("decode/fused", audit_on))
     print(report.serve_decode_row("decode/unfused", audit_off))
-    if ops._resolve("auto") == "pallas":
+    if ops._resolve("auto") != "ref":
         assert audit_on["dispatches"] < audit_off["dispatches"], (
             f"fused decode step dispatches ({audit_on['dispatches']}) not "
             f"below unfused ({audit_off['dispatches']})")

@@ -26,6 +26,9 @@ from repro.kernels import ref
 from repro.kernels import xnor_gemm as _xnor_gemm
 
 _FORCE = os.environ.get("REPRO_KERNEL_IMPL", "")  # "", "ref", "pallas", "interpret"
+# staged-word scratch of the fused XNOR linear: half of the 16 MiB scoped
+# VMEM a TPU v5e kernel gets by default, the rest for its blocks
+_FUSED_STAGE_BYTES = 8 * 2**20
 
 
 def _resolve(impl: str) -> str:
@@ -163,6 +166,11 @@ def xnor_linear_fused(x: jnp.ndarray, pb: jnp.ndarray, beta: jnp.ndarray,
     # bits, so the kernel's valid_k accounting stays exact (see _fused_kernel)
     xp = _pad_cols(x, bitpack.WORD)
     bm, bn = min(bm, _round8(m)), min(bn, _round8(n))
+    # the kernel stages each row block's packed words broadcast over the
+    # output lanes, (Kw, bm, bn) uint32 in VMEM: fewer rows per block keep
+    # that within _FUSED_STAGE_BYTES (at K 9728 and bn 128, bm 48)
+    kw = xp.shape[1] // bitpack.WORD
+    bm = min(bm, max(8, _FUSED_STAGE_BYTES // (kw * bn * 4) // 8 * 8))
     xp, pb2 = _pad_rows(xp, bm), _pad_rows(pb, bn)
     beta2 = jnp.pad(beta, (0, pb2.shape[0] - n))
     out = _xnor_gemm.xnor_linear_fused(xp, pb2, beta2, valid_k=valid_k,

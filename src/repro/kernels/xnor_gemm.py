@@ -157,7 +157,9 @@ def xnor_linear_fused(x: jnp.ndarray, pb: jnp.ndarray, beta: jnp.ndarray, *,
     the dot (same constraint as pack.py); the column axis is sequential
     ("arbitrary") so the packed row block staged at column 0 serves every
     column.  Per-step VMEM is the (bm, Kp) activation block, the (bn, Kw)
-    weight planes and their word-major copies, and the (bm, bn) tile.
+    weight planes and their word-major copies, and the (bm, bn) tile; the
+    staged activation words, (Kw, bm, bn) uint32, dominate, so
+    ``ops.xnor_linear_fused`` narrows bm as K grows.
     """
     m, kp = x.shape
     n, kw = pb.shape

@@ -14,6 +14,7 @@ or encoder frames (audio); None for text-only archs.
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, NamedTuple
 
 import jax
@@ -323,6 +324,27 @@ def paged_decode_step(cfg, params, token: jnp.ndarray, state: DecodeState,
     logits = layers.logits(cfg, params["embed"], x)
     inc = 1 if active is None else active.astype(jnp.int32)
     return logits, DecodeState(state.pos + inc, tuple(new_states), state.ctx)
+
+
+def truncate(cfg, params, state: DecodeState, depth: int):
+    """The model cut to its first ``depth`` decoder layers: ``(cfg, params,
+    state)`` with the same weights and cached state for those layers, and
+    the embedding, head and any encoder kept — e.g. to compare two numerics
+    before depth compounds their differences."""
+    if not 0 < depth <= cfg.n_layers:
+        raise ValueError(f"depth {depth} outside 1..{cfg.n_layers}")
+    params, seg_states, left = dict(params), [], depth
+    for i, (kind, n) in enumerate(cfg.segments()):
+        keep, left = min(n, left), left - min(n, left)
+        name = f"seg{i}_{kind}"
+        if not keep:
+            del params[name]
+            continue
+        params[name] = jax.tree.map(lambda x: x[:keep], params[name])
+        seg_states.append(jax.tree.map(lambda x: x[:keep],
+                                       state.seg_states[i]))
+    return (dataclasses.replace(cfg, n_layers=depth), params,
+            state._replace(seg_states=tuple(seg_states)))
 
 
 def prefill_chunk_step(cfg, params, tokens: jnp.ndarray, state: DecodeState,

@@ -829,39 +829,6 @@ class ServeEngine:
                 self._device_tables(), jnp.asarray(self._active), self._key,
                 jnp.zeros((self.slots,), jnp.int32))
 
-    def decode_program_text(self) -> str:
-        """Compiled HLO text of the paged decode program this engine runs,
-        e.g. to check which kernels the step contains.  Nothing runs; the
-        program compiles again unless a compilation cache holds it."""
-        self._require_paged("decode_program_text")
-        return self._paged_decode_program.lower(
-            *self._decode_args()).compile().as_text()
-
-    def peek_logits(self, depth: int | None = None) -> jax.Array:
-        """(slots, 1, padded vocab) logits the next decode step samples
-        from, computed without advancing the engine (the resident state is
-        not donated).  Traced afresh per call, so it follows the
-        ``REPRO_FUSED_DECODE`` setting in force at the call.
-
-        ``depth`` evaluates only the first ``depth`` layers of a
-        single-segment arch — the same weights and cached state, cut — so
-        two numerics can be compared before depth compounds their
-        differences.
-        """
-        self._require_paged("peek_logits")
-        cfg = self.cfg
-        params, tokens, state, tables, active = self._decode_args()[:5]
-        if depth is not None:
-            ((kind, _),) = cfg.segments()
-            name = f"seg0_{kind}"
-            cut = lambda tree: jax.tree.map(lambda x: x[:depth], tree)
-            params = {**params, name: cut(params[name])}
-            state = state._replace(seg_states=(cut(state.seg_states[0]),))
-            cfg = dataclasses.replace(cfg, n_layers=depth)
-        step = jax.jit(lambda p, t, s, tb, a: lm.paged_decode_step(
-            cfg, p, t, s, tb, active=a)[0])
-        return step(params, tokens, state, tables, active)
-
     def decode_roofline(self) -> dict:
         """AOT roofline audit of this engine's decode step (nothing runs).
 

@@ -171,12 +171,15 @@ def test_fused_mode_resolution(monkeypatch):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("m,k,n", [(5, 70, 9), (17, 200, 33), (128, 64, 128)])
+@pytest.mark.parametrize("m,k,n", [(5, 70, 9), (17, 200, 33), (128, 64, 128),
+                                   (130, 4200, 128)])
 def test_xnor_fused_matches_unfused_chain(m, k, n):
     """Fused kernel vs the three-dispatch chain, including ragged K (not a
-    word multiple).  The ref impl of the fused op is bit-identical to the
-    chain; the kernel is allclose (its alpha mean associates differently)
-    with bit-identical integer dots by construction."""
+    word multiple) and a K wide enough that the row block narrows to bound
+    the staged words (130 rows in two blocks of 120).  The ref impl of the
+    fused op is bit-identical to the chain; the kernel is allclose (its
+    alpha mean associates differently) with bit-identical integer dots by
+    construction."""
     x = jnp.asarray(RNG.standard_normal((m, k)), jnp.float32)
     w = jnp.asarray(RNG.standard_normal((n, k)), jnp.float32)
     pb, beta = xnor_layers.pack_weights(w, impl="ref")

@@ -423,10 +423,20 @@ def test_engine_device_places_params_and_state():
     assert toks[0] == toks[1]
 
 
-@pytest.mark.parametrize("name", ["qwen3-4b", "qwen2-7b+xnor"])
-def test_peek_logits_is_what_the_next_step_samples(name):
-    """peek_logits leaves the engine untouched and its greedy argmax is the
-    token the next decode step emits; the full-depth cut is the model."""
+def _next_logits(eng, depth=None):
+    """(slots, 1, padded vocab) logits of the engine's next decode step, from
+    a one-off program over its decode arguments (nothing is donated), with
+    the model cut to ``depth`` layers by ``lm.truncate``."""
+    params, tokens, state, tables, active = eng._decode_args()[:5]
+    cfg = eng.cfg
+    if depth is not None:
+        cfg, params, state = lm.truncate(cfg, params, state, depth)
+    step = jax.jit(lambda p, t, s, tb, a: lm.paged_decode_step(
+        cfg, p, t, s, tb, active=a)[0])
+    return np.asarray(step(params, tokens, state, tables, active), np.float32)
+
+
+def _engine_mid_decode(name):
     cfg, params = _setup(name)
     eng = ServeEngine(cfg, params, slots=2, s_max=24)
     for r in synthetic_trace(2, cfg.vocab, seed=4, prompt_lens=(4,),
@@ -434,12 +444,43 @@ def test_peek_logits_is_what_the_next_step_samples(name):
         eng.submit(r)
     while not eng.stats.decode_steps:
         eng.step()
-    logits = np.asarray(eng.peek_logits(), np.float32)[:, 0, :cfg.vocab]
+    return cfg, eng
+
+
+@pytest.mark.parametrize("name", ["qwen3-4b", "qwen2-7b+xnor"])
+def test_peek_logits_is_what_the_next_step_samples(name):
+    """Logits read from the engine's decode arguments leave it untouched and
+    their greedy argmax is the token the next decode step emits; the
+    full-depth cut is the model."""
+    cfg, eng = _engine_mid_decode(name)
+    logits = _next_logits(eng)[:, 0, :cfg.vocab]
     np.testing.assert_array_equal(
-        logits, np.asarray(eng.peek_logits(depth=cfg.n_layers),
-                           np.float32)[:, 0, :cfg.vocab])
-    assert eng.peek_logits(depth=1).shape == (2, 1, cfg.padded_vocab)
+        logits, _next_logits(eng, depth=cfg.n_layers)[:, 0, :cfg.vocab])
+    assert _next_logits(eng, depth=1).shape == (2, 1, cfg.padded_vocab)
     before = {rid: list(s.tokens) for rid, s in eng.sessions.items()}
     eng.step()
     for rid, s in eng.sessions.items():
         assert s.tokens[len(before[rid])] == int(np.argmax(logits[s.slot]))
+
+
+@pytest.mark.parametrize("name", ["recurrentgemma-2b", "xlstm-350m"])
+def test_truncate_cuts_across_segments(name):
+    """``lm.truncate`` on a multi-segment arch: each cut keeps the leading
+    layers of every segment it reaches and drops the rest, and the cut model
+    decodes."""
+    cfg, eng = _engine_mid_decode(name)
+    params, _, state = eng._decode_args()[:3]
+    full = _next_logits(eng)
+    for depth in range(1, cfg.n_layers + 1):
+        cut_cfg, cut_params, cut_state = lm.truncate(cfg, params, state, depth)
+        segs = cut_cfg.segments()
+        assert sum(n for _, n in segs) == depth
+        assert {k for k in cut_params if k.startswith("seg")} == {
+            f"seg{i}_{kind}" for i, (kind, _) in enumerate(segs)}
+        for (kind, n), st in zip(segs, cut_state.seg_states, strict=True):
+            assert all(x.shape[0] == n for x in jax.tree.leaves(st))
+        logits = _next_logits(eng, depth)
+        assert logits.shape == full.shape and np.all(np.isfinite(logits))
+    np.testing.assert_array_equal(_next_logits(eng, cfg.n_layers), full)
+    with pytest.raises(ValueError):
+        lm.truncate(cfg, params, state, cfg.n_layers + 1)

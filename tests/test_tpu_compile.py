@@ -86,7 +86,8 @@ def test_paged_decode_attention_compiles(compile_text, pool):
 
 
 @pytest.mark.parametrize("k,n", [(D, FF), (FF, D)], ids=["up", "down"])
-@pytest.mark.parametrize("m", [SLOTS, 32], ids=["decode", "prefill_chunk"])
+@pytest.mark.parametrize("m", [SLOTS, 32, 128],
+                         ids=["decode", "prefill_chunk", "rows_128"])
 def test_xnor_linear_fused_compiles(compile_text, m, k, n):
     def fn(x, pb, beta):
         return ops.xnor_linear_fused(x, pb, beta, k, impl="pallas")
