@@ -45,5 +45,6 @@ def bulk_xor(a: jnp.ndarray, b: jnp.ndarray, *, invert: bool = False,
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, d), jnp.uint32),
+        name="bulk_xor",
         interpret=interpret,
     )(a, b)

@@ -53,5 +53,6 @@ def xor_cipher(words: jnp.ndarray, key: jnp.ndarray, *, br: int = 512,
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, d), jnp.uint32),
+        name="xor_cipher",
         interpret=interpret,
     )(key.reshape(1, 3), words)

@@ -96,6 +96,7 @@ def flash_attention(q, k, v, *, causal: bool = True, bq: int = 256,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_attention",
         interpret=interpret,
     )(q, k, v)
 

@@ -66,6 +66,7 @@ def pack(x: jnp.ndarray, *, bm: int = 256, interpret: bool = False):
             jax.ShapeDtypeStruct((m, k // WORD), jnp.uint32),
             jax.ShapeDtypeStruct((m, 1), jnp.float32),
         ],
+        name="pack",
         interpret=interpret,
     )(x)
     return planes, alpha[:, 0]

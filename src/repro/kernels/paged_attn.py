@@ -149,5 +149,6 @@ def paged_decode_attention(q, ck, cv, table, pos, *, window: int = 0,
         out_shape=jax.ShapeDtypeStruct((b, kv, g, dh), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="paged_decode_attention",
         interpret=interpret,
     )(table, pos, q, ck, cv)

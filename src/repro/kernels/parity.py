@@ -62,6 +62,7 @@ def parity_digest(words: jnp.ndarray, *, digest_width: int = 128,
         out_specs=pl.BlockSpec((1, d), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, d), jnp.uint32),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        name="parity_digest",
         interpret=interpret,
     )(words)
     return out[0]

@@ -105,6 +105,7 @@ def xnor_gemm(pa: jnp.ndarray, pb: jnp.ndarray, *, valid_k: int,
                         pltpu.VMEM((bk, bn), jnp.uint32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="xnor_gemm",
         interpret=interpret,
     )(pa, pb)
     return jnp.int32(valid_k) - 2 * popc
@@ -180,5 +181,6 @@ def xnor_linear_fused(x: jnp.ndarray, pb: jnp.ndarray, beta: jnp.ndarray, *,
                         pltpu.VMEM((bm, 1), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name="xnor_linear_fused",
         interpret=interpret,
     )(x, pb, beta.astype(jnp.float32).reshape(1, -1))

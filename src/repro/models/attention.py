@@ -355,6 +355,7 @@ class PagedKVCache(NamedTuple):
         return PagedKVCache(cp(self.k), cp(self.v))
 
 
+@jax.named_scope("attn.core")
 def paged_attention(cfg, p: dict, x: jnp.ndarray, cache: PagedKVCache,
                     table: jnp.ndarray, pos: jnp.ndarray, *, window: int = 0,
                     valid: jnp.ndarray | None = None):
@@ -387,16 +388,17 @@ def paged_attention(cfg, p: dict, x: jnp.ndarray, cache: PagedKVCache,
     i8 = cache.k.dtype == jnp.int8
     if i8:
         k, v = i8_encode(cfg, k), i8_encode(cfg, v)
-    phys = jnp.take_along_axis(table, (qpos // bs) % w, axis=1)   # (B, C)
-    if valid is not None:
-        phys = jnp.where(valid, phys, 0)             # trash block
-    off = qpos % bs
-    # advanced indices (phys, off) broadcast to (B, C); the KV slice stays:
-    # scatter target shape (B, C, KV, dh).  Distinct live tokens always hit
-    # distinct (block, offset) pairs (BlockPool uniqueness + ring sizing);
-    # only trash-block writes may collide, and those are never read.
-    ck = cache.k.at[phys, :, off].set(k.astype(cache.k.dtype))
-    cv = cache.v.at[phys, :, off].set(v.astype(cache.v.dtype))
+    with jax.named_scope("attn.kv_write"):
+        phys = jnp.take_along_axis(table, (qpos // bs) % w, axis=1)  # (B, C)
+        if valid is not None:
+            phys = jnp.where(valid, phys, 0)         # trash block
+        off = qpos % bs
+        # advanced indices (phys, off) broadcast to (B, C); the KV slice
+        # stays: scatter target shape (B, C, KV, dh).  Distinct live tokens
+        # always hit distinct (block, offset) pairs (BlockPool uniqueness +
+        # ring sizing); only trash-block writes may collide, never read.
+        ck = cache.k.at[phys, :, off].set(k.astype(cache.k.dtype))
+        cv = cache.v.at[phys, :, off].set(v.astype(cache.v.dtype))
 
     scale = cfg.d_head ** -0.5
     if i8:

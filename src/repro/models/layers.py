@@ -76,6 +76,7 @@ def ffn_defs(cfg, n: int, d_ff: int | None = None) -> dict:
     }
 
 
+@jax.named_scope("mlp")
 def ffn(cfg, p: dict, x: jnp.ndarray) -> jnp.ndarray:
     h = jax.nn.silu(linear(x, p["w1"], cfg.quant)) * linear(x, p["w3"], cfg.quant)
     h = constrain(h, "batch", None, "tp")

@@ -316,12 +316,14 @@ def paged_decode_step(cfg, params, token: jnp.ndarray, state: DecodeState,
     so a mid-prefill slot (chunked prefill interleaves with decode) rides
     along inertly; dead slots' table rows are zeroed by the host as well.
     """
-    x = layers.embed(params["embed"], token).astype(cfg.dtype)
+    with jax.named_scope("embed"):
+        x = layers.embed(params["embed"], token).astype(cfg.dtype)
     x, new_states = blocks.segment_decode(cfg, _seg_params(cfg, params), x,
                                           list(state.seg_states), state.pos,
                                           state.ctx, tables=tables,
                                           active=active)
-    logits = layers.logits(cfg, params["embed"], x)
+    with jax.named_scope("head"):
+        logits = layers.logits(cfg, params["embed"], x)
     inc = 1 if active is None else active.astype(jnp.int32)
     return logits, DecodeState(state.pos + inc, tuple(new_states), state.ctx)
 
@@ -372,15 +374,17 @@ def prefill_chunk_step(cfg, params, tokens: jnp.ndarray, state: DecodeState,
                      jnp.asarray(start, jnp.int32),
                      state.pos[slot]).astype(jnp.int32)
     valid = (jnp.arange(c) < n_valid)[None]                    # (1, C)
-    x = layers.embed(params["embed"], tokens).astype(cfg.dtype)
+    with jax.named_scope("embed"):
+        x = layers.embed(params["embed"], tokens).astype(cfg.dtype)
     if ctx is not None:
         ctx = ctx.astype(cfg.dtype)
     x, new_states = blocks.segment_chunk(cfg, _seg_params(cfg, params), x,
                                          list(state.seg_states), slot, pos0,
                                          valid, n_valid, ctx, tables,
                                          fresh=fresh)
-    xlast = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
-    logits = layers.logits(cfg, params["embed"], xlast)
+    with jax.named_scope("head"):
+        xlast = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
+        logits = layers.logits(cfg, params["embed"], xlast)
     pos = state.pos.at[slot].set(pos0 + n_valid)
     return logits, DecodeState(pos, tuple(new_states), state.ctx)
 

@@ -43,6 +43,7 @@ def capacity(cfg, tg: int) -> int:
     return max(1, int(tg * cfg.top_k / cfg.n_experts * cfg.capacity_factor))
 
 
+@jax.named_scope("mlp")
 def moe_ffn(cfg, p: dict, x: jnp.ndarray, valid: jnp.ndarray | None = None):
     """x (B, S, d) -> (y (B, S, d), aux_loss scalar).
 

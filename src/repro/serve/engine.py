@@ -57,6 +57,7 @@ from typing import Any
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.models import lm
 from repro.serve.pools import BlockPool, SlotPool
@@ -69,6 +70,7 @@ from repro.serve.stats import EngineStats, ServeReport
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("sample")
 def _sample_tokens(cfg, logits, key, seeds, temperature: float):
     """Last-position sampling, sliced to the true vocab (pad ids never
     sampled).  Per-row keys fold the host-computed (rid, step) seed into the
@@ -193,6 +195,10 @@ class ServeEngine:
         self.paged = bool(paged)
         self.stats = EngineStats()
         self._step_idx = 0                 # engine steps since construction
+        # the engine's host time since the last decode token read (None
+        # before the first), and when the current stretch of it began
+        self._turn_ns: int | None = None
+        self._t_mark = 0
         if self.paged:
             self.block_size = block_size or cfg.block_size
             self.prefill_chunk = prefill_chunk or cfg.prefill_chunk
@@ -388,7 +394,8 @@ class ServeEngine:
     def _post_prefill(self, session: Session, slot: int, tok) -> bool:
         """Record the prefill-sampled token; returns True when the request
         survives into the decode batch."""
-        t = int(np.asarray(tok)[0, 0])
+        with TraceAnnotation("serve.prefill.sync", rid=session.request.rid):
+            t = int(np.asarray(tok)[0, 0])
         session.tokens.append(t)
         session.t_first = time.monotonic()
         session.step_first = self._step_idx
@@ -699,60 +706,68 @@ class ServeEngine:
         step per admitting slot — so a long prompt interleaves with the
         decode batch in bounded ``prefill_chunk``-sized slices instead of
         blocking it head-of-line."""
-        while (plan := self._admissible_paged()) is not None:
-            session, slot = self.pool.admit()
-            req = session.request
-            session.t_admit = time.monotonic()
-            p_len = req.prompt.shape[0]
-            shared, skip, cow_src = plan
-            if self.blocks is not None:
-                if shared:
-                    self.blocks.share(req.rid, shared)
-                fresh = {cls_name: self._alloc_blocks(req.rid, n)
-                         for cls_name, n in
-                         self._fresh_needed(req, plan).items()}
-                for cls_name, ids in fresh.items():
-                    row = self._tables[cls_name][slot]
-                    row[:] = 0
-                    if cls_name == self._share_cls and shared:
-                        row[:len(shared)] = shared
-                        tail = ids
-                        if cow_src is not None:
-                            # repoint the first-write block at a private
-                            # copy; the device copy below runs before any
-                            # subsequently dispatched program can write it
-                            row[skip // self.block_size] = ids[0]
-                            tail = ids[1:]
-                        row[len(shared):len(shared) + len(tail)] = tail
-                    else:
-                        row[:len(ids)] = ids
-                self._dev_tables = None
-                if cow_src is not None:
-                    self._state = self._cow_program(
-                        self._state, jnp.int32(cow_src),
-                        jnp.int32(fresh[self._share_cls][0]))
-                    self.blocks.drop(req.rid, cow_src)
-                    self.stats.cow_copies += 1
-                self.stats.fresh_blocks += sum(len(v) for v in fresh.values())
-                self.stats.observe_blocks(self.blocks.in_use)
-            self.stats.prompt_tokens += p_len
+        while (head := self.pool.peek()) is not None and self.pool.free_slots:
+            with TraceAnnotation("serve.admit", rid=head.request.rid):
+                plan = self._admissible_paged()
+                if plan is None:
+                    return
+                self._admit_head(plan)
+
+    def _admit_head(self, plan: tuple) -> None:
+        """Seat the FIFO head under its prefix ``plan``."""
+        session, slot = self.pool.admit()
+        req = session.request
+        session.t_admit = time.monotonic()
+        p_len = req.prompt.shape[0]
+        shared, skip, cow_src = plan
+        if self.blocks is not None:
             if shared:
-                self.stats.prefix_hits += 1
-                self.stats.prefix_shared_blocks += len(shared)
-                self.stats.prefix_tokens += skip
-            c = self.prefill_chunk
-            n_suffix = p_len - skip
-            n_chunks = -(-n_suffix // c)
-            padded = np.zeros((n_chunks * c,), np.int32)
-            padded[:n_suffix] = req.prompt[skip:]
-            chain = ([] if self._prefix is None
-                     else self._prefix.chain(req.prompt, req.ctx))
-            self._prefilling[slot] = _PrefillProgress(
-                session=session, padded=padded, p_len=n_suffix,
-                n_chunks=n_chunks, next_chunk=0, ctx=self._ctx_for(req),
-                seeds=jnp.asarray([self._seed_for(req.rid, 0)], jnp.int32),
-                rows=self._slot_table_rows(slot), skip=skip, chain=chain)
-            self.stats.prefills += 1
+                self.blocks.share(req.rid, shared)
+            fresh = {cls_name: self._alloc_blocks(req.rid, n)
+                     for cls_name, n in
+                     self._fresh_needed(req, plan).items()}
+            for cls_name, ids in fresh.items():
+                row = self._tables[cls_name][slot]
+                row[:] = 0
+                if cls_name == self._share_cls and shared:
+                    row[:len(shared)] = shared
+                    tail = ids
+                    if cow_src is not None:
+                        # repoint the first-write block at a private
+                        # copy; the device copy below runs before any
+                        # subsequently dispatched program can write it
+                        row[skip // self.block_size] = ids[0]
+                        tail = ids[1:]
+                    row[len(shared):len(shared) + len(tail)] = tail
+                else:
+                    row[:len(ids)] = ids
+            self._dev_tables = None
+            if cow_src is not None:
+                self._state = self._cow_program(
+                    self._state, jnp.int32(cow_src),
+                    jnp.int32(fresh[self._share_cls][0]))
+                self.blocks.drop(req.rid, cow_src)
+                self.stats.cow_copies += 1
+            self.stats.fresh_blocks += sum(len(v) for v in fresh.values())
+            self.stats.observe_blocks(self.blocks.in_use)
+        self.stats.prompt_tokens += p_len
+        if shared:
+            self.stats.prefix_hits += 1
+            self.stats.prefix_shared_blocks += len(shared)
+            self.stats.prefix_tokens += skip
+        c = self.prefill_chunk
+        n_suffix = p_len - skip
+        n_chunks = -(-n_suffix // c)
+        padded = np.zeros((n_chunks * c,), np.int32)
+        padded[:n_suffix] = req.prompt[skip:]
+        chain = ([] if self._prefix is None
+                 else self._prefix.chain(req.prompt, req.ctx))
+        self._prefilling[slot] = _PrefillProgress(
+            session=session, padded=padded, p_len=n_suffix,
+            n_chunks=n_chunks, next_chunk=0, ctx=self._ctx_for(req),
+            seeds=jnp.asarray([self._seed_for(req.rid, 0)], jnp.int32),
+            rows=self._slot_table_rows(slot), skip=skip, chain=chain)
+        self.stats.prefills += 1
 
     def _register_upto(self, prog: _PrefillProgress, slot: int,
                        n_done: int) -> None:
@@ -780,13 +795,15 @@ class ServeEngine:
             prog = self._prefilling[slot]
             c = self.prefill_chunk
             j = prog.next_chunk
-            piece = jnp.asarray(prog.padded[None, j * c:(j + 1) * c])
-            n_valid = min(c, prog.p_len - j * c)
-            tok, self._state = self._chunk_program(
-                self.params, piece, self._state, jnp.int32(slot),
-                jnp.int32(n_valid), prog.rows, prog.ctx,
-                jnp.asarray(j == 0), jnp.int32(prog.skip), self._key,
-                prog.seeds)
+            with TraceAnnotation("serve.prefill.dispatch",
+                                 rid=prog.session.request.rid, chunk=j):
+                piece = jnp.asarray(prog.padded[None, j * c:(j + 1) * c])
+                n_valid = min(c, prog.p_len - j * c)
+                tok, self._state = self._chunk_program(
+                    self.params, piece, self._state, jnp.int32(slot),
+                    jnp.int32(n_valid), prog.rows, prog.ctx,
+                    jnp.asarray(j == 0), jnp.int32(prog.skip), self._key,
+                    prog.seeds)
             self.stats.prefill_chunks += 1
             prog.next_chunk += 1
             if self._prefix is not None:
@@ -803,19 +820,20 @@ class ServeEngine:
         while self.pool.admissible():
             session, slot = self.pool.admit()
             req = session.request
-            session.t_admit = time.monotonic()
-            tokens = jnp.asarray(req.prompt[None, :], jnp.int32)
-            ctx = self._ctx_for(req)
-            seeds = jnp.asarray([self._seed_for(req.rid, 0)], jnp.int32)
-            self._dense_prefill_lens.add(req.prompt.shape[0])
-            self.stats.prefill_traces = len(self._dense_prefill_lens)
-            tok, one = _prefill_program(
-                self.cfg, self.params, tokens, ctx, self._key, seeds,
-                s_max=self.s_max, temperature=self.temperature)
-            self.stats.prefills += 1
-            if self._post_prefill(session, slot, tok):
-                self._state = _insert_program(self._state, one,
-                                              jnp.int32(slot))
+            with TraceAnnotation("serve.admit", rid=req.rid):
+                session.t_admit = time.monotonic()
+                tokens = jnp.asarray(req.prompt[None, :], jnp.int32)
+                ctx = self._ctx_for(req)
+                seeds = jnp.asarray([self._seed_for(req.rid, 0)], jnp.int32)
+                self._dense_prefill_lens.add(req.prompt.shape[0])
+                self.stats.prefill_traces = len(self._dense_prefill_lens)
+                tok, one = _prefill_program(
+                    self.cfg, self.params, tokens, ctx, self._key, seeds,
+                    s_max=self.s_max, temperature=self.temperature)
+                self.stats.prefills += 1
+                if self._post_prefill(session, slot, tok):
+                    self._state = _insert_program(self._state, one,
+                                                  jnp.int32(slot))
 
     def _device_tables(self) -> dict:
         if self._dev_tables is None:
@@ -837,8 +855,7 @@ class ServeEngine:
         untouched), compiles it ahead-of-time, and returns the
         ``analysis.roofline`` dict augmented with the analytic per-step
         byte floor (``roofline_bytes``), ``achieved_bytes`` and the jaxpr
-        ``dispatches`` count — which is also recorded in
-        ``stats.decode_dispatches``.  The serve benchmarks render this via
+        ``dispatches`` count.  The serve benchmarks render this via
         ``report.serve_decode_row``; the fused/unfused comparison is the
         same engine audited under different ``cfg.fused_decode`` settings.
         """
@@ -856,8 +873,7 @@ class ServeEngine:
         args = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
             self._decode_args())
-        self.stats.decode_dispatches = analysis.dispatch_count(
-            jax.make_jaxpr(fn)(*args))
+        dispatches = analysis.dispatch_count(jax.make_jaxpr(fn)(*args))
         r = analysis.roofline(jax.jit(fn).lower(*args).compile())
         param_bytes = sum(x.size * jnp.dtype(x.dtype).itemsize
                           for x in jax.tree_util.tree_leaves(self.params))
@@ -870,48 +886,89 @@ class ServeEngine:
             n_kv_heads=cfg.n_kv_heads, d_head=cfg.d_head,
             kv_itemsize=kv_itemsize)
         r["achieved_bytes"] = r["hlo_bytes_per_chip"]
-        r["dispatches"] = self.stats.decode_dispatches
+        r["dispatches"] = dispatches
         return r
+
+    def _count_decode(self, rows: dict) -> None:
+        """One decode step's counters: the rows that decode a token and
+        the context each attends; the KV blocks live requests hold, and
+        those among them that hold a written token (a block several rows
+        share counts once)."""
+        st = self.stats
+        st.decode_rows += len(rows)
+        st.decode_ctx_tokens += sum(s.request.prompt.shape[0] + len(s.tokens)
+                                    for s in rows.values())
+        if not self.paged or self.blocks is None:
+            return
+        st.observe_blocks(self.blocks.in_use)
+        st.kv_reserved_block_steps += self.blocks.in_use
+        written = np.zeros((self.slots, 1), np.int64)
+        for slot, sess in self.pool.active.items():
+            prog = self._prefilling.get(slot)
+            written[slot] = (
+                sess.request.prompt.shape[0] + len(sess.tokens) if prog is None
+                else prog.skip + min(prog.next_chunk * self.prefill_chunk,
+                                     prog.p_len))
+        n = -(-written // self.block_size)
+        ids = np.unique(np.concatenate(
+            [t[np.arange(t.shape[1]) < n] for t in self._tables.values()]))
+        st.kv_live_block_steps += int(np.count_nonzero(ids))
 
     def _decode_once(self) -> None:
         """One batched decode step; append/evict per active slot (slots
         still mid-prefill ride along inertly and are skipped here)."""
-        active_sessions = {s: sess for s, sess in self.pool.active.items()
-                           if s not in self._prefilling}
-        seeds = np.zeros((self.slots,), np.int32)
-        for slot, sess in active_sessions.items():
-            seeds[slot] = self._seed_for(sess.request.rid, len(sess.tokens))
-        if self.paged:
-            toks, self._state = self._paged_decode_program(
-                *self._decode_args()[:-1], jnp.asarray(seeds))
-            if self.blocks is not None:
-                self.stats.observe_blocks(self.blocks.in_use)
-        else:
-            toks, self._state = _decode_program(
-                self.cfg, self.params, jnp.asarray(self._tokens), self._state,
-                jnp.asarray(self._active), self._key, jnp.asarray(seeds),
-                temperature=self.temperature)
+        with TraceAnnotation("serve.decode.prepare"):
+            active_sessions = {s: sess for s, sess in self.pool.active.items()
+                               if s not in self._prefilling}
+            seeds = np.zeros((self.slots,), np.int32)
+            for slot, sess in active_sessions.items():
+                seeds[slot] = self._seed_for(sess.request.rid,
+                                             len(sess.tokens))
+            if self.paged:
+                program = self._paged_decode_program
+                args = (*self._decode_args()[:-1], jnp.asarray(seeds))
+            else:
+                program = functools.partial(_decode_program, self.cfg,
+                                            temperature=self.temperature)
+                args = (self.params, jnp.asarray(self._tokens), self._state,
+                        jnp.asarray(self._active), self._key,
+                        jnp.asarray(seeds))
+        if self._turn_ns is not None:
+            self.stats.host_turns += 1
+            self.stats.host_turn_ns += (self._turn_ns + time.perf_counter_ns()
+                                        - self._t_mark)
+        with TraceAnnotation("serve.decode.dispatch"):
+            toks, self._state = program(*args)
         self.stats.decode_steps += 1
-        toks = np.asarray(toks)                     # the per-step sync point
-        for slot, sess in active_sessions.items():
-            t = int(toks[slot, 0])
-            sess.tokens.append(t)
-            self._tokens[slot, 0] = t
-            if self.eos_id is not None and t == self.eos_id:
-                self._finish(sess, "eos")
-            elif len(sess.tokens) >= sess.request.max_new_tokens:
-                self._finish(sess, "length")
+        self._count_decode(active_sessions)       # while the device runs
+        with TraceAnnotation("serve.decode.sync"):
+            toks = np.asarray(toks)                 # the per-step sync point
+        self._turn_ns, self._t_mark = 0, time.perf_counter_ns()
+        with TraceAnnotation("serve.decode.commit"):
+            for slot, sess in active_sessions.items():
+                t = int(toks[slot, 0])
+                sess.tokens.append(t)
+                self._tokens[slot, 0] = t
+                if self.eos_id is not None and t == self.eos_id:
+                    self._finish(sess, "eos")
+                elif len(sess.tokens) >= sess.request.max_new_tokens:
+                    self._finish(sess, "length")
 
     def step(self) -> bool:
         """Admit, advance in-flight prefills by one chunk each, then decode
         once; returns False when fully drained."""
+        self._t_mark = time.perf_counter_ns()
         self._step_idx += 1
-        self._admit()
-        if self._prefilling:
-            self._prefill_step()
-        if any(s not in self._prefilling for s in self.pool.active):
-            self._decode_once()
-        return not self.pool.idle()
+        with StepTraceAnnotation("serve.step", step_num=self._step_idx):
+            self._admit()
+            if self._prefilling:
+                self._prefill_step()
+            if any(s not in self._prefilling for s in self.pool.active):
+                self._decode_once()
+            more = not self.pool.idle()
+        if self._turn_ns is not None:     # the caller's time is not ours
+            self._turn_ns += time.perf_counter_ns() - self._t_mark
+        return more
 
     def run(self) -> ServeReport:
         """Drain queue + slots; returns the per-request report."""

@@ -32,9 +32,18 @@ class EngineStats:
     prefill_chunks: int = 0
     prefill_traces: int = 0
     decode_traces: int = 0
-    decode_dispatches: int = 0  # jaxpr dispatch count of the decode step
-                                # (recorded by ServeEngine.decode_roofline;
-                                # 0 until an audit runs)
+    # each decode step adds: the rows that decoded a token, the context
+    # they attended, the KV blocks live requests hold (the idle cached tier
+    # excluded) and those among them holding a written token
+    decode_rows: int = 0
+    decode_ctx_tokens: int = 0
+    kv_reserved_block_steps: int = 0
+    kv_live_block_steps: int = 0
+    # the host's turns: the engine's own host time (inside step(), its
+    # caller's left out) from each decode step's token read to the next
+    # decode dispatch, while no decode program is queued
+    host_turns: int = 0
+    host_turn_ns: int = 0
     blocks_total: int = 0       # allocatable blocks (0: dense layout)
     blocks_in_use: int = 0
     blocks_peak: int = 0

@@ -132,3 +132,47 @@ def test_bulk_op_compiles(compile_text, op):
                         ((STREAM_WORDS,), jnp.uint32),
                         ((STREAM_WORDS,), jnp.uint32))
     assert "tpu_custom_call" in text
+
+
+
+def test_decode_step_names_its_kernels_and_scopes(compile_text, one_chip,
+                                                  monkeypatch):
+    """The served decode step at qwen3-4b+xnor widths (two layers): each
+    kernel in it is named by its ``pallas_call`` name, which for the two it
+    holds is the name the benchmark's readers match, and its ops carry the
+    model scopes in their metadata."""
+    import dataclasses
+    import re
+
+    from repro import configs
+    from repro.models import lm
+    from repro.serve import engine
+
+    monkeypatch.setattr(ops, "_FORCE", "pallas")
+    cfg = dataclasses.replace(configs.get("qwen3-4b+xnor"), n_layers=2)
+    s_max = W * BS
+
+    def fn(params, tokens, state, tables, active, key, seeds):
+        logits, state = lm.paged_decode_step(cfg, params, tokens, state,
+                                             tables, active=active)
+        return engine._sample_tokens(cfg, logits, key, seeds, 0.0), state
+
+    specs = (
+        lm.packed_abstract_params(cfg),
+        jax.ShapeDtypeStruct((SLOTS, 1), jnp.int32),
+        lm.paged_decode_state_spec(cfg, SLOTS, s_max, n_blocks=N_BLOCKS,
+                                   block_size=BS),
+        {c: jax.ShapeDtypeStruct((SLOTS, w), jnp.int32) for c, w in
+         lm.paged_table_widths(cfg, s_max, BS, cfg.prefill_chunk).items()},
+        jax.ShapeDtypeStruct((SLOTS,), jnp.bool_),
+        jax.ShapeDtypeStruct((2,), jnp.uint32),
+        jax.ShapeDtypeStruct((SLOTS,), jnp.int32))
+    args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), specs)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    kernels = set(re.findall(r"%([a-z_]+)[.\d]* = \S+ custom-call\(", text))
+    assert kernels == {"paged_decode_attention", "xnor_linear_fused"}
+    scopes = set(re.findall(r'op_name="[^"]*/(embed|attn\.kv_write|attn\.core'
+                            r'|mlp|head|sample)/', text))
+    assert scopes == {"embed", "attn.kv_write", "attn.core", "mlp", "head",
+                      "sample"}
