@@ -220,6 +220,15 @@ def paged_table_widths(cfg, s_max: int, block_size: int,
     return widths
 
 
+def paged_table_windows(cfg) -> dict:
+    """The attention window per block-table class: ``cfg.local_window``
+    for a ring class, 0 for a monotone one (the ``window`` the paged
+    decode kernel walks that class's tables with)."""
+    return {c.table_class: cfg.local_window if c.window else 0
+            for c in (registry.contract(kind) for kind, _ in cfg.segments())
+            if c.paged_kv}
+
+
 def paged_decode_layer_classes(cfg) -> dict:
     """Paged decoder layers per block-table class.
 

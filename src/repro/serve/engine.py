@@ -59,6 +59,7 @@ import jax
 import jax.numpy as jnp
 from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
+from repro.kernels import paged_attn
 from repro.models import lm
 from repro.serve.pools import BlockPool, SlotPool
 from repro.serve.prefix import PrefixIndex
@@ -228,6 +229,16 @@ class ServeEngine:
             self._tables = {c: np.zeros((slots, w), np.int32)
                             for c, w in self._widths.items()}
             self._dev_tables = None
+            # the decode attention kernel's walk of each table class:
+            # (W, P table blocks per grid step, window), for the counters
+            kv_item = (1 if cfg.kv_cache_dtype == "i8"
+                       else jnp.dtype(cfg.dtype).itemsize)
+            slab = cfg.n_kv_heads * self.block_size * cfg.d_head * kv_item
+            windows = lm.paged_table_windows(cfg)
+            self._walks = {
+                c: (w, paged_attn.chunk_blocks(w, self.block_size, slab),
+                    windows[c])
+                for c, w in self._widths.items()}
             self._state = lm.paged_decode_state_spec(
                 cfg, slots, s_max, n_blocks=n_blocks,
                 block_size=self.block_size, abstract=False)
@@ -891,15 +902,23 @@ class ServeEngine:
 
     def _count_decode(self, rows: dict) -> None:
         """One decode step's counters: the rows that decode a token and
-        the context each attends; the KV blocks live requests hold, and
-        those among them that hold a written token (a block several rows
-        share counts once)."""
+        the context each attends, the table blocks the attention kernel's
+        walk reads for them and those holding their attended keys (once
+        per table class, as each layer of a class repeats its walk); the
+        KV blocks live requests hold, and those among them that hold a
+        written token (a block several rows share counts once)."""
         st = self.stats
+        ctx = np.array([s.request.prompt.shape[0] + len(s.tokens)
+                        for s in rows.values()], np.int64)
         st.decode_rows += len(rows)
-        st.decode_ctx_tokens += sum(s.request.prompt.shape[0] + len(s.tokens)
-                                    for s in rows.values())
+        st.decode_ctx_tokens += int(ctx.sum())
         if not self.paged or self.blocks is None:
             return
+        for w, p, window in self._walks.values():
+            read, live = paged_attn.walk_blocks(
+                ctx - 1, p=p, bs=self.block_size, w=w, window=window)
+            st.decode_kv_blocks_read += int(np.sum(read))
+            st.decode_kv_blocks_live += int(np.sum(live))
         st.observe_blocks(self.blocks.in_use)
         st.kv_reserved_block_steps += self.blocks.in_use
         written = np.zeros((self.slots, 1), np.int64)

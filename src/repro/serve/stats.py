@@ -33,10 +33,14 @@ class EngineStats:
     prefill_traces: int = 0
     decode_traces: int = 0
     # each decode step adds: the rows that decoded a token, the context
-    # they attended, the KV blocks live requests hold (the idle cached tier
-    # excluded) and those among them holding a written token
+    # they attended, the table blocks the paged attention kernel's walk
+    # reads for them and those holding their attended keys, the KV blocks
+    # live requests hold (the idle cached tier excluded) and those among
+    # them holding a written token
     decode_rows: int = 0
     decode_ctx_tokens: int = 0
+    decode_kv_blocks_read: int = 0
+    decode_kv_blocks_live: int = 0
     kv_reserved_block_steps: int = 0
     kv_live_block_steps: int = 0
     # the host's turns: the engine's own host time (inside step(), its

@@ -5,8 +5,11 @@ Two layers of contract:
 * kernel vs oracle — ``kernels/paged_attn.py`` (interpret mode) against the
   pure-jnp one-shot-softmax oracle ``kernels/ref.py::paged_decode`` across
   monotone tables, window rings (including recycling past the ring
-  capacity), ragged table tails (pos mid-block), GQA groups, bf16 and the
-  i8 KV pool; plus the fused XNOR linear against its unfused chain.  These
+  capacity), ragged table tails (pos mid-block), tables of several
+  chunks (live length mid-chunk and on a chunk edge, a prime width,
+  zeroed dead rows), GQA/MQA groups, bf16 and the i8 KV pool; the chunk
+  rule against a brute-force count; plus the fused XNOR linear against
+  its unfused chain.  These
   are allclose pins: the online-softmax recurrence equals one-shot softmax
   exactly in real arithmetic but not bit-for-bit in floats.
 
@@ -109,6 +112,85 @@ def test_kernel_gqa_groups():
     q, ck, cv, table, scale, out_scale = _case(kv=1, g=4)
     _parity(q, ck, cv, table, [2, 13, 31], window=0, scale=scale,
             out_scale=out_scale, tol=2e-5)
+
+
+# geometries whose tables span several chunks of P blocks (kernels/
+# paged_attn.py's chunk rule): W=48 of 16-token blocks walks 3 chunks of
+# P=16 (256 tokens each); a prime W=17 falls back to P=1
+CHUNKED = {
+    "mid_chunk": dict(w=48, pos=[300, 530, 700]),
+    "chunk_edge": dict(w=48, pos=[255, 256, 511]),
+    "prime_w": dict(w=17, pos=[0, 100, 271]),
+    "pos_0": dict(w=48, pos=[0, 0, 1]),
+    "dead_rows": dict(w=48, pos=[0, 400, 767], dead=(0, 2)),
+    "ring_before_wrap": dict(w=48, pos=[100, 500, 766], window=300),
+    "ring_at_wrap": dict(w=48, pos=[767, 768, 769], window=300),
+    "ring_after_wrap": dict(w=48, pos=[900, 1300, 2000], window=300),
+    "i8": dict(w=48, pos=[40, 300, 767], i8=True),
+    "i8_ring": dict(w=48, pos=[200, 800, 1600], window=300, i8=True),
+    "mqa": dict(w=48, pos=[17, 300, 600], kv=1, g=8),
+    "mqa_ring": dict(w=48, pos=[500, 900, 1500], window=600, kv=1, g=8),
+}
+
+
+@pytest.mark.parametrize("name", list(CHUNKED))
+def test_kernel_chunked_walk(name):
+    """Several chunks per row: the walk's dead-chunk rule and its double
+    buffer across chunks and rows, against the oracle.  Dead-slot rows are
+    zeroed tables (every column the trash block)."""
+    c = dict(CHUNKED[name])
+    pos, window, dead = c.pop("pos"), c.pop("window", 0), c.pop("dead", ())
+    q, ck, cv, table, scale, out_scale = _case(bs=16, **c)
+    if dead:
+        table = table.at[jnp.asarray(dead)].set(0)
+    bs, w = ck.shape[2], table.shape[1]
+    p = paged_attn.chunk_blocks(w, bs, ck.shape[1] * bs * ck.shape[3]
+                                * ck.dtype.itemsize)
+    assert p == (1 if w == 17 else 16)
+    _parity(q, ck, cv, table, pos, window=window, scale=scale,
+            out_scale=out_scale, tol=2e-5)
+
+
+def _brute_walk(pos, *, p, bs, w, window):
+    """(blocks a walk must read, blocks holding attended keys) of one row
+    at ``pos``, from the oracle's key mask."""
+    cap = w * bs
+    kslot = np.arange(cap)
+    if window:
+        valid = (pos % cap - kslot) % cap < min(window, pos + 1)
+    else:
+        valid = kslot <= pos
+    cols = valid.reshape(w, bs).any(axis=1)
+    chunks = cols.reshape(w // p, p).any(axis=1)
+    return int(chunks.sum()) * p, int(cols.sum())
+
+
+def test_chunk_rule():
+    """qwen3-4b's bf16 pool (W=160, 16-token blocks, 8 KV heads of dh
+    128) walks 10 chunks of P=16; a prime W falls back to P=1, a slab too
+    large for the scratch budget shrinks P, and the engine's block counts
+    match a brute-force count of the oracle's mask: live exactly, read
+    never below what a live chunk needs, and exactly that on monotone
+    tables."""
+    slab = 8 * 16 * 128 * 2
+    assert paged_attn.chunk_blocks(160, 16, slab) == 16
+    assert paged_attn.chunk_blocks(157, 16, slab) == 1
+    assert paged_attn.chunk_blocks(160, 16, 4 * slab) == 8
+    assert paged_attn.chunk_blocks(5, 8, 100) == 5
+    for w, bs, window in ((160, 16, 0), (12, 4, 0), (12, 4, 10),
+                          (160, 16, 2048), (17, 16, 0), (9, 5, 13)):
+        p = paged_attn.chunk_blocks(w, bs, slab)
+        pos = np.arange(0, 3 * w * bs if window else w * bs, 7)
+        read, live = paged_attn.walk_blocks(pos, p=p, bs=bs, w=w,
+                                            window=window)
+        for r, l, x in zip(read, live, pos):
+            need, held = _brute_walk(int(x), p=p, bs=bs, w=w, window=window)
+            assert l == held and r >= need
+            if not window:
+                assert r == need
+        # the kernel's scalar form and the engine's array form agree
+        assert int(paged_attn.live_chunks(jnp.int32(pos[-1]), p=p, bs=bs,
+                                          w=w)) * p == read[-1]
 
 
 def test_kernel_is_one_dispatch():
@@ -316,5 +398,6 @@ def test_random_block_table_layouts_pinned():
     """A deterministic slice of the property sweep so the layout-agnostic
     claim is exercised even where hypothesis is unavailable."""
     for b, w, bs, seed, ring in [(1, 1, 1, 0, False), (3, 4, 8, 1, False),
-                                 (4, 2, 16, 2, True), (2, 3, 5, 3, True)]:
+                                 (4, 2, 16, 2, True), (2, 3, 5, 3, True),
+                                 (3, 36, 16, 4, False), (2, 40, 16, 5, True)]:
         _random_layout_case(b, w, bs, seed, ring)

@@ -1,7 +1,8 @@
 """The engine's per-decode-step counters: rows that decode and the context
-they attend, KV blocks held against blocks written (a shared prefix block
-once), and the host's turn between a decode step's token read and the next
-decode dispatch."""
+they attend, the table blocks the attention kernel's walk reads for them
+against those holding their keys, KV blocks held against blocks written (a
+shared prefix block once), and the host's turn between a decode step's
+token read and the next decode dispatch."""
 
 from __future__ import annotations
 
@@ -16,7 +17,8 @@ import repro.configs as configs
 from repro.models import lm
 from repro.serve import EngineStats, Request, ServeEngine
 
-COUNTERS = ("decode_rows", "decode_ctx_tokens", "kv_reserved_block_steps",
+COUNTERS = ("decode_rows", "decode_ctx_tokens", "decode_kv_blocks_read",
+            "decode_kv_blocks_live", "kv_reserved_block_steps",
             "kv_live_block_steps", "host_turns")
 
 
@@ -48,15 +50,18 @@ def test_a_shared_prefix_block_counts_once(model):
     assert [_step(eng)["decode_rows"] for _ in range(3)] == [0, 0, 1]
     eng.submit(Request(rid=1, prompt=b, max_new_tokens=10))
     # B admitted sharing 2 blocks (5 reserved, 3 fresh), chunk 0 written:
-    # A's 22 positions hold 3 blocks, B's 24 hold 3, 2 of them A's
+    # A's 22 positions hold 3 blocks, B's 24 hold 3, 2 of them A's; the
+    # kernel's walk reads a row's 5-block table as one chunk
     d = _step(eng)
     assert eng.stats.prefix_shared_blocks == 2 and eng.stats.cow_copies == 0
     assert d == {"decode_rows": 1, "decode_ctx_tokens": 22,
+                 "decode_kv_blocks_read": 5, "decode_kv_blocks_live": 3,
                  "kv_reserved_block_steps": 4 + 3, "kv_live_block_steps": 4,
                  "host_turns": 1}
     # B's last chunk: both decode, A over 23 positions, B over 29 (4 blocks)
     d = _step(eng)
     assert d == {"decode_rows": 2, "decode_ctx_tokens": 23 + 29,
+                 "decode_kv_blocks_read": 5 + 5, "decode_kv_blocks_live": 3 + 4,
                  "kv_reserved_block_steps": 7, "kv_live_block_steps": 5,
                  "host_turns": 1}
     eng.run()
@@ -93,6 +98,7 @@ def test_dense_layout_counts_rows_and_no_blocks(model):
     assert st.decode_ctx_tokens == sum(8 + k for k in range(1, 5)) + \
         sum(8 + k for k in range(1, 7))
     assert st.kv_reserved_block_steps == st.kv_live_block_steps == 0
+    assert st.decode_kv_blocks_read == st.decode_kv_blocks_live == 0
 
 
 def test_decode_dispatches_left_the_counters():
