@@ -2,7 +2,11 @@
 
 Everything a cell needs is found by name (``BENCHMARK.json`` names it):
 
-  bench/configs/<config>.json    sizes as run, weight init, system arch
+  bench/configs/<config>.json    sizes as run under the published keys
+                                 (``build_cfg`` holds the system to them),
+                                 the weights' ``init`` rules and the roles
+                                 of the leaves (``model_weights``), the
+                                 system's ``arch`` and the ``reference``
   bench/traffic/<mix>.json       parameters of the one traffic generator
   bench/cells/<cell>.json        the cell's limits and run settings
   bench/metrics/<metric>.py      a reader per per-layer metric; a metric
@@ -31,6 +35,7 @@ import time
 
 import numpy as np
 
+import roofline
 import traffic_gen
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
@@ -145,25 +150,72 @@ def memory_peak_bytes() -> int:
 # ---------------------------------------------------------------------------
 
 
+# configuration-file keys -> the system config's fields that must hold the
+# same numbers: every key the yardstick (``roofline``) reads, and
+# ``rope_theta``.  A model that brings latent attention or shared experts
+# names its fields so; the layer plan (``first_k_dense_replace``,
+# ``moe_layer_freq``) is held to the system's block kinds instead.
+SYSTEM_FIELDS = {
+    "hidden_size": "d_model", "num_hidden_layers": "n_layers",
+    "num_attention_heads": "n_heads", "vocab_size": "vocab",
+    "num_key_value_heads": "n_kv_heads", "head_dim": "d_head",
+    "intermediate_size": "d_ff", "rope_theta": "rope_theta",
+    "n_routed_experts": "n_experts", "num_experts_per_tok": "top_k",
+    "moe_intermediate_size": "d_ff_expert",
+    "n_shared_experts": "n_shared_experts", "kv_lora_rank": "kv_lora_rank",
+    "qk_rope_head_dim": "qk_rope_head_dim",
+    "qk_nope_head_dim": "qk_nope_head_dim", "v_head_dim": "v_head_dim"}
+
+
 def build_cfg(conf: dict, overrides: dict | None = None):
-    """The system's config for ``conf``, checked against the file's sizes."""
+    """The system's config for ``conf`` (its ``arch``), held to the file,
+    fail-closed: the yardstick must find every key it reads; each key of
+    ``SYSTEM_FIELDS`` must equal the system's field at the number the
+    yardstick counts with (``roofline.value``: the file's, else its
+    default), a field the system lacks standing at that default; the
+    yardstick's MoE layers must be the system's layers of a kind with
+    routed experts; and ``binarized`` must match the ``+xnor`` axis."""
     import repro.configs as configs
+    from repro.models import blocks, registry  # noqa: F401  (blocks registers the kinds)
     cfg = configs.get(conf["arch"])
     if overrides:
         cfg = dataclasses.replace(cfg, **overrides)
-    want = {"hidden_size": cfg.d_model, "num_hidden_layers": cfg.n_layers,
-            "num_attention_heads": cfg.n_heads,
-            "num_key_value_heads": cfg.n_kv_heads, "head_dim": cfg.d_head,
-            "intermediate_size": cfg.d_ff, "vocab_size": cfg.vocab,
-            "rope_theta": cfg.rope_theta}
-    bad = {k: (conf[k], v) for k, v in want.items() if conf[k] != v}
+    try:
+        roofline.model_ops(conf, 1, 1)
+        roofline.kv_bytes_per_token(conf)
+    except KeyError as e:
+        raise Refused(f"{conf['arch']}: the config file lacks {e}, which "
+                      "the yardstick reads") from None
+    bad = {}
+    for key, field in SYSTEM_FIELDS.items():
+        try:
+            want = roofline.value(conf, key)
+        except KeyError:
+            continue
+        have = getattr(cfg, field, roofline.DEFAULTS.get(key))
+        if want != have:
+            bad[key] = (want, have)
+    moe = tuple(i for i, kind in enumerate(cfg.layer_kinds())
+                if registry.get(kind).contract.routed_experts)
+    if roofline.moe_layers(conf) != moe:
+        bad["moe layers"] = (roofline.moe_layers(conf), moe)
     if bad or bool(conf["binarized"]) != (cfg.quant == "xnor"):
         raise Refused(f"{conf['arch']} differs from its config file: {bad}")
     return cfg
 
 
 def kv_block_bytes(cfg, block_size: int) -> int:
-    return cfg.n_layers * 2 * cfg.n_kv_heads * block_size * cfg.d_head * 2
+    """Bytes one more block adds to the system's paged serving state, from
+    its own state spec: whatever its layers cache per token."""
+    import jax
+    from repro.models import lm
+
+    def state_bytes(n):
+        spec = lm.paged_decode_state_spec(cfg, 1, block_size, n_blocks=n,
+                                          block_size=block_size)
+        return sum(x.size * x.dtype.itemsize
+                   for x in jax.tree_util.tree_leaves(spec))
+    return state_bytes(2) - state_bytes(1)
 
 
 # HBM left unplanned besides what is in use when the pool is sized: room for
@@ -602,10 +654,8 @@ def measure(cell: Cell, cfg, seed: int, seconds: float, dev: dict, *,
     geometry = {"slots": eng.slots, "s_max": eng.s_max,
                 "block_size": eng.block_size, "n_blocks": eng.n_blocks,
                 "prefill_chunk": eng.prefill_chunk}
-    dims = {k: cell.conf[k] for k in (
-        "hidden_size", "num_hidden_layers", "num_attention_heads",
-        "num_key_value_heads", "head_dim", "intermediate_size",
-        "vocab_size", "binarized")}
+    dims = {k: v for k, v in cell.conf.items()
+            if isinstance(v, (bool, int, float))}
     win = Window(raw["t_open"], raw["t_close"], raw["recs"],
                  raw["stats_open"], raw["stats_close"], raw["decode_log"],
                  raw["steps"], dims, geometry, dev["peaks"],
