@@ -16,6 +16,7 @@ from repro.configs.phi4_mini_3_8b import CONFIG as phi4_mini_3_8b
 from repro.configs.qwen3_14b import CONFIG as qwen3_14b
 from repro.configs.xlstm_350m import CONFIG as xlstm_350m
 from repro.configs.llama4_scout_17b_a16e import CONFIG as llama4_scout_17b_a16e
+from repro.configs.moonlight_16b_a3b import CONFIG as moonlight_16b_a3b
 from repro.configs.moonshot_v1_16b_a3b import CONFIG as moonshot_v1_16b_a3b
 from repro.configs.recurrentgemma_2b import CONFIG as recurrentgemma_2b
 from repro.configs.llama_3_2_vision_11b import CONFIG as llama_3_2_vision_11b
@@ -26,7 +27,8 @@ ALL: dict[str, ArchConfig] = {
     c.name: c
     for c in [
         qwen2_7b, qwen3_4b, phi4_mini_3_8b, qwen3_14b, xlstm_350m,
-        llama4_scout_17b_a16e, moonshot_v1_16b_a3b, recurrentgemma_2b,
+        llama4_scout_17b_a16e, moonlight_16b_a3b, moonshot_v1_16b_a3b,
+        recurrentgemma_2b,
         llama_3_2_vision_11b, whisper_tiny, xnor_cnn,
     ]
 }

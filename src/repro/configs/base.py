@@ -34,16 +34,36 @@ class ArchConfig:
     rope_theta: float = 1e6
     local_window: int = 2048       # window for "local" blocks
 
-    # depth plan: `pattern` tiles across depth; leftover layers take the
+    norm_eps: float = 1e-6         # every RMSNorm's epsilon
+
+    # latent attention (MLA, no q compression): each token caches one
+    # vector of kv_lora_rank + qk_rope_head_dim values per layer; 0 => GQA
+    kv_lora_rank: int = 0
+    qk_rope_head_dim: int = 0
+    qk_nope_head_dim: int = 0
+    v_head_dim: int = 0
+
+    # depth plan: the first `first_dense` layers are dense ("attn"); then
+    # `pattern` tiles the rest of the depth, leftover layers taking the
     # pattern prefix.  Each maximal run of equal kinds becomes one scanned
     # segment (see models/blocks.py).
+    first_dense: int = 0
     pattern: tuple = ("attn",)
 
-    # MoE
+    # MoE: the router chooses top_k of router_experts (0 => n_experts);
+    # this layer holds the first n_experts of them and computes their part
+    # of the result, dropless.  Gates: "softmax" over the router's logits,
+    # or "sigmoid" scores chosen with a per-expert bias (DeepSeek-V3's
+    # noaux_tc) — either normalised over the k chosen and times
+    # routed_scale.  n_shared_experts run as one SwiGLU FFN of that many
+    # times d_ff_expert on every token.
     n_experts: int = 0
+    router_experts: int = 0
     top_k: int = 0
     d_ff_expert: int = 0
-    capacity_factor: float = 1.25
+    router_score: str = "softmax"
+    routed_scale: float = 1.0
+    n_shared_experts: int = 0
     router_aux_weight: float = 0.01
 
     # cross-attention context (vlm) / encoder-decoder (audio)
@@ -79,11 +99,13 @@ class ArchConfig:
         if self.d_head == 0:
             object.__setattr__(self, "d_head", self.d_model // self.n_heads)
         assert self.n_heads % self.n_kv_heads == 0, (self.n_heads, self.n_kv_heads)
+        assert self.router_score in ("softmax", "sigmoid"), self.router_score
 
     # --- depth plan ---------------------------------------------------------
     def layer_kinds(self) -> list[str]:
-        reps = -(-self.n_layers // len(self.pattern))
-        return list((self.pattern * reps)[: self.n_layers])
+        rest = self.n_layers - self.first_dense
+        reps = -(-rest // len(self.pattern))
+        return ["attn"] * self.first_dense + list((self.pattern * reps)[:rest])
 
     def segments(self) -> list[tuple[str, int]]:
         """Maximal runs of equal block kinds -> scanned segments."""
@@ -109,6 +131,33 @@ class ArchConfig:
         return self.n_heads // self.n_kv_heads
 
     @property
+    def latent(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def cache_heads(self) -> int:
+        """Heads of the KV cache: one latent vector, or the KV heads."""
+        return 1 if self.latent else self.n_kv_heads
+
+    @property
+    def cache_k_dim(self) -> int:
+        """Width of a cached key: d_head, or the latent and its rotary key
+        padded to whole 128-lane tiles (576 -> 640), as the chip lays a
+        576-wide array out anyway; the pad lanes hold zeros."""
+        if self.latent:
+            return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+        return self.d_head
+
+    @property
+    def cache_v_dim(self) -> int:
+        """Width of a cached value: none where the latent serves as both."""
+        return 0 if self.latent else self.d_head
+
+    @property
+    def n_router_experts(self) -> int:
+        return self.router_experts or self.n_experts
+
+    @property
     def padded_vocab(self) -> int:
         """Vocab rounded up to 256 so embedding/head shard over 16-way TP
         (standard practice; pad tokens never appear as labels)."""
@@ -131,7 +180,13 @@ class ArchConfig:
             name=self.name + "-smoke",
         )
         if self.n_experts:
-            scale.update(n_experts=4, top_k=min(self.top_k, 2), d_ff_expert=32)
+            scale.update(n_experts=4, top_k=min(self.top_k, 2), d_ff_expert=32,
+                         router_experts=8 if self.router_experts else 0)
+        if self.latent:
+            scale.update(kv_lora_rank=32, qk_rope_head_dim=8,
+                         qk_nope_head_dim=16, v_head_dim=16)
+        if self.first_dense:
+            scale.update(first_dense=1)
         if self.n_ctx_tokens:
             scale.update(n_ctx_tokens=16)
         if self.encoder_layers:
@@ -139,6 +194,7 @@ class ArchConfig:
         # keep the full pattern so every block kind is exercised
         if len(self.pattern) > 1:
             scale["n_layers"] = len(self.pattern)
+        scale["n_layers"] += scale.get("first_dense", 0)
         scale.update(over)
         return dataclasses.replace(self, **scale)
 

@@ -1,8 +1,13 @@
-"""Moonlight-16B-A3B [hf:moonshotai/Moonlight-16B-A3B; hf]: MoE 64e top-6.
+"""A generic softmax-routed MoE preset at Moonlight-like widths: every layer
+MoE, 64 experts of 1,408, top-6, GQA with 16 KV heads, no shared experts,
+no latent cache, no leading dense layer.
 
-DeepSeek-V3-style fine-grained experts (d_ff=1408 per expert); the
-assignment specifies 64 experts, top-6 routing, GQA kv=16 (== n_heads:
-effectively MHA).
+It is *not* Moonlight-16B-A3B, whose published config this system serves
+as ``moonlight-16b-a3b`` (``configs/moonlight_16b_a3b.py``: 27 layers,
+latent attention, sigmoid routing, shared experts, a dense first layer).
+It stays registered because the benchmark's CPU tests of a MoE tree added
+as files (``bench/tests/test_bench_any_config.py``) build their tiny
+softmax-routed model on it.
 """
 from repro.configs.base import ArchConfig
 

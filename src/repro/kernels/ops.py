@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from repro.core import bitpack
 from repro.kernels import bulk as _bulk
 from repro.kernels import cipher as _cipher
+from repro.kernels import moe_ffn as _moe_ffn
 from repro.kernels import pack as _pack
 from repro.kernels import paged_attn as _paged_attn
 from repro.kernels import parity as _parity
@@ -199,21 +200,37 @@ def digest(buf: jnp.ndarray, digest_width: int = 128, impl: str = "auto",
 
 
 def paged_decode(q, ck, cv, table, pos, *, window: int = 0,
-                 scale: float = 1.0, out_scale: float = 1.0,
+                 scale: float = 1.0, out_scale: float = 1.0, v_dim: int = 0,
                  impl: str = "auto") -> jnp.ndarray:
     """Fused block-paged decode attention (DESIGN.md §18).
 
     ``impl="ref"`` runs the one-shot oracle :func:`ref.paged_decode`; the
     kernel is interpreted only when ``interpret`` is asked for.  Shapes as
-    in :func:`repro.kernels.paged_attn.paged_decode_attention`.
+    in :func:`repro.kernels.paged_attn.paged_decode_attention`; a latent
+    pool passes ``cv=None`` and ``v_dim``.
     """
     impl = _resolve(impl)
     if impl == "ref":
         return ref.paged_decode(q, ck, cv, table, pos, window=window,
-                                scale=scale, out_scale=out_scale)
+                                scale=scale, out_scale=out_scale, v_dim=v_dim)
     return _paged_attn.paged_decode_attention(
         q, ck, cv, table, pos, window=window, scale=scale,
-        out_scale=out_scale, interpret=(impl == "interpret"))
+        out_scale=out_scale, v_dim=v_dim, interpret=(impl == "interpret"))
+
+
+def moe_expert_ffn(x, w1, w3, w2, layer, tile_expert, tile_used, *,
+                   tm: int, impl: str = "auto") -> jnp.ndarray:
+    """Grouped SwiGLU FFN over expert-sorted row tiles (the held experts of
+    layer ``layer`` of layer-stacked weights).  Shapes as in
+    :func:`repro.kernels.moe_ffn.moe_expert_ffn`; ``impl="ref"`` runs
+    :func:`ref.moe_expert_ffn`."""
+    impl = _resolve(impl)
+    if impl == "ref":
+        return ref.moe_expert_ffn(x, w1, w3, w2, layer, tile_expert,
+                                  tile_used, tm=tm)
+    return _moe_ffn.moe_expert_ffn(x, w1, w3, w2, layer, tile_expert,
+                                   tile_used, tm=tm,
+                                   interpret=(impl == "interpret"))
 
 
 def bulk_op(a: jnp.ndarray, b: jnp.ndarray, op: str = "xor",

@@ -16,7 +16,8 @@ Block-table walk
 Grid is (B, W / P): one grid step handles a *chunk* of P consecutive table
 columns for every KV head at once.  One table entry addresses a contiguous
 (KV, bs, dh) slab of the (n_blocks, KV, bs, dh) pool, so a chunk is P slab
-copies each of K and V, issued by hand (``pltpu.make_async_copy`` from the
+copies each of K and V (of the one latent slab, for a latent pool: see
+below), issued by hand (``pltpu.make_async_copy`` from the
 ``pl.ANY`` pool) into a (2, P, KV, bs, dh) VMEM scratch: double-buffered,
 the copies of the next live chunk — the same row's next chunk, else the
 next row's first — are in flight while this one is computed.  The table and
@@ -55,6 +56,18 @@ Masking within a live chunk (bit-for-bit the unfused chain's semantics):
 A live chunk whose keys are all masked (a ring chunk out of the window) is
 not folded in (``@pl.when``): the guard for the online-softmax all-NEG_INF
 edge case (see online_softmax.update).
+
+Latent pools (MLA, ``v_dim > 0``): one slab per token of kv_lora_rank +
+qk_rope_head_dim values (576 for Moonlight), the compressed vector then
+its rotary key, under a single KV "head".  The caller folds the key half
+of ``kv_b`` into q, so all query heads score against the slab as it lies
+(q_lat·c + q_rope·k_rope in one product), and the weighted sum is taken
+over the slab's first ``v_dim`` lanes (c itself): each live slab is read
+once, there is no V pool.  The value half of ``kv_b`` is applied by the
+caller after the sum.  576 lanes are 4.5 tiles of 128, and a slab copy must
+be tile-aligned: the pool stores the vector zero-padded to 640 lanes (the
+chip lays a 576-wide array out at 640 anyway), 11% more bytes than the
+yardstick counts.
 
 Numerics: the online recurrence equals one-shot masked softmax exactly in
 real arithmetic but not bit-for-bit in floats (association/rounding).  The
@@ -95,6 +108,14 @@ def chunk_blocks(w: int, bs: int, slab_bytes: int) -> int:
                                              <= SCRATCH_BYTES)))
 
 
+def slab_bytes(kv: int, bs: int, dk: int, itemsize: int, pools: int) -> int:
+    """The ``slab_bytes`` :func:`chunk_blocks` takes for a pool of
+    ``pools`` slabs per block (K and V, or one latent slab): its rule
+    budgets a K and a V buffer, so one latent slab counts as half a
+    pair."""
+    return kv * bs * dk * itemsize * pools // 2
+
+
 def live_chunks(pos, *, p: int, bs: int, w: int):
     """Chunks of P table blocks the walk reads for a row at ``pos``: the
     first ``min(pos // (P·bs) + 1, W / P)``.  Works on a traced scalar (the
@@ -113,11 +134,16 @@ def walk_blocks(pos, *, p: int, bs: int, w: int, window: int):
     return live_chunks(pos, p=p, bs=bs, w=w) * p, live
 
 
-def _kernel(table_ref, pos_ref, q_ref, ck_hbm, cv_hbm, o_ref, kbuf, vbuf,
-            sems, slot_ref, m_ref, l_ref, acc_ref, *, bs: int, p: int,
-            w_total: int, window: int, scale: float, out_scale: float):
+def _kernel(table_ref, pos_ref, q_ref, *refs, bs: int, p: int, w_total: int,
+            window: int, scale: float, out_scale: float, v_dim: int):
+    # a latent pool (v_dim > 0) is one slab per token whose first v_dim
+    # lanes are the values; a GQA pool is a K slab and a V slab
+    n_pools = 1 if v_dim else 2
+    pools, (o_ref,) = refs[:n_pools], refs[n_pools:n_pools + 1]
+    bufs = refs[n_pools + 1:2 * n_pools + 1]
+    sems, slot_ref, m_ref, l_ref, acc_ref = refs[2 * n_pools + 1:]
     b, i = pl.program_id(0), pl.program_id(1)
-    kv, dh = q_ref.shape[1], q_ref.shape[3]
+    kv, dk = q_ref.shape[1], q_ref.shape[3]
     span = p * bs
     pos = pos_ref[b]
     n = live_chunks(pos, p=p, bs=bs, w=w_total)
@@ -127,11 +153,11 @@ def _kernel(table_ref, pos_ref, q_ref, ck_hbm, cv_hbm, o_ref, kbuf, vbuf,
     # an unrolled body took seconds to trace; the head loop unrolls as it
     # is lowered (its iterations overlap, ~25% faster than a rolled one)
     def each_copy(row, chunk, slot, op):
-        """``op`` on the K and V slab copy of each block of one chunk into
+        """``op`` on each pool's slab copy of each block of one chunk into
         buffer ``slot``."""
         def body(j, carry):
             blk = table_ref[row, chunk * p + j]
-            for t, (pool, buf) in enumerate(((ck_hbm, kbuf), (cv_hbm, vbuf))):
+            for t, (pool, buf) in enumerate(zip(pools, bufs)):
                 op(pltpu.make_async_copy(pool.at[blk], buf.at[slot, j],
                                          sems.at[t, slot]))
             return carry
@@ -177,9 +203,12 @@ def _kernel(table_ref, pos_ref, q_ref, ck_hbm, cv_hbm, o_ref, kbuf, vbuf,
             valid = kslot <= pos
 
         def update(h):
-            q = q_ref[0, h].astype(jnp.float32)                    # (G, dh)
-            k = kbuf[slot, :, h].astype(jnp.float32).reshape(span, dh)
-            v = vbuf[slot, :, h].astype(jnp.float32).reshape(span, dh)
+            q = q_ref[0, h].astype(jnp.float32)                    # (G, dk)
+            k = bufs[0][slot, :, h].astype(jnp.float32).reshape(span, dk)
+            if v_dim:
+                v = k[:, :v_dim]
+            else:
+                v = bufs[1][slot, :, h].astype(jnp.float32).reshape(span, dk)
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
             s = jnp.where(valid, s * scale, NEG_INF)               # (G, span)
@@ -194,58 +223,63 @@ def _kernel(table_ref, pos_ref, q_ref, ck_hbm, cv_hbm, o_ref, kbuf, vbuf,
 
 
 @functools.partial(jax.jit, static_argnames=("window", "scale", "out_scale",
-                                             "interpret"))
+                                             "v_dim", "interpret"))
 def paged_decode_attention(q, ck, cv, table, pos, *, window: int = 0,
                            scale: float = 1.0, out_scale: float = 1.0,
+                           v_dim: int = 0,
                            interpret: bool = False) -> jnp.ndarray:
     """One fused decode-attention dispatch over the block-paged pool.
 
-    q       (B, KV, G, dh)  — this step's queries, compact GQA form
-    ck, cv  (n_blocks, KV, bs, dh) — the shared pool (f32/bf16 or int8),
+    q       (B, KV, G, dk)  — this step's queries, compact GQA form
+    ck, cv  (n_blocks, KV, bs, dk) — the shared pool (f32/bf16 or int8),
             with this step's K/V already scattered in (the scatter is a
             (B,) token write, not part of the HBM-bound gather chain)
     table   (B, W) int32    — per-slot physical block ids
     pos     (B,)  int32     — per-slot current absolute position
     window  0 for monotone tables; the local window size for block rings
-    scale   QK scale (``dh**-0.5``, with the i8 fixed-point factor folded
+    scale   QK scale (``dk**-0.5``, with the i8 fixed-point factor folded
             in for int8 pools); ``out_scale`` is the PV-side i8 correction.
+    v_dim   0, or a latent pool: ``cv`` is None, the KV "head" is one
+            latent vector per token and the values are its first ``v_dim``
+            lanes, so each live slab is read once for both.
 
-    Returns (B, KV, G, dh) in q.dtype.  VMEM per step: the double-buffered
-    (2, P, KV, bs, dh) K and V chunk scratch (at most ``SCRATCH_BYTES``;
-    2 MB for qwen3-4b's bf16 pool at P=16), the (KV, G, dh) q and out tiles
-    and the (KV, G, 1)/(KV, G, dh) f32 accumulators.
+    Returns (B, KV, G, dv) in q.dtype (dv = ``v_dim`` or dk).  VMEM per
+    step: the double-buffered (2, P, KV, bs, dk) chunk scratch of each
+    pool (at most ``SCRATCH_BYTES``; 2 MB for qwen3-4b's bf16 pool at
+    P=16), the q and out tiles and the f32 accumulators.
     """
-    b, kv, g, dh = q.shape
+    b, kv, g, dk = q.shape
     bs = ck.shape[2]
+    dv = v_dim or dk
     w_total = table.shape[1]
-    p = chunk_blocks(w_total, bs, kv * bs * dh * ck.dtype.itemsize)
+    pools = (ck,) if v_dim else (ck, cv)
+    p = chunk_blocks(w_total, bs,
+                     slab_bytes(kv, bs, dk, ck.dtype.itemsize, len(pools)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, w_total // p),
         in_specs=[
-            pl.BlockSpec((1, kv, g, dh), lambda b, i, tbl, pos: (b, 0, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((1, kv, g, dk), lambda b, i, tbl, pos: (b, 0, 0, 0)),
+            *[pl.BlockSpec(memory_space=pl.ANY) for _ in pools],
         ],
-        out_specs=pl.BlockSpec((1, kv, g, dh),
+        out_specs=pl.BlockSpec((1, kv, g, dv),
                                lambda b, i, tbl, pos: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, p, kv, bs, dh), ck.dtype),
-            pltpu.VMEM((2, p, kv, bs, dh), cv.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
+            *[pltpu.VMEM((2, p, kv, bs, dk), x.dtype) for x in pools],
+            pltpu.SemaphoreType.DMA((len(pools), 2)),
             pltpu.SMEM((1,), jnp.int32),
             pltpu.VMEM((kv, g, 1), jnp.float32),
             pltpu.VMEM((kv, g, 1), jnp.float32),
-            pltpu.VMEM((kv, g, dh), jnp.float32),
+            pltpu.VMEM((kv, g, dv), jnp.float32),
         ],
     )
     return pl.pallas_call(
         functools.partial(_kernel, bs=bs, p=p, w_total=w_total, window=window,
-                          scale=scale, out_scale=out_scale),
+                          scale=scale, out_scale=out_scale, v_dim=v_dim),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kv, g, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kv, g, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         name="paged_decode_attention",
         interpret=interpret,
-    )(table, pos, q, ck, cv)
+    )(table, pos, q, *pools)

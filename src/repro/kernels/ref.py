@@ -58,24 +58,26 @@ def pack(x: jnp.ndarray):
 # Fused paged-decode attention (kernels/paged_attn.py, DESIGN.md §18)
 # ---------------------------------------------------------------------------
 
-def paged_decode(q: jnp.ndarray, ck: jnp.ndarray, cv: jnp.ndarray,
-                 table: jnp.ndarray, pos: jnp.ndarray, *, window: int = 0,
-                 scale: float = 1.0, out_scale: float = 1.0) -> jnp.ndarray:
+def paged_decode(q: jnp.ndarray, ck: jnp.ndarray, cv, table: jnp.ndarray,
+                 pos: jnp.ndarray, *, window: int = 0, scale: float = 1.0,
+                 out_scale: float = 1.0, v_dim: int = 0) -> jnp.ndarray:
     """One-shot masked-softmax oracle for the fused paged decode kernel.
 
     Same semantics as the C == 1 path of ``models/attention.py::
     paged_attention`` after the scatter: gather the pool through the block
     table, score, mask (monotone or window-ring), softmax, PV.  ``q`` is
-    (B, KV, G, dh); ``ck``/``cv`` are the (n_blocks, KV, bs, dh) pool
+    (B, KV, G, dk); ``ck``/``cv`` are the (n_blocks, KV, bs, dk) pool
     (any dtype incl. int8 — decoded to f32 here, the fixed-point factors
-    arrive folded into ``scale``/``out_scale``); returns (B, KV, G, dh)
-    in q.dtype.
+    arrive folded into ``scale``/``out_scale``); with ``v_dim`` the pool is
+    latent, ``cv`` is None and the values are ``ck``'s first ``v_dim``
+    lanes.  Returns (B, KV, G, dv) in q.dtype.
     """
-    b, kv, g, dh = q.shape
+    b, kv, g, dk = q.shape
     bs = ck.shape[2]
     cap = table.shape[1] * bs
-    gk = jnp.moveaxis(ck[table], 1, 2).reshape(b, kv, cap, dh)
-    gv = jnp.moveaxis(cv[table], 1, 2).reshape(b, kv, cap, dh)
+    gk = jnp.moveaxis(ck[table], 1, 2).reshape(b, kv, cap, dk)
+    gv = (gk[..., :v_dim] if v_dim
+          else jnp.moveaxis(cv[table], 1, 2).reshape(b, kv, cap, dk))
     s = jnp.einsum("bkgd,bksd->bkgs", q.astype(jnp.float32),
                    gk.astype(jnp.float32)) * scale
     kslot = jnp.arange(cap, dtype=jnp.int32)
@@ -89,6 +91,28 @@ def paged_decode(q: jnp.ndarray, ck: jnp.ndarray, cv: jnp.ndarray,
     w = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bkgs,bksd->bkgd", w, gv.astype(jnp.float32))
     return (out * out_scale).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Grouped expert FFN (kernels/moe_ffn.py)
+# ---------------------------------------------------------------------------
+
+def moe_expert_ffn(x, w1, w3, w2, layer, tile_expert, tile_used, *,
+                   tm: int) -> jnp.ndarray:
+    """Oracle for the grouped expert kernel: each tile of ``tm`` rows
+    through its expert's SwiGLU FFN in layer ``layer[0]`` of the stacked
+    weights (f32 products, the hidden in the weights' type); rows of unused
+    tiles are zero."""
+    w1, w3, w2 = (w[layer[0]] for w in (w1, w3, w2))
+    n, d = x.shape
+    xt = x.reshape(n // tm, tm, d)
+    h = (jax.nn.silu(jnp.einsum("tmd,tdf->tmf", xt, w1[tile_expert],
+                                preferred_element_type=jnp.float32))
+         * jnp.einsum("tmd,tdf->tmf", xt, w3[tile_expert],
+                      preferred_element_type=jnp.float32))
+    y = jnp.einsum("tmf,tfd->tmd", h.astype(w2.dtype), w2[tile_expert],
+                   preferred_element_type=jnp.float32)
+    return jnp.where(tile_used[:, None, None] == 1, y, 0.0).reshape(n, d)
 
 
 # ---------------------------------------------------------------------------

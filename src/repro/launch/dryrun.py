@@ -167,9 +167,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, **overrides) -> dict:
     kv_dtype = overrides.pop("kv_dtype", None)
     if kv_dtype:
         cfg = dataclasses.replace(cfg, kv_cache_dtype=kv_dtype)
-    cap = overrides.pop("capacity_factor", None)
-    if cap:
-        cfg = dataclasses.replace(cfg, capacity_factor=cap)
     mlstm_chunk = overrides.pop("mlstm_chunk", None)
     if mlstm_chunk:
         cfg = dataclasses.replace(cfg, mlstm_chunk=mlstm_chunk)
@@ -238,7 +235,6 @@ def main() -> int:
     ap.add_argument("--fsdp-off", action="store_true",
                     help="serving config: replicate params over data axis "
                          "(TP-only)")
-    ap.add_argument("--capacity-factor", type=float, default=None)
     ap.add_argument("--mlstm-chunk", type=int, default=None)
     ap.add_argument("--unroll", action="store_true",
                     help="unroll layer stacks for exact cost/collective "
@@ -268,7 +264,6 @@ def main() -> int:
                                    grad_compress=args.grad_compress,
                                    unroll=args.unroll,
                                    kv_dtype=args.kv_dtype,
-                                   capacity_factor=args.capacity_factor,
                                    mlstm_chunk=args.mlstm_chunk,
                                    acc_bf16=args.acc_bf16,
                                    fsdp_pods=args.fsdp_pods,

@@ -16,6 +16,20 @@ Layout conventions:
   paged cache  (n_blocks, KV, block_size, dh) — the block-paged serve form
                (DESIGN.md §14): a shared pool with no batch axis, addressed
                through per-slot int32 block tables.
+Latent attention (MLA, ``cfg.kv_lora_rank > 0``, DeepSeek-V2/V3 without q
+compression): q is projected per head (nope and rotary parts); ``wkv_a``
+gives a kv_lora_rank vector c, normalised by its own RMSNorm, and one
+rotary key shared by the heads; ``wkv_b`` expands c to per-head nope keys
+and values.  Training and prefill run the expanded form.  Caches hold the
+latent alone: one (kv_lora_rank + qk_rope_head_dim) vector per token under
+a single cache head (``cfg.cache_heads``), with a zero-width V; the
+vector is stored zero-padded to ``cfg.cache_k_dim`` lanes, whole tiles of
+128 (576 -> 640: the chip's layout pads a 576-wide pool to 640 anyway, and
+the kernel's slab copies must be tile-aligned).  Attention
+over a cache folds ``wkv_b``'s key half into q (scores q_lat·c +
+q_rope·k_rope) and applies its value half after the weighted sum of c.
+Rotary embeddings follow the published modelling code: the rotary lanes
+are de-interleaved (even lanes, then odd) before the rotate-half form.
 Scores accumulate in f32; softmax is f32 with max subtraction.
 """
 
@@ -45,6 +59,21 @@ def i8_encode(cfg, x: jnp.ndarray) -> jnp.ndarray:
 
 def attn_defs(cfg, n: int, cross: bool = False) -> dict:
     d, H, KV, dh = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_head
+    if cfg.latent:
+        r, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+        rope, dv = cfg.qk_rope_head_dim, cfg.v_head_dim
+        return {
+            "wq": ParamDef((n, d, H * (nope + rope)), (None, "fsdp", "tp"),
+                           cfg.dtype, binarize=True),
+            "wkv_a": ParamDef((n, d, r + rope), (None, "fsdp", None),
+                              cfg.dtype, binarize=True),
+            "kv_norm": ParamDef((n, r), (None, None), jnp.float32,
+                                init="ones"),
+            "wkv_b": ParamDef((n, r, H * (nope + dv)), (None, None, "tp"),
+                              cfg.dtype, binarize=True),
+            "wo": ParamDef((n, H * dv, d), (None, "tp", "fsdp"), cfg.dtype,
+                           binarize=True),
+        }
     defs = {
         "wq": ParamDef((n, d, H * dh), (None, "fsdp", "tp"), cfg.dtype,
                        binarize=True),
@@ -75,7 +104,7 @@ def _project_q(cfg, p, x, positions):
     q = layers.linear(x, p["wq"], cfg.quant, p.get("bq"))
     q = q.reshape(b, s, cfg.n_heads, cfg.d_head)
     if cfg.qk_norm:
-        q = layers.rms_norm(q, p["q_norm"])
+        q = layers.rms_norm(q, p["q_norm"], cfg.norm_eps)
     if positions is not None:
         q = layers.apply_rope(q, positions, cfg.rope_theta)
     return constrain(q, "batch", None, "tp", None)
@@ -89,10 +118,101 @@ def _project_kv(cfg, p, x, positions):
     k = k.reshape(b, s, cfg.n_kv_heads, cfg.d_head)
     v = v.reshape(b, s, cfg.n_kv_heads, cfg.d_head)
     if cfg.qk_norm:
-        k = layers.rms_norm(k, p["k_norm"])
+        k = layers.rms_norm(k, p["k_norm"], cfg.norm_eps)
     if positions is not None:
         k = layers.apply_rope(k, positions, cfg.rope_theta)
     return k, v
+
+
+def _rope_deinterleaved(x, positions, theta):
+    """Rotary embedding of MLA's rotary lanes, as the published modelling
+    code applies it: even lanes, then odd, then the rotate-half form."""
+    x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+    return layers.apply_rope(x, positions, theta)
+
+
+def _mla_q(cfg, p, x, positions):
+    """-> q_nope (B, S, H, nope), q_rope (B, S, H, rope), the latter roped."""
+    b, s, _ = x.shape
+    q = layers.linear(x, p["wq"], cfg.quant).reshape(
+        b, s, cfg.n_heads, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+    q_nope, q_rope = jnp.split(q, [cfg.qk_nope_head_dim], axis=-1)
+    return q_nope, _rope_deinterleaved(q_rope, positions, cfg.rope_theta)
+
+
+def mla_latent(cfg, p, x, positions):
+    """-> (B, S, kv_lora_rank + rope): the normed latent c, then the roped
+    shared key — what a latent cache holds per token."""
+    kv = layers.linear(x, p["wkv_a"], cfg.quant)
+    c, kr = jnp.split(kv, [cfg.kv_lora_rank], axis=-1)
+    c = layers.rms_norm(c, p["kv_norm"], cfg.norm_eps)
+    kr = _rope_deinterleaved(kr[:, :, None], positions, cfg.rope_theta)
+    return jnp.concatenate([c, kr[:, :, 0].astype(c.dtype)], axis=-1)
+
+
+def _pad_lanes(cfg, x):
+    """The last axis zero-padded to ``cfg.cache_k_dim`` (a latent cache's
+    stored width)."""
+    pad = cfg.cache_k_dim - x.shape[-1]
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+
+def _mla_expand(cfg, p, lat):
+    """Latents (B, S, r + rope) -> k (B, S, H, nope + rope), v (B, S, H, dv)."""
+    b, s, _ = lat.shape
+    c, kr = jnp.split(lat, [cfg.kv_lora_rank], axis=-1)
+    kv = layers.linear(c, p["wkv_b"], cfg.quant).reshape(
+        b, s, cfg.n_heads, cfg.qk_nope_head_dim + cfg.v_head_dim)
+    k_nope, v = jnp.split(kv, [cfg.qk_nope_head_dim], axis=-1)
+    kr = jnp.broadcast_to(kr[:, :, None], k_nope.shape[:3] + kr.shape[-1:])
+    return jnp.concatenate([k_nope, kr.astype(k_nope.dtype)], axis=-1), v
+
+
+def _mla_kv_b(cfg, p):
+    """``wkv_b`` as (r, H, nope + dv): float only (folding it into q
+    needs the weights, which a binarized layer does not apply linearly)."""
+    w = p["wkv_b"]
+    if cfg.quant != "none" or not isinstance(w, jax.Array):
+        raise NotImplementedError(
+            "latent attention over a cache folds wkv_b into q: float "
+            "weights only (quant='none')")
+    return w.reshape(cfg.kv_lora_rank, cfg.n_heads,
+                     cfg.qk_nope_head_dim + cfg.v_head_dim)
+
+
+def _mla_absorb_q(cfg, p, x, positions):
+    """-> (B, S, H, cache_k_dim): q with ``wkv_b``'s key half folded in, to
+    score against cached latents as they lie (zeros on the pad lanes)."""
+    q_nope, q_rope = _mla_q(cfg, p, x, positions)
+    w_uk = _mla_kv_b(cfg, p)[:, :, :cfg.qk_nope_head_dim]
+    q_lat = jnp.einsum("bshn,rhn->bshr", q_nope, w_uk,
+                       preferred_element_type=jnp.float32)
+    return _pad_lanes(cfg, jnp.concatenate([q_lat.astype(q_rope.dtype),
+                                            q_rope], axis=-1))
+
+
+def _mla_out(cfg, p, o_lat):
+    """Weighted sums of latents (B, S, H, r) -> the layer's output (B, S, d):
+    ``wkv_b``'s value half, then ``wo``."""
+    b, s = o_lat.shape[:2]
+    w_uv = _mla_kv_b(cfg, p)[:, :, cfg.qk_nope_head_dim:]
+    o = jnp.einsum("bshr,rhv->bshv", o_lat, w_uv,
+                   preferred_element_type=jnp.float32).astype(o_lat.dtype)
+    return layers.linear(o.reshape(b, s, cfg.n_heads * cfg.v_head_dim),
+                         p["wo"], cfg.quant)
+
+
+def mla_scale(cfg) -> float:
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+
+
+def project_cache(cfg, p, x, positions):
+    """-> (k, v) each (B, S, cache_heads, width) as a cache holds them: the
+    compact GQA K and V, or the latent vector and a zero-width V."""
+    if cfg.latent:
+        lat = _pad_lanes(cfg, mla_latent(cfg, p, x, positions))[:, :, None]
+        return lat, jnp.zeros(lat.shape[:3] + (0,), lat.dtype)
+    return _project_kv(cfg, p, x, positions)
 
 
 def _expand_kv(cfg, k, v):
@@ -106,8 +226,9 @@ def _expand_kv(cfg, k, v):
 
 
 def _sdpa(cfg, q, k, v, mask):
-    """MHA core: q (B,Sq,H,dh), k/v (B,Sk,H,dh), mask (1|B,Sq,Sk) or None."""
-    scale = cfg.d_head ** -0.5
+    """MHA core: q/k (B,Sq|Sk,H,dk), v (B,Sk,H,dv), mask (1|B,Sq,Sk) or
+    None; scaled by dk**-0.5."""
+    scale = q.shape[-1] ** -0.5
     scores = jnp.einsum("bqhd,bshd->bhqs", q, k,
                         preferred_element_type=jnp.float32) * scale
     if mask is not None:
@@ -144,8 +265,12 @@ def attention(cfg, p: dict, x: jnp.ndarray, *, causal: bool = True,
     b, s, _ = x.shape
     if positions is None:
         positions = jnp.arange(s)
-    q = _project_q(cfg, p, x, positions)
-    k, v = _expand_kv(cfg, *_project_kv(cfg, p, x, positions))
+    if cfg.latent:
+        q = jnp.concatenate(_mla_q(cfg, p, x, positions), axis=-1)
+        k, v = _mla_expand(cfg, p, mla_latent(cfg, p, x, positions))
+    else:
+        q = _project_q(cfg, p, x, positions)
+        k, v = _expand_kv(cfg, *_project_kv(cfg, p, x, positions))
 
     if not q_chunk or s <= q_chunk:
         mask = _causal_mask(s, s, 0, window) if causal else None
@@ -178,9 +303,10 @@ def attention(cfg, p: dict, x: jnp.ndarray, *, causal: bool = True,
                               for i in range(n_chunks)])
         else:
             _, outs = jax.lax.scan(chunk_fn, (), jnp.arange(n_chunks))
-        out = jnp.moveaxis(outs, 0, 1).reshape(b, s, cfg.n_heads, cfg.d_head)
+        out = jnp.moveaxis(outs, 0, 1).reshape(b, s, cfg.n_heads,
+                                               v.shape[-1])
 
-    out = out.reshape(b, s, cfg.n_heads * cfg.d_head)
+    out = out.reshape(b, s, cfg.n_heads * v.shape[-1])
     out = constrain(out, "batch", None, "tp")
     return constrain(layers.linear(out, p["wo"], cfg.quant),
                      "batch", None, None)
@@ -227,16 +353,38 @@ class KVCache(NamedTuple):
 
     @classmethod
     def zeros(cls, cfg, batch: int, s_max: int, dtype=None):
-        shp = (batch, cfg.n_kv_heads, s_max, cfg.d_head)
         dt = dtype or cfg.dtype
-        return cls(jnp.zeros(shp, dt), jnp.zeros(shp, dt))
+        return cls(*(jnp.zeros(s, dt) for s in _cache_shapes(cfg, batch,
+                                                            s_max)))
 
     @classmethod
     def abstract(cls, cfg, batch: int, s_max: int, dtype=None):
-        shp = (batch, cfg.n_kv_heads, s_max, cfg.d_head)
         dt = dtype or cfg.dtype
-        return cls(jax.ShapeDtypeStruct(shp, dt),
-                   jax.ShapeDtypeStruct(shp, dt))
+        return cls(*(jax.ShapeDtypeStruct(s, dt)
+                     for s in _cache_shapes(cfg, batch, s_max)))
+
+
+def _cache_shapes(cfg, lead: int, tokens: int):
+    """K and V shapes of a cache: (lead, cache_heads, tokens, width); a
+    latent cache's V is zero-width (the latent serves as both)."""
+    if cfg.latent and cfg.kv_cache_dtype == "i8":
+        raise NotImplementedError("a latent cache is bf16/f32 only")
+    base = (lead, cfg.cache_heads, tokens)
+    return base + (cfg.cache_k_dim,), base + (cfg.cache_v_dim,)
+
+
+def _latent_attend(cfg, p, q, ck, kv_valid, dtype):
+    """Attention of absorbed queries q (B, C, H, L) over latents ck
+    (B, cap, L), L = ``cfg.cache_k_dim``, under ``kv_valid`` (B, C, cap) -> output (B, C, d)."""
+    scores = jnp.einsum("bchd,bsd->bhcs", q, ck.astype(q.dtype),
+                        preferred_element_type=jnp.float32) * mla_scale(cfg)
+    scores = jnp.where(kv_valid[:, None], scores, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1)
+    # the weighted sum in f32, as the decode kernel takes it (the CPU has
+    # no bf16 x bf16 -> f32 product of a single query row)
+    o_lat = jnp.einsum("bhcs,bsr->bchr", probs,
+                       ck[..., :cfg.kv_lora_rank].astype(jnp.float32))
+    return _mla_out(cfg, p, o_lat.astype(dtype))
 
 
 def decode_attention(cfg, p: dict, x: jnp.ndarray, cache: KVCache,
@@ -257,6 +405,15 @@ def decode_attention(cfg, p: dict, x: jnp.ndarray, cache: KVCache,
     per_slot = pos.ndim == 1
     pos_b = jnp.broadcast_to(pos, (b,))
     positions = pos_b[:, None]
+    if cfg.latent:
+        lat = _pad_lanes(cfg, mla_latent(cfg, p, x, positions))[:, :, None]
+        ck = jax.vmap(lambda c, new, s: jax.lax.dynamic_update_slice_in_dim(
+            c, new, s, axis=1))(cache.k, jnp.moveaxis(lat, 1, 2).astype(
+                cache.k.dtype), pos_b)
+        valid = jnp.arange(s_max)[None, None, :] <= pos_b[:, None, None]
+        y = _latent_attend(cfg, p, _mla_absorb_q(cfg, p, x, positions),
+                           ck[:, 0], valid, x.dtype)
+        return y, KVCache(ck, cache.v)
     q = _project_q(cfg, p, x, positions)          # (B, 1, H, dh)
     q = q.reshape(b, 1, cfg.n_kv_heads, cfg.q_per_kv, cfg.d_head)
     k, v = _project_kv(cfg, p, x, positions)
@@ -327,16 +484,15 @@ class PagedKVCache(NamedTuple):
 
     @classmethod
     def zeros(cls, cfg, n_blocks: int, block_size: int, dtype=None):
-        shp = (n_blocks, cfg.n_kv_heads, block_size, cfg.d_head)
         dt = dtype or cfg.dtype
-        return cls(jnp.zeros(shp, dt), jnp.zeros(shp, dt))
+        return cls(*(jnp.zeros(s, dt) for s in _cache_shapes(
+            cfg, n_blocks, block_size)))
 
     @classmethod
     def abstract(cls, cfg, n_blocks: int, block_size: int, dtype=None):
-        shp = (n_blocks, cfg.n_kv_heads, block_size, cfg.d_head)
         dt = dtype or cfg.dtype
-        return cls(jax.ShapeDtypeStruct(shp, dt),
-                   jax.ShapeDtypeStruct(shp, dt))
+        return cls(*(jax.ShapeDtypeStruct(s, dt) for s in _cache_shapes(
+            cfg, n_blocks, block_size)))
 
     def copy_block(self, src, dst) -> "PagedKVCache":
         """Physical block copy ``dst := src`` in both pools — the device
@@ -381,6 +537,8 @@ def paged_attention(cfg, p: dict, x: jnp.ndarray, cache: PagedKVCache,
     cap = w * bs
     pos = jnp.asarray(pos, jnp.int32)
     qpos = jnp.broadcast_to(pos, (b,))[:, None] + jnp.arange(c)[None, :]
+    if cfg.latent:
+        return _paged_latent(cfg, p, x, cache, table, qpos, valid)
     q = _project_q(cfg, p, x, qpos)                  # (B, C, H, dh)
     q = q.reshape(b, c, cfg.n_kv_heads, cfg.q_per_kv, cfg.d_head)
     k, v = _project_kv(cfg, p, x, qpos)              # (B, C, KV, dh)
@@ -444,3 +602,30 @@ def paged_attention(cfg, p: dict, x: jnp.ndarray, cache: PagedKVCache,
         out = out / cfg.kv_i8_scale
     out = out.reshape(b, c, cfg.n_heads * cfg.d_head)
     return layers.linear(out, p["wo"], cfg.quant), PagedKVCache(ck, cv)
+
+
+def _paged_latent(cfg, p, x, cache: PagedKVCache, table, qpos, valid):
+    """:func:`paged_attention` over a latent pool (monotone tables): the
+    token's latent is scattered into its slot, then the absorbed queries
+    attend over the table's latents — through the Pallas kernel at decode
+    where the kernels are on, else the gather chain, its twin."""
+    b, c, _ = x.shape
+    bs, w = cache.k.shape[2], table.shape[1]
+    q = _mla_absorb_q(cfg, p, x, qpos)               # (B, C, H, L)
+    lat = _pad_lanes(cfg, mla_latent(cfg, p, x, qpos))   # (B, C, L)
+    with jax.named_scope("attn.kv_write"):
+        phys = jnp.take_along_axis(table, (qpos // bs) % w, axis=1)
+        if valid is not None:
+            phys = jnp.where(valid, phys, 0)         # trash block
+        ck = cache.k.at[phys, 0, qpos % bs].set(lat.astype(cache.k.dtype))
+    if c == 1 and ops.fused_mode(cfg.fused_decode) == "kernel":
+        o_lat = ops.paged_decode(q[:, 0][:, None], ck, None, table,
+                                 qpos[:, 0], scale=mla_scale(cfg),
+                                 v_dim=cfg.kv_lora_rank)
+        y = _mla_out(cfg, p, o_lat.reshape(b, 1, cfg.n_heads,
+                                           cfg.kv_lora_rank).astype(x.dtype))
+        return y, PagedKVCache(ck, cache.v)
+    gk = ck[table][:, :, 0].reshape(b, w * bs, cfg.cache_k_dim)
+    kv_valid = jnp.arange(w * bs)[None, None, :] <= qpos[:, :, None]
+    return (_latent_attend(cfg, p, q, gk, kv_valid, x.dtype),
+            PagedKVCache(ck, cache.v))

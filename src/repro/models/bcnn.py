@@ -79,7 +79,7 @@ class BinDenseBlock(PagedLayout):
 
     @classmethod
     def _mlp(cls, cfg, p, x, g):
-        h = layers.rms_norm(x, p["ln1"])
+        h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
         u = layers.linear(h + g, p["w_up"], cfg.quant)
         u = constrain(u, "batch", None, "tp")
         y = layers.linear(jax.nn.relu(u), p["w_down"], cfg.quant)

@@ -7,9 +7,9 @@ with ``jax.lax.scan`` — keeping the HLO size O(#segments), not O(#layers),
 which is what makes 512-device lower+compile tractable for 48-layer models.
 
 Block kinds:
-  attn   — GQA self-attention + SwiGLU FFN (dense transformers)
+  attn   — self-attention + SwiGLU FFN (dense transformers)
   local  — sliding-window GQA + FFN (RecurrentGemma attention layers)
-  moe    — GQA self-attention + top-k MoE FFN
+  moe    — self-attention + dropless top-k MoE FFN
   cross  — cross-attention to modality context + FFN (Llama-3.2-Vision)
   enc    — bidirectional self-attention + FFN (Whisper encoder)
   dec    — self-attn + cross-attn + FFN (Whisper decoder)
@@ -17,10 +17,14 @@ Block kinds:
   mlstm  — xLSTM mLSTM block (own up/down projections, no FFN)
   slstm  — xLSTM sLSTM block (post-up GLU projection)
 
+The attention of attn and moe follows the config: GQA, or latent (MLA)
+where ``cfg.kv_lora_rank`` is set — the same block kinds either way.
+
 Every kind implements:
   defs(cfg, n)                          stacked ParamDefs
   fwd(cfg, p, x, ctx, opts)             -> (x, aux, state|None)
-  decode(cfg, p, x, state, pos, ctx)    -> (x, state)
+  decode(cfg, p, x, state, pos, ctx)    -> (x, state), and a kind with
+                                           routed experts (x, state, counts)
   state_spec(cfg, batch, s_max, abstract) decode-state pytree per layer
 
 Paged serving (DESIGN.md §14) adds a parallel surface:
@@ -49,10 +53,11 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.ctx import constrain
+from repro.kernels import ops
 from repro.models import attention as attn_mod
 from repro.models import layers, moe, registry, ssm
 from repro.models.attention import KVCache, PagedKVCache
-from repro.models.params import ParamDef
+from repro.models.params import LayerOf, ParamDef
 from repro.models.registry import BlockContract, register
 
 
@@ -113,6 +118,19 @@ def _kv_from_seq(cfg, k, v, s_max, rolling: bool = False):
     if cfg.kv_cache_dtype == "i8":
         return KVCache(attn_mod.i8_encode(cfg, ck), attn_mod.i8_encode(cfg, cv))
     return KVCache(ck.astype(cfg.dtype), cv.astype(cfg.dtype))
+
+
+def _scan_leaves(block, p, n):
+    """(leaves a serving scan slices per layer, leaves it hands whole with
+    the layer's index — the block's ``whole`` — and the layer indices)."""
+    names = getattr(block, "whole", ())
+    whole = {k: v for k, v in p.items() if k in names}
+    return ({k: v for k, v in p.items() if k not in whole}, whole,
+            jnp.arange(n))
+
+
+def _layer_params(pl, whole, i):
+    return dict(pl, **{k: LayerOf(v, i) for k, v in whole.items()})
 
 
 class PagedLayout:
@@ -238,31 +256,32 @@ class AttnBlock(PagedLayout):
         return _attn_ffn_defs(cfg, n)
 
     @classmethod
-    def _ffn(cls, cfg, p, x, valid=None):
-        h = layers.rms_norm(x, p["ln2"])
-        return x + layers.ffn(cfg, _sub(p, "ffn_"), h), jnp.float32(0.0)
+    def _ffn(cls, cfg, p, x, valid=None, serve=False):
+        """-> (x, aux loss, routed-expert counts or None)."""
+        h = layers.rms_norm(x, p["ln2"], cfg.norm_eps)
+        return x + layers.ffn(cfg, _sub(p, "ffn_"), h), jnp.float32(0.0), None
 
     @classmethod
     def fwd(cls, cfg, p, x, ctx, opts: FwdOpts):
-        h = layers.rms_norm(x, p["ln1"])
+        h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
         ap = _sub(p, "attn_")
         win = cfg.local_window if cls.window else 0
         state = None
         if opts.want_state:
             s = h.shape[1]
             positions = jnp.arange(s)
-            k, v = attn_mod._project_kv(cfg, ap, h, positions)
+            k, v = attn_mod.project_cache(cfg, ap, h, positions)
             cap = min(opts.s_max, win) if win else opts.s_max
             state = _kv_from_seq(cfg, k, v, cap, rolling=bool(win))
         y = attn_mod.attention(cfg, ap, h, causal=cls.causal, window=win,
                                q_chunk=opts.q_chunk, unroll=opts.unroll)
         x = x + y
-        x, aux = cls._ffn(cfg, p, x)
+        x, aux, _ = cls._ffn(cfg, p, x)
         return x, aux, state
 
     @classmethod
-    def decode(cls, cfg, p, x, state, pos, ctx, table=None, valid=None):
-        h = layers.rms_norm(x, p["ln1"])
+    def _attend(cls, cfg, p, x, state, pos, table, valid):
+        h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
         win = cfg.local_window if cls.window else 0
         if table is not None:
             y, state = attn_mod.paged_attention(cfg, _sub(p, "attn_"), h,
@@ -271,21 +290,20 @@ class AttnBlock(PagedLayout):
         else:
             y, state = attn_mod.decode_attention(cfg, _sub(p, "attn_"), h,
                                                  state, pos, window=win)
-        x = x + y
-        x, _ = cls._ffn(cfg, p, x)
+        return x + y, state
+
+    @classmethod
+    def decode(cls, cfg, p, x, state, pos, ctx, table=None, valid=None):
+        x, state = cls._attend(cfg, p, x, state, pos, table, valid)
+        x, _, _ = cls._ffn(cfg, p, x, valid=valid, serve=True)
         return x, state
 
     @classmethod
     def chunk(cls, cfg, p, x, state, pos0, valid, n_valid, ctx, table):
         """One chunked-prefill piece: x (1, C, d) at positions
         pos0..pos0+C-1, of which the first ``n_valid`` are real tokens."""
-        h = layers.rms_norm(x, p["ln1"])
-        win = cfg.local_window if cls.window else 0
-        y, state = attn_mod.paged_attention(cfg, _sub(p, "attn_"), h, state,
-                                            table, pos0, window=win,
-                                            valid=valid)
-        x = x + y
-        x, _ = cls._ffn(cfg, p, x, valid=valid)
+        x, state = cls._attend(cfg, p, x, state, pos0, table, valid)
+        x, _, _ = cls._ffn(cfg, p, x, valid=valid, serve=True)
         return x, state
 
     @classmethod
@@ -327,16 +345,31 @@ class EncBlock(AttnBlock):
 class MoeBlock(AttnBlock):
     contract = BlockContract("moe", paged_kv=True, table_class="full",
                              prefix_shareable=True, routed_experts=True)
+    # the serving scans hand the expert weights over whole (``LayerOf``):
+    # the grouped kernel reads only the experts a step touches
+    whole = ("moe_w1", "moe_w3", "moe_w2")
 
     @classmethod
     def defs(cls, cfg, n):
         return _attn_ffn_defs(cfg, n, moe_ffn_=True)
 
     @classmethod
-    def _ffn(cls, cfg, p, x, valid=None):
-        h = layers.rms_norm(x, p["ln2"])
-        y, aux = moe.moe_ffn(cfg, _sub(p, "moe_"), h, valid=valid)
-        return x + y, aux
+    def _ffn(cls, cfg, p, x, valid=None, serve=False):
+        # the serving paths take the grouped expert kernel where the
+        # kernels are on (DESIGN.md §18); training keeps the dense twin
+        h = layers.rms_norm(x, p["ln2"], cfg.norm_eps)
+        grouped = serve and ops.fused_mode(cfg.fused_decode) == "kernel"
+        y, aux, counts = moe.moe_ffn(cfg, _sub(p, "moe_"), h, valid=valid,
+                                     grouped=grouped)
+        return x + y, aux, counts
+
+    @classmethod
+    def decode(cls, cfg, p, x, state, pos, ctx, table=None, valid=None):
+        """-> (x, state, counts): :func:`moe.held_counts` of the rows that
+        decode."""
+        x, state = cls._attend(cfg, p, x, state, pos, table, valid)
+        x, _, counts = cls._ffn(cfg, p, x, valid=valid, serve=True)
+        return x, state, counts
 
 
 @register
@@ -354,18 +387,18 @@ class CrossBlock(PagedLayout):
     def fwd(cls, cfg, p, x, ctx, opts: FwdOpts):
         ap = _sub(p, "attn_")
         ctx_kv = attn_mod.make_ctx_kv(cfg, ap, ctx)
-        h = layers.rms_norm(x, p["ln1"])
+        h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
         x = x + attn_mod.cross_attention(cfg, ap, h, ctx_kv)
-        h = layers.rms_norm(x, p["ln2"])
+        h = layers.rms_norm(x, p["ln2"], cfg.norm_eps)
         x = x + layers.ffn(cfg, _sub(p, "ffn_"), h)
         state = ctx_kv if opts.want_state else None
         return x, jnp.float32(0.0), state
 
     @classmethod
     def decode(cls, cfg, p, x, state, pos, ctx, table=None, valid=None):
-        h = layers.rms_norm(x, p["ln1"])
+        h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
         x = x + attn_mod.decode_cross_attention(cfg, _sub(p, "attn_"), h, state)
-        h = layers.rms_norm(x, p["ln2"])
+        h = layers.rms_norm(x, p["ln2"], cfg.norm_eps)
         x = x + layers.ffn(cfg, _sub(p, "ffn_"), h)
         return x, state
 
@@ -376,9 +409,9 @@ class CrossBlock(PagedLayout):
         # state so decode can read it without the raw ctx staying resident.
         ap = _sub(p, "attn_")
         ctx_kv = attn_mod.make_ctx_kv(cfg, ap, ctx)
-        h = layers.rms_norm(x, p["ln1"])
+        h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
         x = x + attn_mod.cross_attention(cfg, ap, h, ctx_kv)
-        h = layers.rms_norm(x, p["ln2"])
+        h = layers.rms_norm(x, p["ln2"], cfg.norm_eps)
         x = x + layers.ffn(cfg, _sub(p, "ffn_"), h)
         return x, ctx_kv
 
@@ -410,7 +443,7 @@ class DecBlock(PagedLayout):
 
     @classmethod
     def fwd(cls, cfg, p, x, ctx, opts: FwdOpts):
-        h = layers.rms_norm(x, p["ln1"])
+        h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
         ap = _sub(p, "attn_")
         self_state = None
         if opts.want_state:
@@ -421,9 +454,9 @@ class DecBlock(PagedLayout):
                                    q_chunk=opts.q_chunk, unroll=opts.unroll)
         xp = _sub(p, "xattn_")
         ctx_kv = attn_mod.make_ctx_kv(cfg, xp, ctx)
-        h = layers.rms_norm(x, p["lnx"])
+        h = layers.rms_norm(x, p["lnx"], cfg.norm_eps)
         x = x + attn_mod.cross_attention(cfg, xp, h, ctx_kv)
-        h = layers.rms_norm(x, p["ln2"])
+        h = layers.rms_norm(x, p["ln2"], cfg.norm_eps)
         x = x + layers.ffn(cfg, _sub(p, "ffn_"), h)
         state = (self_state, ctx_kv) if opts.want_state else None
         return x, jnp.float32(0.0), state
@@ -431,7 +464,7 @@ class DecBlock(PagedLayout):
     @classmethod
     def decode(cls, cfg, p, x, state, pos, ctx, table=None, valid=None):
         self_cache, ctx_kv = state
-        h = layers.rms_norm(x, p["ln1"])
+        h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
         if table is not None:
             y, self_cache = attn_mod.paged_attention(cfg, _sub(p, "attn_"),
                                                      h, self_cache, table,
@@ -440,9 +473,9 @@ class DecBlock(PagedLayout):
             y, self_cache = attn_mod.decode_attention(cfg, _sub(p, "attn_"),
                                                       h, self_cache, pos)
         x = x + y
-        h = layers.rms_norm(x, p["lnx"])
+        h = layers.rms_norm(x, p["lnx"], cfg.norm_eps)
         x = x + attn_mod.decode_cross_attention(cfg, _sub(p, "xattn_"), h, ctx_kv)
-        h = layers.rms_norm(x, p["ln2"])
+        h = layers.rms_norm(x, p["ln2"], cfg.norm_eps)
         x = x + layers.ffn(cfg, _sub(p, "ffn_"), h)
         return x, (self_cache, ctx_kv)
 
@@ -451,16 +484,16 @@ class DecBlock(PagedLayout):
         """ctx here is the *encoded* encoder output (1, T, d) — encoded once
         at admission, not once per chunk."""
         self_cache, _ = state
-        h = layers.rms_norm(x, p["ln1"])
+        h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
         y, self_cache = attn_mod.paged_attention(cfg, _sub(p, "attn_"), h,
                                                  self_cache, table, pos0,
                                                  valid=valid)
         x = x + y
         xp = _sub(p, "xattn_")
         ctx_kv = attn_mod.make_ctx_kv(cfg, xp, ctx)
-        h = layers.rms_norm(x, p["lnx"])
+        h = layers.rms_norm(x, p["lnx"], cfg.norm_eps)
         x = x + attn_mod.cross_attention(cfg, xp, h, ctx_kv)
-        h = layers.rms_norm(x, p["ln2"])
+        h = layers.rms_norm(x, p["ln2"], cfg.norm_eps)
         x = x + layers.ffn(cfg, _sub(p, "ffn_"), h)
         return x, (self_cache, ctx_kv)
 
@@ -525,7 +558,7 @@ class RglruBlock(PagedLayout):
 
     @classmethod
     def fwd(cls, cfg, p, x, ctx, opts: FwdOpts):
-        h = layers.rms_norm(x, p["ln1"])
+        h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
         g, u = cls._mix(cfg, p, h)
         uc = ssm.conv1d(u, p["conv_k"])
         r = layers.linear(uc, p["w_r"], cfg.quant)
@@ -533,7 +566,7 @@ class RglruBlock(PagedLayout):
         st0 = ssm.RGLRUState.zeros(x.shape[0], cfg.d_model)
         y, st = ssm.rglru(uc, r, i, p["lam"], cfg.rglru_c, st0)
         x = x + layers.linear(g * y, p["w_out"], cfg.quant)
-        h = layers.rms_norm(x, p["ln2"])
+        h = layers.rms_norm(x, p["ln2"], cfg.norm_eps)
         x = x + layers.ffn(cfg, _sub(p, "ffn_"), h)
         state = None
         if opts.want_state:
@@ -545,28 +578,28 @@ class RglruBlock(PagedLayout):
     @classmethod
     def decode(cls, cfg, p, x, state, pos, ctx, table=None, valid=None):
         st, buf = state
-        h = layers.rms_norm(x, p["ln1"])
+        h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
         g, u = cls._mix(cfg, p, h)
         uc, buf = ssm.conv1d_step(buf, u, p["conv_k"])
         r = layers.linear(uc, p["w_r"], cfg.quant)
         i = layers.linear(uc, p["w_i"], cfg.quant)
         y, st = ssm.rglru_step(uc, r, i, p["lam"], cfg.rglru_c, st)
         x = x + layers.linear(g * y, p["w_out"], cfg.quant)
-        h = layers.rms_norm(x, p["ln2"])
+        h = layers.rms_norm(x, p["ln2"], cfg.norm_eps)
         x = x + layers.ffn(cfg, _sub(p, "ffn_"), h)
         return x, (st, buf.astype(cfg.dtype))
 
     @classmethod
     def chunk(cls, cfg, p, x, state, pos0, valid, n_valid, ctx, table):
         st, buf = state
-        h = layers.rms_norm(x, p["ln1"])
+        h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
         g, u = cls._mix(cfg, p, h)
         uc = ssm.conv1d_carry(buf, u, p["conv_k"])
         r = layers.linear(uc, p["w_r"], cfg.quant)
         i = layers.linear(uc, p["w_i"], cfg.quant)
         y, st = ssm.rglru(uc, r, i, p["lam"], cfg.rglru_c, st, valid=valid)
         x = x + layers.linear(g * y, p["w_out"], cfg.quant)
-        h = layers.rms_norm(x, p["ln2"])
+        h = layers.rms_norm(x, p["ln2"], cfg.norm_eps)
         x = x + layers.ffn(cfg, _sub(p, "ffn_"), h)
         buf = ssm.conv1d_carry_out(buf, u, n_valid).astype(cfg.dtype)
         return x, (st, buf)
@@ -634,7 +667,7 @@ class MlstmBlock(PagedLayout):
     def fwd(cls, cfg, p, x, ctx, opts: FwdOpts):
         b, s, d = x.shape
         di, nh = cls._di(cfg), cfg.n_heads
-        h = layers.rms_norm(x, p["ln1"])
+        h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
         u = constrain(layers.linear(h, p["w_up"], cfg.quant),
                       "batch", None, "tp")
         z = constrain(layers.linear(h, p["w_gate"], cfg.quant),
@@ -649,7 +682,7 @@ class MlstmBlock(PagedLayout):
         hseq, st = ssm.mlstm_chunkwise(q, k, v, ig, fg, st0,
                                        min(cfg.mlstm_chunk, s))
         hseq = hseq.reshape(b, s, di).astype(x.dtype)
-        hseq = layers.rms_norm(hseq, p["out_norm"]) * jax.nn.silu(z)
+        hseq = layers.rms_norm(hseq, p["out_norm"], cfg.norm_eps) * jax.nn.silu(z)
         x = x + layers.linear(hseq, p["w_down"], cfg.quant)
         state = None
         if opts.want_state:
@@ -662,7 +695,7 @@ class MlstmBlock(PagedLayout):
         st, buf = state
         b = x.shape[0]
         di, nh = cls._di(cfg), cfg.n_heads
-        h = layers.rms_norm(x, p["ln1"])
+        h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
         u = layers.linear(h, p["w_up"], cfg.quant)
         z = layers.linear(h, p["w_gate"], cfg.quant)
         uc_lin, buf = ssm.conv1d_step(buf, u, p["conv_k"])
@@ -671,7 +704,7 @@ class MlstmBlock(PagedLayout):
         hstep, st = ssm.mlstm_step(st, q[:, 0], k[:, 0], v[:, 0],
                                    ig[:, 0], fg[:, 0])
         hstep = hstep.reshape(b, 1, di).astype(x.dtype)
-        hstep = layers.rms_norm(hstep, p["out_norm"]) * jax.nn.silu(z)
+        hstep = layers.rms_norm(hstep, p["out_norm"], cfg.norm_eps) * jax.nn.silu(z)
         x = x + layers.linear(hstep, p["w_down"], cfg.quant)
         return x, (st, buf.astype(cfg.dtype))
 
@@ -680,7 +713,7 @@ class MlstmBlock(PagedLayout):
         st, buf = state
         b, c, _ = x.shape
         di = cls._di(cfg)
-        h = layers.rms_norm(x, p["ln1"])
+        h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
         u = layers.linear(h, p["w_up"], cfg.quant)
         z = layers.linear(h, p["w_gate"], cfg.quant)
         uc = jax.nn.silu(ssm.conv1d_carry(buf, u, p["conv_k"]))
@@ -693,7 +726,7 @@ class MlstmBlock(PagedLayout):
         hseq, st = ssm.mlstm_chunkwise(q, k, v, ig, fg, st,
                                        min(cfg.mlstm_chunk, c))
         hseq = hseq.reshape(b, c, di).astype(x.dtype)
-        hseq = layers.rms_norm(hseq, p["out_norm"]) * jax.nn.silu(z)
+        hseq = layers.rms_norm(hseq, p["out_norm"], cfg.norm_eps) * jax.nn.silu(z)
         x = x + layers.linear(hseq, p["w_down"], cfg.quant)
         buf = ssm.conv1d_carry_out(buf, u, n_valid).astype(cfg.dtype)
         return x, (st, buf)
@@ -743,7 +776,7 @@ class SlstmBlock(PagedLayout):
 
     @classmethod
     def _post_ffn(cls, cfg, p, x):
-        h = layers.rms_norm(x, p["ln2"])
+        h = layers.rms_norm(x, p["ln2"], cfg.norm_eps)
         up = layers.linear(h, p["w_up"], cfg.quant)
         a, g = jnp.split(up, 2, axis=-1)
         return x + layers.linear(a * jax.nn.gelu(g), p["w_down"], cfg.quant)
@@ -751,7 +784,7 @@ class SlstmBlock(PagedLayout):
     @classmethod
     def fwd(cls, cfg, p, x, ctx, opts: FwdOpts):
         b = x.shape[0]
-        h = layers.rms_norm(x, p["ln1"])
+        h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
         gates = constrain(layers.linear(h, p["w_gates"], cfg.quant),
                           "batch", None, "tp")
         st0 = ssm.SLSTMState.zeros(b, cfg.d_model)
@@ -762,7 +795,7 @@ class SlstmBlock(PagedLayout):
 
     @classmethod
     def decode(cls, cfg, p, x, state, pos, ctx, table=None, valid=None):
-        h = layers.rms_norm(x, p["ln1"])
+        h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
         gates = layers.linear(h, p["w_gates"], cfg.quant)
         state, y = ssm.slstm_step(state, gates[:, 0], p["r_kernel"], cfg.n_heads)
         x = x + y[:, None].astype(x.dtype)
@@ -771,7 +804,7 @@ class SlstmBlock(PagedLayout):
 
     @classmethod
     def chunk(cls, cfg, p, x, state, pos0, valid, n_valid, ctx, table):
-        h = layers.rms_norm(x, p["ln1"])
+        h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
         gates = layers.linear(h, p["w_gates"], cfg.quant)
         y, state = ssm.slstm_sequence(gates, p["r_kernel"], state,
                                       cfg.n_heads, valid=valid)
@@ -877,32 +910,50 @@ def segment_decode(cfg, seg_params: list, x, states: list, pos, ctx=None,
     §14); their states are then shared PagedKVCache pools.  ``active``
     (B,) bool additionally freezes inactive slots' per-slot state and
     trash-routes their KV writes (mid-prefill slots share the decode
-    batch)."""
+    batch).  Returns (x, new states, counts): the routed-expert counts
+    (:func:`moe.held_counts`) summed over the layers of kinds with routed
+    experts, int32 (3,), or None where no layer routes experts."""
     valid = None if active is None else active[:, None]
     new_states = []
+    counts = (jnp.zeros((3,), jnp.int32)
+              if any(registry.get(kind).contract.routed_experts
+                     for (kind, _), _ in seg_params) else None)
     for ((kind, n), p), st in zip(seg_params, states):
         block = registry.get(kind)
         table = _block_table(block, tables)
+        routed = block.contract.routed_experts
 
-        def body(xc, pst, _block=block, _table=table):
-            pl, stl = pst
-            xn, stn = _block.decode(cfg, pl, xc, stl, pos, ctx, table=_table,
-                                    valid=valid)
-            return xn, stn
+        sliced, whole, layer = _scan_leaves(block, p, n)
+
+        def body(carry, pst, _block=block, _table=table, _routed=routed,
+                 _whole=whole):
+            xc, cnt = carry
+            pl, stl, i = pst
+            out = _block.decode(cfg, _layer_params(pl, _whole, i), xc, stl,
+                                pos, ctx, table=_table, valid=valid)
+            if _routed:
+                xn, stn, c = out
+                cnt = cnt + c
+            else:
+                xn, stn = out
+            return (xn, cnt), stn
 
         if unroll:
             outs = []
+            carry = (x, counts)
             for i in range(n):
-                pst = jax.tree.map(lambda a: a[i], (p, st))
-                x, stn_i = body(x, pst)
+                pst = jax.tree.map(lambda a: a[i], (sliced, st))
+                carry, stn_i = body(carry, (*pst, jnp.int32(i)))
                 outs.append(stn_i)
+            x, counts = carry
             stn = jax.tree.map(lambda *ls: jnp.stack(ls), *outs)
         else:
-            x, stn = jax.lax.scan(body, x, (p, st))
+            (x, counts), stn = jax.lax.scan(body, (x, counts),
+                                            (sliced, st, layer))
         if active is not None:
             stn = _freeze_inactive(block, st, stn, active)
         new_states.append(stn)
-    return x, new_states
+    return x, new_states, counts
 
 
 def segment_chunk(cfg, seg_params: list, x, states: list, slot, pos0,
@@ -936,14 +987,17 @@ def segment_chunk(cfg, seg_params: list, x, states: list, slot, pos0,
                     lambda i, l: jnp.where(fresh, i.astype(l.dtype), l),
                     init, ps_slot)
 
-        def body(xc, pst, _block=block, _table=table):
-            pl, sh_l, ps_l = pst
+        sliced, whole, layer = _scan_leaves(block, p, n)
+
+        def body(xc, pst, _block=block, _table=table, _whole=whole):
+            pl, sh_l, ps_l, i = pst
             st_l = _block.paged_merge(sh_l, ps_l)
-            xn, st_n = _block.chunk(cfg, pl, xc, st_l, pos0, valid, n_valid,
-                                    ctx, _table)
+            xn, st_n = _block.chunk(cfg, _layer_params(pl, _whole, i), xc,
+                                    st_l, pos0, valid, n_valid, ctx, _table)
             return xn, _block.paged_split(st_n)
 
-        x, (sh_new, ps_new) = jax.lax.scan(body, x, (p, shared, ps_slot))
+        x, (sh_new, ps_new) = jax.lax.scan(body, x,
+                                           (sliced, shared, ps_slot, layer))
         if per_slot is not None:
             ps_new = jax.tree.map(
                 lambda full_l, new_l: jax.lax.dynamic_update_slice_in_dim(

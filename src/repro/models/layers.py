@@ -104,7 +104,7 @@ def embed(p: dict, tokens: jnp.ndarray) -> jnp.ndarray:
 
 
 def logits(cfg, p: dict, x: jnp.ndarray) -> jnp.ndarray:
-    x = rms_norm(x, p["final_norm"])
+    x = rms_norm(x, p["final_norm"], cfg.norm_eps)
     # lm_head stays full precision even under quant="xnor" (XNOR-Net keeps
     # first/last layers full precision; DESIGN.md §5).
     return constrain(jnp.einsum("...d,dv->...v", x, p["lm_head"]),

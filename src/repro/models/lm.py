@@ -78,14 +78,17 @@ def param_count(cfg) -> int:
 
 def active_param_count(cfg) -> int:
     """Params touched per token: excludes the embedding table gather and
-    non-routed experts (MODEL_FLOPS accounting, DESIGN.md §9)."""
+    the held experts a token is not routed to — of the top_k it chooses
+    among the router's experts, the held share on average (MODEL_FLOPS
+    accounting, DESIGN.md §9)."""
     total = pdefs.count(param_defs(cfg))
     inactive = cfg.vocab * cfg.d_model          # embedding table
     if cfg.n_experts:
         per_expert = 3 * cfg.d_model * cfg.d_ff_expert
         n_routed = sum(1 for k in cfg.layer_kinds()
                        if registry.contract(k).routed_experts)
-        inactive += n_routed * (cfg.n_experts - cfg.top_k) * per_expert
+        active = cfg.top_k * cfg.n_experts / cfg.n_router_experts
+        inactive += int(n_routed * (cfg.n_experts - active) * per_expert)
     return total - inactive
 
 
@@ -114,7 +117,7 @@ def encode(cfg, params, frames: jnp.ndarray, q_chunk: int = 0) -> jnp.ndarray:
     x, _, _ = blocks.segment_fwd(cfg, _seg_params(cfg, params, enc=True),
                                  frames.astype(cfg.dtype), None,
                                  FwdOpts(q_chunk=q_chunk))
-    return layers.rms_norm(x, params["enc_norm"])
+    return layers.rms_norm(x, params["enc_norm"], cfg.norm_eps)
 
 
 def forward(cfg, params, tokens: jnp.ndarray, ctx: jnp.ndarray | None = None,
@@ -302,13 +305,13 @@ def decode_step(cfg, params, token: jnp.ndarray, state: DecodeState,
     (dead) cache line is rewritten in place each step instead of walking
     forward — the slot state stays inert until an admission overwrites it.
     Inactive rows still flow through the network (their logits are garbage
-    the scheduler ignores); under MoE their tokens also compete for expert
-    capacity, so the serve layer feeds a constant token id in dead slots.
+    the scheduler ignores).
     """
     x = layers.embed(params["embed"], token).astype(cfg.dtype)
-    x, new_states = blocks.segment_decode(cfg, _seg_params(cfg, params), x,
-                                          list(state.seg_states), state.pos,
-                                          state.ctx, unroll=unroll)
+    x, new_states, _ = blocks.segment_decode(cfg, _seg_params(cfg, params),
+                                             x, list(state.seg_states),
+                                             state.pos, state.ctx,
+                                             unroll=unroll)
     logits = layers.logits(cfg, params["embed"], x)
     inc = 1 if active is None else active.astype(jnp.int32)
     return logits, DecodeState(state.pos + inc, tuple(new_states), state.ctx)
@@ -316,7 +319,10 @@ def decode_step(cfg, params, token: jnp.ndarray, state: DecodeState,
 
 def paged_decode_step(cfg, params, token: jnp.ndarray, state: DecodeState,
                       tables: dict, active: jnp.ndarray | None = None):
-    """One token for every slot against the block-paged resident state.
+    """One token for every slot against the block-paged resident state:
+    (logits, new state, counts), the last the routed-expert counts of the
+    rows that decode (``blocks.segment_decode``): int32 (3,), None for a
+    model without routed experts.
 
     ``tables`` {"full"/"win": (B, W) int32} are host-owned device data —
     they change as blocks are allocated and freed without ever retracing.
@@ -327,14 +333,14 @@ def paged_decode_step(cfg, params, token: jnp.ndarray, state: DecodeState,
     """
     with jax.named_scope("embed"):
         x = layers.embed(params["embed"], token).astype(cfg.dtype)
-    x, new_states = blocks.segment_decode(cfg, _seg_params(cfg, params), x,
-                                          list(state.seg_states), state.pos,
-                                          state.ctx, tables=tables,
-                                          active=active)
+    x, new_states, counts = blocks.segment_decode(
+        cfg, _seg_params(cfg, params), x, list(state.seg_states), state.pos,
+        state.ctx, tables=tables, active=active)
     with jax.named_scope("head"):
         logits = layers.logits(cfg, params["embed"], x)
     inc = 1 if active is None else active.astype(jnp.int32)
-    return logits, DecodeState(state.pos + inc, tuple(new_states), state.ctx)
+    return (logits, DecodeState(state.pos + inc, tuple(new_states), state.ctx),
+            counts)
 
 
 def truncate(cfg, params, state: DecodeState, depth: int):
@@ -415,9 +421,8 @@ def prefix_cache_eligible(cfg) -> bool:
       register, and a resumed chunk could not rebuild the in-window keys
       (the registry rejects a window+shareable contract outright).
 
-    MoE declares it: its KV is ordinary paged attention state (the §14
-    capacity-grouping caveat exempts it from cross-path token identity,
-    not from sharing).
+    MoE declares it: its KV is ordinary paged attention state, and its
+    dropless routing makes a token's output its own.
     """
     kinds = {k for k, _ in cfg.segments()}
     return bool(kinds) and all(registry.contract(k).prefix_shareable

@@ -37,6 +37,20 @@ class ParamDef(NamedTuple):
                             # /lm-head stay full precision — DESIGN.md §5)
 
 
+class LayerOf(NamedTuple):
+    """One layer's slice of a layer-stacked leaf, handed over whole:
+    ``stack[index]``.  A scanned segment gives a kernel that reads only
+    parts of a large leaf (the held experts a step touches) the stack and
+    the index, since a scan that sliced the leaf would copy the whole
+    layer's slice before the kernel ran."""
+
+    stack: Any
+    index: Any
+
+    def get(self):
+        return self.stack[self.index]
+
+
 def abstract(defs):
     return jax.tree.map(
         lambda d: jax.ShapeDtypeStruct(d.shape, d.dtype), defs,

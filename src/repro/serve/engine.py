@@ -11,7 +11,7 @@ on top of the token-level serve path.  This module provides it:
   tables (:class:`repro.serve.pools.BlockPool` allocates; tables are device
   *data*), so cache memory is proportional to tokens actually held, not
   ``slots x s_max``; ``paged=False`` keeps the slot-dense layout — the two
-  are token-identical (MoE excepted, see §14);
+  are token-identical;
 * **admission**: a freed slot is immediately refilled.  Paged: the
   request's worst-case blocks are reserved (OOM backpressure holds the
   FIFO head otherwise) and the prompt is consumed by **chunked prefill** —
@@ -182,6 +182,11 @@ class ServeEngine:
                  block_size: int = 0, prefill_chunk: int = 0,
                  n_blocks: int = 0, prefix_cache: bool = True,
                  device=None):
+        if cfg.latent and cfg.quant != "none":
+            raise ValueError(
+                f"{cfg.name}: latent attention is served from float weights "
+                "only (decoding over cached latents folds wkv_b into q, "
+                "which a binarized wkv_b does not allow)")
         self.cfg = cfg
         self.slots = slots
         self.s_max = s_max
@@ -233,7 +238,9 @@ class ServeEngine:
             # (W, P table blocks per grid step, window), for the counters
             kv_item = (1 if cfg.kv_cache_dtype == "i8"
                        else jnp.dtype(cfg.dtype).itemsize)
-            slab = cfg.n_kv_heads * self.block_size * cfg.d_head * kv_item
+            slab = paged_attn.slab_bytes(cfg.cache_heads, self.block_size,
+                                         cfg.cache_k_dim, kv_item,
+                                         1 if cfg.latent else 2)
             windows = lm.paged_table_windows(cfg)
             self._walks = {
                 c: (w, paged_attn.chunk_blocks(w, self.block_size, slab),
@@ -279,11 +286,12 @@ class ServeEngine:
                     state)
 
         def decode_fn(params, tokens, state, tables, active, key, seeds):
+            """-> (tokens, state), and a MoE model's expert counts."""
             self.stats.decode_traces += 1
-            logits, state = lm.paged_decode_step(cfg, params, tokens, state,
-                                                 tables, active=active)
-            return (_sample_tokens(cfg, logits, key, seeds, temperature),
-                    state)
+            logits, state, counts = lm.paged_decode_step(
+                cfg, params, tokens, state, tables, active=active)
+            toks = _sample_tokens(cfg, logits, key, seeds, temperature)
+            return (toks, state) if counts is None else (toks, state, counts)
 
         self._chunk_program = jax.jit(chunk_fn, donate_argnums=(2,))
         self._paged_decode_program = jax.jit(decode_fn, donate_argnums=(2,))
@@ -354,7 +362,6 @@ class ServeEngine:
             self.pool.evict(slot)
             self._active[slot] = False
             self._tokens[slot] = 0   # dead slots feed a constant token id
-                                     # (keeps MoE capacity competition quiet)
             if self.paged:
                 # eviction returns every block the request held; the zeroed
                 # table row routes the dead slot's frozen re-writes to the
@@ -876,8 +883,9 @@ class ServeEngine:
         cfg, temperature = self.cfg, self.temperature
 
         def fn(params, tokens, state, tables, active, key, seeds):
-            logits, state = lm.paged_decode_step(cfg, params, tokens, state,
-                                                 tables, active=active)
+            logits, state, _ = lm.paged_decode_step(cfg, params, tokens,
+                                                    state, tables,
+                                                    active=active)
             return (_sample_tokens(cfg, logits, key, seeds, temperature),
                     state)
 
@@ -957,11 +965,13 @@ class ServeEngine:
             self.stats.host_turn_ns += (self._turn_ns + time.perf_counter_ns()
                                         - self._t_mark)
         with TraceAnnotation("serve.decode.dispatch"):
-            toks, self._state = program(*args)
+            toks, self._state, *counts = program(*args)
         self.stats.decode_steps += 1
         self._count_decode(active_sessions)       # while the device runs
         with TraceAnnotation("serve.decode.sync"):
             toks = np.asarray(toks)                 # the per-step sync point
+            if counts:
+                self.stats.count_experts(np.asarray(counts[0]))
         self._turn_ns, self._t_mark = 0, time.perf_counter_ns()
         with TraceAnnotation("serve.decode.commit"):
             for slot, sess in active_sessions.items():

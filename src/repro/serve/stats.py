@@ -43,6 +43,12 @@ class EngineStats:
     decode_kv_blocks_live: int = 0
     kv_reserved_block_steps: int = 0
     kv_live_block_steps: int = 0
+    # MoE decode steps (zero for dense models), over every routed-expert
+    # layer: the token choices the rows that decode make, those that land
+    # on an expert the layer holds, and the held experts given a row
+    moe_choices_routed: int = 0
+    moe_choices_held: int = 0
+    moe_experts_touched: int = 0
     # the host's turns: the engine's own host time (inside step(), its
     # caller's left out) from each decode step's token read to the next
     # decode dispatch, while no decode program is queued
@@ -71,6 +77,13 @@ class EngineStats:
     scrub_corruptions: int = 0      # digest mismatches vs recorded baseline
     _block_sum: int = 0
     _block_samples: int = 0
+
+    def count_experts(self, counts) -> None:
+        """Add one decode step's (routed, held, touched) expert counts."""
+        routed, held, touched = (int(c) for c in counts)
+        self.moe_choices_routed += routed
+        self.moe_choices_held += held
+        self.moe_experts_touched += touched
 
     def observe_blocks(self, in_use: int) -> None:
         self.blocks_in_use = in_use

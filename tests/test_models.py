@@ -11,6 +11,8 @@ import pytest
 
 import repro.configs as configs
 from repro.models import lm, moe
+from repro.models import params as pdefs
+from repro.models.params import ParamDef
 
 ARCHS = sorted(configs.ALL)
 
@@ -59,11 +61,10 @@ def test_train_step_grad_finite(name):
 @pytest.mark.parametrize("name", ARCHS)
 def test_prefill_decode_matches_forward(name):
     """Algorithmic equivalence of the serve path (f32 so recurrent-layer
-    bf16 accumulation noise doesn't mask logic bugs; no-drop capacity so
-    MoE routing is identical across both paths)."""
+    bf16 accumulation noise doesn't mask logic bugs; MoE routing is
+    dropless, so identical across both paths)."""
     B, S, s0 = 2, 12, 8
-    cfg, params, tokens, ctx = _setup(name, B, S, capacity_factor=8.0,
-                                      dtype=jnp.float32)
+    cfg, params, tokens, ctx = _setup(name, B, S, dtype=jnp.float32)
     full_logits, _ = lm.forward(cfg, params, tokens, ctx)
     lg, st = lm.prefill(cfg, params, tokens[:, :s0], ctx, s_max=S + 2)
     outs = [lg]
@@ -77,7 +78,7 @@ def test_prefill_decode_matches_forward(name):
 
 
 @pytest.mark.parametrize("name", ["qwen2-7b", "xlstm-350m",
-                                  "moonshot-v1-16b-a3b"])
+                                  "moonlight-16b-a3b"])
 def test_xnor_quant_variant_trains(name):
     """The paper's technique as a config axis: binary projections still give
     finite loss/grads (STE path)."""
@@ -170,39 +171,43 @@ def test_local_window_attention_masks_past():
     assert d_near > d_far  # recurrent path may carry some far influence
 
 
+def _moe_params(cfg, key):
+    """One MoE layer's parameters, drawn by the model's own defs."""
+    defs = jax.tree.map(lambda d: d._replace(shape=d.shape[1:],
+                                             spec=d.spec[1:]),
+                        moe.moe_defs(cfg, 1),
+                        is_leaf=lambda x: isinstance(x, ParamDef))
+    return pdefs.init(defs, key)
+
+
 def test_moe_capacity_and_load_balance():
-    cfg = configs.ALL["moonshot-v1-16b-a3b"].smoke()
-    key = jax.random.PRNGKey(3)
-    d, e = cfg.d_model, cfg.n_experts
-    p = {"router": jax.random.normal(key, (d, e)) * 0.02,
-         "w1": jax.random.normal(key, (e, d, cfg.d_ff_expert), cfg.dtype) * 0.02,
-         "w3": jax.random.normal(key, (e, d, cfg.d_ff_expert), cfg.dtype) * 0.02,
-         "w2": jax.random.normal(key, (e, cfg.d_ff_expert, d), cfg.dtype) * 0.02}
-    x = jax.random.normal(key, (2, 64, d), cfg.dtype)
-    y, aux = moe.moe_ffn(cfg, p, x)
+    cfg = configs.ALL["moonlight-16b-a3b"].smoke()
+    p = _moe_params(cfg, jax.random.PRNGKey(3))
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, 64, cfg.d_model),
+                          cfg.dtype)
+    y, aux, counts = moe.moe_ffn(cfg, p, x)
     assert y.shape == x.shape
     assert np.isfinite(np.asarray(y, np.float32)).all()
     assert float(aux) >= 1.0 - 1e-3  # >= 1 by Cauchy-Schwarz, == 1 if balanced
+    routed, held, touched = (int(c) for c in counts)
+    assert routed == 2 * 64 * cfg.top_k
+    assert 0 < held < routed and 0 < touched <= cfg.n_experts
 
 
-def test_moe_respects_capacity_drop_semantics():
-    """Force all tokens to one expert: overflow must be dropped (residual
-    carries them), output for dropped tokens is exactly zero."""
+def test_moe_is_dropless_under_a_router_forced_to_one_expert():
+    """Every token routed to expert 0: each one gets expert 0's whole FFN
+    (the top-1 gate normalises to 1), however many share it — nothing is
+    dropped at a capacity — in the plain form and in the grouped one."""
     cfg = dataclasses.replace(configs.ALL["llama4-scout-17b-a16e"].smoke(),
-                              capacity_factor=0.25, top_k=1)
-    key = jax.random.PRNGKey(4)
-    d, e = cfg.d_model, cfg.n_experts
-    router = jnp.zeros((d, e)).at[:, 0].set(100.0)  # everyone -> expert 0
-    p = {"router": router,
-         "w1": jnp.ones((e, d, cfg.d_ff_expert), cfg.dtype) * 0.01,
-         "w3": jnp.ones((e, d, cfg.d_ff_expert), cfg.dtype) * 0.01,
-         "w2": jnp.ones((e, cfg.d_ff_expert, d), cfg.dtype) * 0.01}
-    x = jax.random.normal(key, (1, 32, d), cfg.dtype) + 1.0
-    y, _ = moe.moe_ffn(cfg, p, x)
-    ynorm = np.asarray(jnp.sum(jnp.abs(y.astype(jnp.float32)), axis=-1))[0]
-    kept = int((ynorm > 1e-3).sum())
-    cap = moe.capacity(cfg, 32)
-    assert kept == cap, (kept, cap)
+                              dtype=jnp.float32)
+    p = _moe_params(cfg, jax.random.PRNGKey(4))
+    p["router"] = jnp.zeros_like(p["router"]).at[:, 0].set(100.0)
+    x = jax.random.normal(jax.random.PRNGKey(4), (1, 32, cfg.d_model)) + 1.0
+    want = moe._swiglu(x[0], p["w1"][0], p["w3"][0], p["w2"][0])
+    for grouped in (False, True):
+        y, _, counts = moe.moe_ffn(cfg, p, x, grouped=grouped)
+        np.testing.assert_allclose(y[0], want, rtol=1e-5, atol=1e-5)
+        assert [int(c) for c in counts] == [32, 32, 1]
 
 
 @pytest.mark.parametrize("name", ARCHS)

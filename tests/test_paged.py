@@ -5,10 +5,9 @@ The contract under test: the paged serve path — shared block pool,
 per-slot block tables, fixed-size chunked prefill — is *token-identical*
 to the slot-dense path for every arch family that caches attention state
 (dense / local-window / enc-dec / vlm) and for the pure-recurrent archs
-(whose per-slot state stays dense by design); MoE archs are exempt from
-cross-layout identity (expert capacity is a function of the dispatch
-group length, so C-sized chunks legitimately drop differently than a
-P-length exact prefill) and are pinned for schedule-independence instead.
+(whose per-slot state stays dense by design) and for MoE archs, whose
+dropless routing makes each token's output its own (softmax-routed GQA,
+and sigmoid-routed latent attention with held and shared experts).
 §15 extends the contract: serving a shared-prefix trace with the prefix
 cache on is token-identical to serving it with the cache off, across the
 same arch sweep, float/packed residency and the i8 KV cache, with every
@@ -32,10 +31,12 @@ from repro.models import lm
 from repro.serve import (BlockPool, PrefixIndex, Request, ServeEngine,
                          synthetic_trace)
 
-# dense / local+recurrent / enc-dec / vlm / pure-recurrent — the identity
-# sweep the acceptance criteria pin (MoE is exercised separately)
+# dense / local+recurrent / enc-dec / vlm / pure-recurrent / MoE (GQA, and
+# latent attention with held and shared experts) — the identity sweep the
+# acceptance criteria pin
 SWEEP_ARCHS = ["qwen3-4b", "recurrentgemma-2b", "whisper-tiny",
-               "llama-3.2-vision-11b", "xlstm-350m"]
+               "llama-3.2-vision-11b", "xlstm-350m", "llama4-scout-17b-a16e",
+               "moonlight-16b-a3b"]
 
 
 def _setup(name, **over):
@@ -146,20 +147,20 @@ def test_paged_matches_dense_i8_cache(name):
 
 
 def test_paged_moe_deterministic_across_slot_counts():
-    """MoE is exempt from cross-layout identity (capacity is group-length
-    dependent), but the paged path must still be schedule-independent:
-    identical tokens whatever the slot count, greedy and sampled."""
+    """Dropless MoE is schedule-independent and layout-independent:
+    identical tokens whatever the slot count, greedy and sampled, and the
+    same on the slot-dense layout."""
     cfg, params = _setup("llama4-scout-17b-a16e")
     trace_args = dict(seed=3, prompt_lens=(4, 6, 9), new_tokens=(3, 5))
 
-    def run(slots, temperature):
+    def run(slots, temperature, paged=True):
         trace = synthetic_trace(5, cfg.vocab, **trace_args)
-        toks, _ = _run(cfg, params, trace, paged=True, slots=slots,
+        toks, _ = _run(cfg, params, trace, paged=paged, slots=slots,
                        temperature=temperature, seed=11)
         return toks
 
-    assert run(1, 0.0) == run(2, 0.0) == run(4, 0.0)
-    assert run(1, 0.7) == run(3, 0.7)
+    assert run(1, 0.0) == run(2, 0.0) == run(4, 0.0) == run(2, 0.0, False)
+    assert run(1, 0.7) == run(3, 0.7) == run(3, 0.7, False)
 
 
 def test_paged_local_window_ring_recycles_blocks():
@@ -463,7 +464,8 @@ def test_prefix_index_ctx_keys_the_chain_root():
 # rings cannot be rebuilt from cached blocks, so the engine auto-disables
 ELIGIBLE = {"qwen3-4b": True, "recurrentgemma-2b": False,
             "whisper-tiny": True, "llama-3.2-vision-11b": True,
-            "xlstm-350m": False}
+            "xlstm-350m": False, "llama4-scout-17b-a16e": True,
+            "moonlight-16b-a3b": True}
 
 
 def _shared_trace(cfg, seed=13):
@@ -531,22 +533,20 @@ def test_prefix_sharing_identity_i8_cache(name):
 
 
 def test_prefix_sharing_moe_deterministic_replay():
-    """MoE shares prefixes too (its KV is ordinary paged state) but is
-    exempt from identity vs the cache-off path (§14: expert capacity is
-    group-length dependent, and a cache hit legitimately shortens the
-    dispatched group).  The pinned property is determinism: replaying the
-    same shared trace through an identically configured engine — sharing,
-    COW, LRU eviction and all — reproduces the tokens exactly."""
+    """MoE shares prefixes too (its KV is ordinary paged state) and, being
+    dropless, is token-identical to the cache-off path; replaying the same
+    shared trace through an identically configured engine — sharing, COW,
+    LRU eviction and all — reproduces the tokens and the counts exactly."""
     cfg, params = _setup("llama4-scout-17b-a16e")
 
-    def run():
+    def run(prefix_cache=True):
         toks, eng = _run(cfg, params, _shared_trace(cfg, seed=3),
-                         paged=True, slots=2, prefix_cache=True)
+                         paged=True, slots=2, prefix_cache=prefix_cache)
         return toks, eng
 
     t1, e1 = run()
     t2, e2 = run()
-    assert t1 == t2
+    assert t1 == t2 == run(prefix_cache=False)[0]
     assert e1.prefix_caching and e1.stats.prefix_hits > 0
     assert e1.stats.prefix_hits == e2.stats.prefix_hits
     assert e1.stats.cow_copies == e2.stats.cow_copies

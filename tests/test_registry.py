@@ -33,8 +33,7 @@ _OVER = {
     "cross": dict(n_ctx_tokens=16, family="vlm"),
     "dec": dict(n_ctx_tokens=16, encoder_layers=2, family="audio"),
     "bindense": dict(n_ctx_tokens=4, vocab=4, quant="xnor", family="vlm"),
-    "moe": dict(n_experts=4, top_k=2, d_ff_expert=32, capacity_factor=8.0,
-                family="moe"),
+    "moe": dict(n_experts=4, top_k=2, d_ff_expert=32, family="moe"),
 }
 
 
@@ -130,10 +129,6 @@ def test_chunked_paged_matches_dense(kind):
     c = registry.contract(kind)
     if not c.decodes:
         pytest.skip("encoder-only kind never runs the decode path")
-    if c.routed_experts:
-        pytest.skip("MoE exempt from cross-layout token identity "
-                    "(capacity is a function of dispatch group length, "
-                    "DESIGN.md §14)")
     cfg, params = _model(kind)
     trace = synthetic_trace(4, cfg.vocab, seed=3, prompt_lens=(5, 8, 13),
                             new_tokens=(3, 5),

@@ -272,11 +272,16 @@ def test_decode_state_spec_per_slot_pos():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("name", ARCHS)
+# a latent-attention model trains under +xnor but is not served binarized
+# (its decode folds wkv_b into q: test_moonlight.py pins the refusal)
+PACKED_ARCHS = [a for a in ARCHS if not configs.get(a).latent]
+
+
+@pytest.mark.parametrize("name", PACKED_ARCHS)
 def test_packed_serve_path_bit_exact(name):
     """Full-model prefill+decode logits from prepacked weights are
-    bit-identical to the float sign path, for every arch under +xnor
-    (runs in whichever REPRO_KERNEL_IMPL mode CI selects)."""
+    bit-identical to the float sign path, for every arch served under
+    +xnor (runs in whichever REPRO_KERNEL_IMPL mode CI selects)."""
     cfg, params = _setup(name + "+xnor")
     assert cfg.quant == "xnor"
     packed = lm.pack_params(cfg, params)

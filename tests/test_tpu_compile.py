@@ -7,7 +7,9 @@ refuse: Mosaic lowerings that do not exist, block shapes that break the
 nothing about results (the interpret-mode tests pin those) or speed.
 Shapes are qwen3-4b's: d 2560, ff 9728, 8 KV heads of 4 query heads,
 dh 128, with the serve geometry of ``chip_smoke.py`` (8 slots, 16-token
-blocks, 50-block tables).  Each test asserts that the kernel is in the
+blocks, 50-block tables); and Moonlight-16B-A3B's latent attention (16
+heads over one latent of 576 values stored at 640 lanes, 352-block tables, 32 slots) and held
+experts (8 of d 2048, ff 1408).  Each test asserts that the kernel is in the
 compiled program (``tpu_custom_call``).
 
 The topology is described inside a module fixture, never at import: only
@@ -23,6 +25,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import ops
 
 D, FF = 2560, 9728
+ML_D, ML_FF, ML_E, ML_H, ML_LAT, ML_R = 2048, 1408, 8, 16, 640, 512
+ML_SLOTS, ML_W = 32, 352
 KV, G, DH = 8, 4, 128
 SLOTS, BS, W = 8, 16, 50
 N_BLOCKS = 1 + SLOTS * W
@@ -85,6 +89,34 @@ def test_paged_decode_attention_compiles(compile_text, pool):
     assert "tpu_custom_call" in text
 
 
+def test_latent_paged_decode_attention_compiles(compile_text):
+    def fn(q, ck, table, pos):
+        return ops.paged_decode(q, ck, None, table, pos, scale=192**-0.5,
+                                v_dim=ML_R, impl="pallas")
+    text = compile_text(fn, ((ML_SLOTS, 1, ML_H, ML_LAT), jnp.bfloat16),
+                        ((1024, 1, BS, ML_LAT), jnp.bfloat16),
+                        ((ML_SLOTS, ML_W), jnp.int32),
+                        ((ML_SLOTS,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("rows,tm", [(ML_SLOTS * 6, 8), (256 * 6, 32)],
+                         ids=["decode", "prefill_chunk"])
+def test_moe_expert_ffn_compiles(compile_text, rows, tm):
+    n_tiles = -(-(rows + ML_E * (tm - 1)) // tm)
+
+    def fn(x, w1, w3, w2, layer, te, tu):
+        return ops.moe_expert_ffn(x, w1, w3, w2, layer, te, tu, tm=tm,
+                                  impl="pallas")
+    text = compile_text(fn, ((n_tiles * tm, ML_D), jnp.bfloat16),
+                        ((26, ML_E, ML_D, ML_FF), jnp.bfloat16),
+                        ((26, ML_E, ML_D, ML_FF), jnp.bfloat16),
+                        ((26, ML_E, ML_FF, ML_D), jnp.bfloat16),
+                        ((1,), jnp.int32), ((n_tiles,), jnp.int32),
+                        ((n_tiles,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
 @pytest.mark.parametrize("k,n", [(D, FF), (FF, D)], ids=["up", "down"])
 @pytest.mark.parametrize("m", [SLOTS, 32, 128],
                          ids=["decode", "prefill_chunk", "rows_128"])
@@ -135,12 +167,20 @@ def test_bulk_op_compiles(compile_text, op):
 
 
 
+@pytest.mark.parametrize("arch,layers,slots,w,kernels", [
+    ("qwen3-4b+xnor", 2, SLOTS, W,
+     {"paged_decode_attention", "xnor_linear_fused"}),
+    ("moonlight-16b-a3b", 2, ML_SLOTS, ML_W,
+     {"paged_decode_attention", "moe_expert_ffn"})],
+    ids=["qwen3-4b-xnor", "moonlight-16b-a3b"])
 def test_decode_step_names_its_kernels_and_scopes(compile_text, one_chip,
-                                                  monkeypatch):
-    """The served decode step at qwen3-4b+xnor widths (two layers): each
-    kernel in it is named by its ``pallas_call`` name, which for the two it
-    holds is the name the benchmark's readers match, and its ops carry the
-    model scopes in their metadata."""
+                                                  monkeypatch, arch, layers,
+                                                  slots, w, kernels):
+    """The served decode step at published widths (two layers; Moonlight's
+    dense first layer and one MoE layer): each kernel in it is named by its
+    ``pallas_call`` name, which for those it holds is the name the
+    benchmark's readers match, and its ops carry the model scopes in their
+    metadata."""
     import dataclasses
     import re
 
@@ -149,29 +189,30 @@ def test_decode_step_names_its_kernels_and_scopes(compile_text, one_chip,
     from repro.serve import engine
 
     monkeypatch.setattr(ops, "_FORCE", "pallas")
-    cfg = dataclasses.replace(configs.get("qwen3-4b+xnor"), n_layers=2)
-    s_max = W * BS
-
+    cfg = dataclasses.replace(configs.get(arch), n_layers=layers)
+    s_max = w * BS
     def fn(params, tokens, state, tables, active, key, seeds):
-        logits, state = lm.paged_decode_step(cfg, params, tokens, state,
-                                             tables, active=active)
-        return engine._sample_tokens(cfg, logits, key, seeds, 0.0), state
+        logits, state, counts = lm.paged_decode_step(cfg, params, tokens,
+                                                     state, tables,
+                                                     active=active)
+        return (engine._sample_tokens(cfg, logits, key, seeds, 0.0), state,
+                counts)
 
     specs = (
         lm.packed_abstract_params(cfg),
-        jax.ShapeDtypeStruct((SLOTS, 1), jnp.int32),
-        lm.paged_decode_state_spec(cfg, SLOTS, s_max, n_blocks=N_BLOCKS,
+        jax.ShapeDtypeStruct((slots, 1), jnp.int32),
+        lm.paged_decode_state_spec(cfg, slots, s_max, n_blocks=N_BLOCKS,
                                    block_size=BS),
-        {c: jax.ShapeDtypeStruct((SLOTS, w), jnp.int32) for c, w in
+        {c: jax.ShapeDtypeStruct((slots, w), jnp.int32) for c, w in
          lm.paged_table_widths(cfg, s_max, BS, cfg.prefill_chunk).items()},
-        jax.ShapeDtypeStruct((SLOTS,), jnp.bool_),
+        jax.ShapeDtypeStruct((slots,), jnp.bool_),
         jax.ShapeDtypeStruct((2,), jnp.uint32),
-        jax.ShapeDtypeStruct((SLOTS,), jnp.int32))
+        jax.ShapeDtypeStruct((slots,), jnp.int32))
     args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
         a.shape, a.dtype, sharding=one_chip), specs)
     text = jax.jit(fn).lower(*args).compile().as_text()
-    kernels = set(re.findall(r"%([a-z_]+)[.\d]* = \S+ custom-call\(", text))
-    assert kernels == {"paged_decode_attention", "xnor_linear_fused"}
+    found = set(re.findall(r"%([a-z_]+)[.\d]* = \S+ custom-call\(", text))
+    assert found == kernels
     scopes = set(re.findall(r'op_name="[^"]*/(embed|attn\.kv_write|attn\.core'
                             r'|mlp|head|sample)/', text))
     assert scopes == {"embed", "attn.kv_write", "attn.core", "mlp", "head",
