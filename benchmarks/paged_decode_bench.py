@@ -59,7 +59,8 @@ def _time(fn, *args, iters: int = 5) -> float:
 
 
 def _make_case(bs: int, slots: int, kv_dtype: str, seed: int = 0):
-    """One decode-step attention problem over a block-paged pool."""
+    """One decode-step attention problem over a block-paged pool: a stack
+    of one layer and the layer operand, as the kernel takes them."""
     import jax.numpy as jnp
 
     rng = np.random.default_rng(seed)
@@ -79,8 +80,9 @@ def _make_case(bs: int, slots: int, kv_dtype: str, seed: int = 0):
     table = jnp.asarray(
         rng.permutation(slots * w).reshape(slots, w) + 1, jnp.int32)
     pos = jnp.asarray(rng.integers(1, w * bs, size=(slots,)), jnp.int32)
-    return (q, jnp.asarray(ck), jnp.asarray(cv), table, pos,
-            float(scale), float(out_scale))
+    return (q, jnp.asarray(ck)[None], jnp.asarray(cv)[None],
+            jnp.zeros((1,), jnp.int32), table, pos, float(scale),
+            float(out_scale))
 
 
 def _bench_cell(bs: int, slots: int, kv_dtype: str, quiet: bool):
@@ -91,7 +93,9 @@ def _bench_cell(bs: int, slots: int, kv_dtype: str, quiet: bool):
     from repro.kernels import ops, paged_attn, ref
     from repro.roofline import analysis
 
-    q, ck, cv, table, pos, scale, out_scale = _make_case(bs, slots, kv_dtype)
+    q, ck, cv, layer, table, pos, scale, out_scale = _make_case(bs, slots,
+                                                                kv_dtype)
+    args = (q, ck, cv, layer, table, pos)
     impl = ops._resolve("auto")
     if impl == "ref":
         raise RuntimeError(
@@ -103,16 +107,16 @@ def _bench_cell(bs: int, slots: int, kv_dtype: str, quiet: bool):
                               interpret=(impl == "interpret"))
     unfused = jax.jit(functools.partial(ref.paged_decode, window=0,
                                         scale=scale, out_scale=out_scale))
-    out_f = np.asarray(fused(q, ck, cv, table, pos))
-    out_u = np.asarray(unfused(q, ck, cv, table, pos))
+    out_f = np.asarray(fused(*args))
+    out_u = np.asarray(unfused(*args))
     np.testing.assert_allclose(out_f, out_u, rtol=2e-5, atol=2e-5)
 
     disp_f = analysis.dispatch_count(
-        jax.make_jaxpr(fused)(q, ck, cv, table, pos))
+        jax.make_jaxpr(fused)(*args))
     disp_u = analysis.dispatch_count(
-        jax.make_jaxpr(unfused)(q, ck, cv, table, pos))
-    us_f = _time(fused, q, ck, cv, table, pos)
-    us_u = _time(unfused, q, ck, cv, table, pos)
+        jax.make_jaxpr(unfused)(*args))
+    us_f = _time(fused, *args)
+    us_u = _time(unfused, *args)
     name = f"bs{bs}_s{slots}_{kv_dtype}"
     derived = (f"fused_us={us_f:.1f} unfused_us={us_u:.1f} "
                f"disp_fused={disp_f} disp_unfused={disp_u}")

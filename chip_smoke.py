@@ -200,7 +200,8 @@ def kernel_parity(cfg, seed: int, stream_words: int = STREAM_WORDS):
             cv = jax.random.normal(next(keys), shape, jnp.bfloat16)
             scale, out_scale = dh**-0.5, 1.0
         for window in (0, cfg.local_window):
-            args = (q, ck, cv, table, pos)
+            args = (q, ck[None], cv[None], jnp.zeros((1,), jnp.int32),
+                    table, pos)
             kw = dict(window=window, scale=scale, out_scale=out_scale)
             _close(f"paged_decode {pool} window={window}",
                    ops.paged_decode(*args, impl="pallas", **kw),

@@ -199,10 +199,11 @@ def digest(buf: jnp.ndarray, digest_width: int = 128, impl: str = "auto",
                                  interpret=(impl == "interpret"))
 
 
-def paged_decode(q, ck, cv, table, pos, *, window: int = 0,
+def paged_decode(q, ck, cv, layer, table, pos, *, window: int = 0,
                  scale: float = 1.0, out_scale: float = 1.0, v_dim: int = 0,
                  impl: str = "auto") -> jnp.ndarray:
-    """Fused block-paged decode attention (DESIGN.md §18).
+    """Fused block-paged decode attention (DESIGN.md §18) over layer
+    ``layer`` of a layer-stacked pool.
 
     ``impl="ref"`` runs the one-shot oracle :func:`ref.paged_decode`; the
     kernel is interpreted only when ``interpret`` is asked for.  Shapes as
@@ -211,10 +212,10 @@ def paged_decode(q, ck, cv, table, pos, *, window: int = 0,
     """
     impl = _resolve(impl)
     if impl == "ref":
-        return ref.paged_decode(q, ck, cv, table, pos, window=window,
+        return ref.paged_decode(q, ck, cv, layer, table, pos, window=window,
                                 scale=scale, out_scale=out_scale, v_dim=v_dim)
     return _paged_attn.paged_decode_attention(
-        q, ck, cv, table, pos, window=window, scale=scale,
+        q, ck, cv, layer, table, pos, window=window, scale=scale,
         out_scale=out_scale, v_dim=v_dim, interpret=(impl == "interpret"))
 
 

@@ -14,15 +14,18 @@ accumulator per KV head.
 Block-table walk
 ----------------
 Grid is (B, W / P): one grid step handles a *chunk* of P consecutive table
-columns for every KV head at once.  One table entry addresses a contiguous
-(KV, bs, dh) slab of the (n_blocks, KV, bs, dh) pool, so a chunk is P slab
-copies each of K and V (of the one latent slab, for a latent pool: see
-below), issued by hand (``pltpu.make_async_copy`` from the
-``pl.ANY`` pool) into a (2, P, KV, bs, dh) VMEM scratch: double-buffered,
-the copies of the next live chunk — the same row's next chunk, else the
-next row's first — are in flight while this one is computed.  The table and
-the per-slot positions ride in as *scalar-prefetched* operands, so the copy
-sources are ``table[b, w]`` read from SMEM; the gather never materializes.
+columns for every KV head at once.  The pool comes layer-stacked, (L,
+n_blocks, KV, bs, dh), with the layer's index: the serving scans carry the
+whole stack, and a kernel handed one layer's slice would be handed a copy
+of it.  One table entry addresses a contiguous (KV, bs, dh) slab of the
+layer's pool, ``pool.at[layer, blk]``, so a chunk is P slab copies each of
+K and V (of the one latent slab, for a latent pool: see below), issued by
+hand (``pltpu.make_async_copy`` from the ``pl.ANY`` pool) into a (2, P,
+KV, bs, dh) VMEM scratch: double-buffered, the copies of the next live
+chunk — the same row's next chunk, else the next row's first — are in
+flight while this one is computed.  The table, the per-slot positions and
+the layer ride in as *scalar-prefetched* operands, so the copy sources are
+``table[b, w]`` read from SMEM; the gather never materializes.
 Both grid axes are "arbitrary": the prefetch chain runs across rows, and
 the buffer slot in use lives in SMEM scratch.
 
@@ -134,8 +137,9 @@ def walk_blocks(pos, *, p: int, bs: int, w: int, window: int):
     return live_chunks(pos, p=p, bs=bs, w=w) * p, live
 
 
-def _kernel(table_ref, pos_ref, q_ref, *refs, bs: int, p: int, w_total: int,
-            window: int, scale: float, out_scale: float, v_dim: int):
+def _kernel(table_ref, pos_ref, layer_ref, q_ref, *refs, bs: int, p: int,
+            w_total: int, window: int, scale: float, out_scale: float,
+            v_dim: int):
     # a latent pool (v_dim > 0) is one slab per token whose first v_dim
     # lanes are the values; a GQA pool is a K slab and a V slab
     n_pools = 1 if v_dim else 2
@@ -143,6 +147,7 @@ def _kernel(table_ref, pos_ref, q_ref, *refs, bs: int, p: int, w_total: int,
     bufs = refs[n_pools + 1:2 * n_pools + 1]
     sems, slot_ref, m_ref, l_ref, acc_ref = refs[2 * n_pools + 1:]
     b, i = pl.program_id(0), pl.program_id(1)
+    layer = layer_ref[0]
     kv, dk = q_ref.shape[1], q_ref.shape[3]
     span = p * bs
     pos = pos_ref[b]
@@ -158,7 +163,8 @@ def _kernel(table_ref, pos_ref, q_ref, *refs, bs: int, p: int, w_total: int,
         def body(j, carry):
             blk = table_ref[row, chunk * p + j]
             for t, (pool, buf) in enumerate(zip(pools, bufs)):
-                op(pltpu.make_async_copy(pool.at[blk], buf.at[slot, j],
+                op(pltpu.make_async_copy(pool.at[layer, blk],
+                                         buf.at[slot, j],
                                          sems.at[t, slot]))
             return carry
         jax.lax.fori_loop(0, p, body, 0)
@@ -224,16 +230,18 @@ def _kernel(table_ref, pos_ref, q_ref, *refs, bs: int, p: int, w_total: int,
 
 @functools.partial(jax.jit, static_argnames=("window", "scale", "out_scale",
                                              "v_dim", "interpret"))
-def paged_decode_attention(q, ck, cv, table, pos, *, window: int = 0,
+def paged_decode_attention(q, ck, cv, layer, table, pos, *, window: int = 0,
                            scale: float = 1.0, out_scale: float = 1.0,
                            v_dim: int = 0,
                            interpret: bool = False) -> jnp.ndarray:
     """One fused decode-attention dispatch over the block-paged pool.
 
     q       (B, KV, G, dk)  — this step's queries, compact GQA form
-    ck, cv  (n_blocks, KV, bs, dk) — the shared pool (f32/bf16 or int8),
-            with this step's K/V already scattered in (the scatter is a
-            (B,) token write, not part of the HBM-bound gather chain)
+    ck, cv  (L, n_blocks, KV, bs, dk) — the layer-stacked shared pool
+            (f32/bf16 or int8), read in place, with this step's K/V
+            already written in (a (B,) token write, not part of the
+            HBM-bound gather chain)
+    layer   (1,) int32      — the layer of ``ck``/``cv`` to attend over
     table   (B, W) int32    — per-slot physical block ids
     pos     (B,)  int32     — per-slot current absolute position
     window  0 for monotone tables; the local window size for block rings
@@ -249,21 +257,22 @@ def paged_decode_attention(q, ck, cv, table, pos, *, window: int = 0,
     P=16), the q and out tiles and the f32 accumulators.
     """
     b, kv, g, dk = q.shape
-    bs = ck.shape[2]
+    bs = ck.shape[3]
     dv = v_dim or dk
     w_total = table.shape[1]
     pools = (ck,) if v_dim else (ck, cv)
     p = chunk_blocks(w_total, bs,
                      slab_bytes(kv, bs, dk, ck.dtype.itemsize, len(pools)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, w_total // p),
         in_specs=[
-            pl.BlockSpec((1, kv, g, dk), lambda b, i, tbl, pos: (b, 0, 0, 0)),
+            pl.BlockSpec((1, kv, g, dk),
+                         lambda b, i, tbl, pos, lyr: (b, 0, 0, 0)),
             *[pl.BlockSpec(memory_space=pl.ANY) for _ in pools],
         ],
         out_specs=pl.BlockSpec((1, kv, g, dv),
-                               lambda b, i, tbl, pos: (b, 0, 0, 0)),
+                               lambda b, i, tbl, pos, lyr: (b, 0, 0, 0)),
         scratch_shapes=[
             *[pltpu.VMEM((2, p, kv, bs, dk), x.dtype) for x in pools],
             pltpu.SemaphoreType.DMA((len(pools), 2)),
@@ -282,4 +291,4 @@ def paged_decode_attention(q, ck, cv, table, pos, *, window: int = 0,
             dimension_semantics=("arbitrary", "arbitrary")),
         name="paged_decode_attention",
         interpret=interpret,
-    )(table, pos, q, *pools)
+    )(table, pos, layer, q, *pools)

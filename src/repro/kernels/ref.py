@@ -58,26 +58,29 @@ def pack(x: jnp.ndarray):
 # Fused paged-decode attention (kernels/paged_attn.py, DESIGN.md §18)
 # ---------------------------------------------------------------------------
 
-def paged_decode(q: jnp.ndarray, ck: jnp.ndarray, cv, table: jnp.ndarray,
-                 pos: jnp.ndarray, *, window: int = 0, scale: float = 1.0,
-                 out_scale: float = 1.0, v_dim: int = 0) -> jnp.ndarray:
+def paged_decode(q: jnp.ndarray, ck: jnp.ndarray, cv, layer,
+                 table: jnp.ndarray, pos: jnp.ndarray, *, window: int = 0,
+                 scale: float = 1.0, out_scale: float = 1.0,
+                 v_dim: int = 0) -> jnp.ndarray:
     """One-shot masked-softmax oracle for the fused paged decode kernel.
 
     Same semantics as the C == 1 path of ``models/attention.py::
     paged_attention`` after the scatter: gather the pool through the block
     table, score, mask (monotone or window-ring), softmax, PV.  ``q`` is
-    (B, KV, G, dk); ``ck``/``cv`` are the (n_blocks, KV, bs, dk) pool
+    (B, KV, G, dk); ``ck``/``cv`` are the (L, n_blocks, KV, bs, dk)
+    layer-stacked pool, read at layer ``layer[0]``
     (any dtype incl. int8 — decoded to f32 here, the fixed-point factors
     arrive folded into ``scale``/``out_scale``); with ``v_dim`` the pool is
     latent, ``cv`` is None and the values are ``ck``'s first ``v_dim``
     lanes.  Returns (B, KV, G, dv) in q.dtype.
     """
     b, kv, g, dk = q.shape
-    bs = ck.shape[2]
+    bs = ck.shape[3]
     cap = table.shape[1] * bs
-    gk = jnp.moveaxis(ck[table], 1, 2).reshape(b, kv, cap, dk)
+    gk = jnp.moveaxis(ck[layer[0], table], 1, 2).reshape(b, kv, cap, dk)
     gv = (gk[..., :v_dim] if v_dim
-          else jnp.moveaxis(cv[table], 1, 2).reshape(b, kv, cap, dk))
+          else jnp.moveaxis(cv[layer[0], table], 1, 2).reshape(b, kv, cap,
+                                                               dk))
     s = jnp.einsum("bkgd,bksd->bkgs", q.astype(jnp.float32),
                    gk.astype(jnp.float32)) * scale
     kslot = jnp.arange(cap, dtype=jnp.int32)

@@ -15,7 +15,9 @@ Layout conventions:
                cache is the memory bottleneck; never expanded).
   paged cache  (n_blocks, KV, block_size, dh) — the block-paged serve form
                (DESIGN.md §14): a shared pool with no batch axis, addressed
-               through per-slot int32 block tables.
+               through per-slot int32 block tables; the resident state
+               stacks it per layer, and the serving scans hand each layer
+               the whole stack with its index (``LayerOf``).
 Latent attention (MLA, ``cfg.kv_lora_rank > 0``, DeepSeek-V2/V3 without q
 compression): q is projected per head (nope and rotary parts); ``wkv_a``
 gives a kv_lora_rank vector c, normalised by its own RMSNorm, and one
@@ -44,7 +46,7 @@ import jax.numpy as jnp
 from repro.distributed.ctx import constrain
 from repro.kernels import ops
 from repro.models import layers
-from repro.models.params import ParamDef
+from repro.models.params import LayerOf, ParamDef
 
 NEG_INF = -1e30
 
@@ -512,27 +514,33 @@ class PagedKVCache(NamedTuple):
 
 
 @jax.named_scope("attn.core")
-def paged_attention(cfg, p: dict, x: jnp.ndarray, cache: PagedKVCache,
+def paged_attention(cfg, p: dict, x: jnp.ndarray, cache: LayerOf,
                     table: jnp.ndarray, pos: jnp.ndarray, *, window: int = 0,
                     valid: jnp.ndarray | None = None):
-    """Attention through a per-slot block table: scatter the new tokens into
-    the pool, gather K/V back through the table, and mask by position.
+    """Attention through a per-slot block table: write the new tokens into
+    the pool, read K/V back through the table, and mask by position.
 
     One function covers both serve regimes:
       decode          — x (B, 1, d), per-slot ``pos`` (B,), B = slots;
       chunked prefill — x (1, C, d), scalar-ish ``pos`` (1,) = chunk start.
 
-    ``table`` (B, W) holds physical block ids; token at absolute position q
-    lives at block ``table[b, (q // bs) % W]``, offset ``q % bs``.  For full
-    (non-window) tables ``W * bs >= s_max`` so the ring modulus is the
-    identity; for local layers the table is a block ring of capacity
-    ``W * bs >= window + C - 1`` (older blocks are recycled — blocks that
-    fall out of the window never stay resident).  ``valid`` (B, C) routes
-    padding / dead-slot writes to the reserved trash block 0.
-    Returns (out (B, C, d), new cache).
+    ``cache`` is one layer of the layer-stacked pool, handed over whole:
+    ``LayerOf(PagedKVCache((L, n_blocks, KV, bs, dh) ...), layer)``.  The
+    tokens are written into ``[layer, block, :, offset]`` of the stack and
+    the stack is returned, so a scan that carries the pool never slices,
+    relays out or restacks it.  ``table`` (B, W) holds physical block ids;
+    token at absolute position q lives at block ``table[b, (q // bs) %
+    W]``, offset ``q % bs``.  For full (non-window) tables ``W * bs >=
+    s_max`` so the ring modulus is the identity; for local layers the table
+    is a block ring of capacity ``W * bs >= window + C - 1`` (older blocks
+    are recycled — blocks that fall out of the window never stay
+    resident).  ``valid`` (B, C) routes padding / dead-slot writes to the
+    reserved trash block 0.
+    Returns (out (B, C, d), the new ``LayerOf`` of the stack).
     """
     b, c, _ = x.shape
-    bs = cache.k.shape[2]
+    pool, layer = cache
+    bs = pool.k.shape[3]
     w = table.shape[1]
     cap = w * bs
     pos = jnp.asarray(pos, jnp.int32)
@@ -543,45 +551,40 @@ def paged_attention(cfg, p: dict, x: jnp.ndarray, cache: PagedKVCache,
     q = q.reshape(b, c, cfg.n_kv_heads, cfg.q_per_kv, cfg.d_head)
     k, v = _project_kv(cfg, p, x, qpos)              # (B, C, KV, dh)
 
-    i8 = cache.k.dtype == jnp.int8
+    i8 = pool.k.dtype == jnp.int8
     if i8:
         k, v = i8_encode(cfg, k), i8_encode(cfg, v)
     with jax.named_scope("attn.kv_write"):
-        phys = jnp.take_along_axis(table, (qpos // bs) % w, axis=1)  # (B, C)
-        if valid is not None:
-            phys = jnp.where(valid, phys, 0)         # trash block
-        off = qpos % bs
-        # advanced indices (phys, off) broadcast to (B, C); the KV slice
-        # stays: scatter target shape (B, C, KV, dh).  Distinct live tokens
-        # always hit distinct (block, offset) pairs (BlockPool uniqueness +
-        # ring sizing); only trash-block writes may collide, never read.
-        ck = cache.k.at[phys, :, off].set(k.astype(cache.k.dtype))
-        cv = cache.v.at[phys, :, off].set(v.astype(cache.v.dtype))
+        phys = _write_blocks(table, qpos, bs, valid)
+        ck = _write_tokens(pool.k, layer, phys, qpos % bs, k)
+        cv = _write_tokens(pool.v, layer, phys, qpos % bs, v)
+    new = LayerOf(PagedKVCache(ck, cv), layer)
 
     scale = cfg.d_head ** -0.5
     if i8:
         scale = scale / cfg.kv_i8_scale
     if c == 1 and ops.fused_mode(cfg.fused_decode) == "kernel":
         # single-dispatch decode: the Pallas kernel walks the block table via
-        # scalar prefetch and streams pool blocks through VMEM (DESIGN.md
-        # §18) — the gather/mask/softmax/PV chain below is its reference
-        # twin (exact in real arithmetic, allclose in floats), kept as the
-        # production path on ref/interpret backends so cross-layout token
-        # pins stay bitwise.  Forced on a ref backend, ops.paged_decode runs
-        # the one-shot oracle; the kernel is interpreted only under an
-        # explicit interpret impl.  Chunked prefill (c > 1) always takes the
-        # unfused path.
+        # scalar prefetch and streams the layer's pool blocks through VMEM
+        # from the stack in place (DESIGN.md §18) — the gather/mask/
+        # softmax/PV chain below is its reference twin (exact in real
+        # arithmetic, allclose in floats), kept as the production path on
+        # ref/interpret backends so cross-layout token pins stay bitwise.
+        # Forced on a ref backend, ops.paged_decode runs the one-shot
+        # oracle; the kernel is interpreted only under an explicit
+        # interpret impl.  Chunked prefill (c > 1) always takes the unfused
+        # path.
         out = ops.paged_decode(
-            q[:, 0], ck, cv, table, qpos[:, 0], window=window,
-            scale=float(scale),
+            q[:, 0], ck, cv, jnp.reshape(layer, (1,)), table, qpos[:, 0],
+            window=window, scale=float(scale),
             out_scale=float(1.0 / cfg.kv_i8_scale) if i8 else 1.0)
         out = out.reshape(b, 1, cfg.n_heads * cfg.d_head).astype(x.dtype)
-        return layers.linear(out, p["wo"], cfg.quant), PagedKVCache(ck, cv)
+        return layers.linear(out, p["wo"], cfg.quant), new
 
-    gk = jnp.moveaxis(ck[table], 1, 2).reshape(b, cfg.n_kv_heads, cap,
-                                               cfg.d_head)
-    gv = jnp.moveaxis(cv[table], 1, 2).reshape(b, cfg.n_kv_heads, cap,
-                                               cfg.d_head)
+    gk = jnp.moveaxis(ck[layer, table], 1, 2).reshape(b, cfg.n_kv_heads, cap,
+                                                      cfg.d_head)
+    gv = jnp.moveaxis(cv[layer, table], 1, 2).reshape(b, cfg.n_kv_heads, cap,
+                                                      cfg.d_head)
     scores = jnp.einsum("bqkgd,bksd->bkgqs", q, gk.astype(q.dtype),
                         preferred_element_type=jnp.float32) * scale
     kslot = jnp.arange(cap)[None, None, :]
@@ -601,31 +604,56 @@ def paged_attention(cfg, p: dict, x: jnp.ndarray, cache: PagedKVCache,
     if i8:
         out = out / cfg.kv_i8_scale
     out = out.reshape(b, c, cfg.n_heads * cfg.d_head)
-    return layers.linear(out, p["wo"], cfg.quant), PagedKVCache(ck, cv)
+    return layers.linear(out, p["wo"], cfg.quant), new
 
 
-def _paged_latent(cfg, p, x, cache: PagedKVCache, table, qpos, valid):
+def _write_blocks(table, qpos, bs, valid):
+    """(B, C) physical blocks the tokens at ``qpos`` are written to: their
+    table entries, or the trash block 0 where not ``valid``."""
+    phys = jnp.take_along_axis(table, (qpos // bs) % table.shape[1], axis=1)
+    return phys if valid is None else jnp.where(valid, phys, 0)
+
+
+def _write_tokens(pool, layer, phys, off, new):
+    """``pool`` (L, n_blocks, heads, bs, width) with the tokens ``new`` (B,
+    C, heads, width) written into ``[layer, phys, :, off]``, in place.
+
+    The write goes through the pool viewed as rows, (L, n_blocks, heads·bs,
+    width), a row per head and token: a write of whole (heads, width)
+    slabs into the 5-d pool makes the TPU compiler lay the whole pool out
+    with heads minor for the scatter, and copy it back for the attention
+    kernel, every layer of every step.  Distinct live tokens always hit
+    distinct (block, offset) pairs (BlockPool uniqueness + ring sizing);
+    only trash-block writes may collide, never read."""
+    n_layers, n_blocks, heads, bs, width = pool.shape
+    rows = jnp.arange(heads) * bs + off[..., None]             # (B, C, heads)
+    flat = pool.reshape(n_layers, n_blocks, heads * bs, width)
+    flat = flat.at[layer, phys[..., None], rows].set(new.astype(pool.dtype))
+    return flat.reshape(pool.shape)
+
+
+def _paged_latent(cfg, p, x, cache: LayerOf, table, qpos, valid):
     """:func:`paged_attention` over a latent pool (monotone tables): the
-    token's latent is scattered into its slot, then the absorbed queries
-    attend over the table's latents — through the Pallas kernel at decode
-    where the kernels are on, else the gather chain, its twin."""
+    token's latent is written into its slot of the layer-stacked pool, then
+    the absorbed queries attend over the table's latents — through the
+    Pallas kernel at decode where the kernels are on, else the gather
+    chain, its twin."""
     b, c, _ = x.shape
-    bs, w = cache.k.shape[2], table.shape[1]
+    pool, layer = cache
+    bs, w = pool.k.shape[3], table.shape[1]
     q = _mla_absorb_q(cfg, p, x, qpos)               # (B, C, H, L)
     lat = _pad_lanes(cfg, mla_latent(cfg, p, x, qpos))   # (B, C, L)
     with jax.named_scope("attn.kv_write"):
-        phys = jnp.take_along_axis(table, (qpos // bs) % w, axis=1)
-        if valid is not None:
-            phys = jnp.where(valid, phys, 0)         # trash block
-        ck = cache.k.at[phys, 0, qpos % bs].set(lat.astype(cache.k.dtype))
+        phys = _write_blocks(table, qpos, bs, valid)
+        ck = _write_tokens(pool.k, layer, phys, qpos % bs, lat[:, :, None])
+    new = LayerOf(PagedKVCache(ck, pool.v), layer)
     if c == 1 and ops.fused_mode(cfg.fused_decode) == "kernel":
-        o_lat = ops.paged_decode(q[:, 0][:, None], ck, None, table,
-                                 qpos[:, 0], scale=mla_scale(cfg),
-                                 v_dim=cfg.kv_lora_rank)
+        o_lat = ops.paged_decode(q[:, 0][:, None], ck, None,
+                                 jnp.reshape(layer, (1,)), table, qpos[:, 0],
+                                 scale=mla_scale(cfg), v_dim=cfg.kv_lora_rank)
         y = _mla_out(cfg, p, o_lat.reshape(b, 1, cfg.n_heads,
                                            cfg.kv_lora_rank).astype(x.dtype))
-        return y, PagedKVCache(ck, cache.v)
-    gk = ck[table][:, :, 0].reshape(b, w * bs, cfg.cache_k_dim)
+        return y, new
+    gk = ck[layer, table][:, :, 0].reshape(b, w * bs, cfg.cache_k_dim)
     kv_valid = jnp.arange(w * bs)[None, None, :] <= qpos[:, :, None]
-    return (_latent_attend(cfg, p, q, gk, kv_valid, x.dtype),
-            PagedKVCache(ck, cache.v))
+    return _latent_attend(cfg, p, q, gk, kv_valid, x.dtype), new

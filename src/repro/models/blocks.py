@@ -902,17 +902,47 @@ def _freeze_inactive(block, old, new, active):
     return block.paged_merge(shared, jax.tree.map(sel, ps_new, ps_old))
 
 
+def _carried(block, st, table):
+    """(the pool a serving scan carries, the leaves it scans): a paged
+    kind's layer-stacked pool rides in the scan's carry, handed to each
+    layer whole with its index (``LayerOf``) and written in place, since a
+    scan that took it as xs and ys would slice each layer out, relay it
+    out for the token write and stack it again on every step.  Dense
+    states (no table) and per-slot leaves stay xs and ys."""
+    if table is None:
+        return None, st
+    return block.paged_split(st)
+
+
+def _layer_state(block, pool, per_slot, i):
+    return (per_slot if pool is None
+            else block.paged_merge(LayerOf(pool, i), per_slot))
+
+
+def _layer_out(block, pool, st):
+    """(the carried pool, the per-layer leaves) of a layer's new state."""
+    if pool is None:
+        return None, st
+    shared, per_slot = block.paged_split(st)
+    return shared.stack, per_slot
+
+
+def _merge_carried(block, pool, per_slot):
+    return per_slot if pool is None else block.paged_merge(pool, per_slot)
+
+
 def segment_decode(cfg, seg_params: list, x, states: list, pos, ctx=None,
                    unroll: bool = False, tables: dict | None = None,
                    active=None):
     """``tables`` switches attn-family blocks to the paged gather/scatter
     path: {"full": (B, W), "win": (B, W)} per-slot block tables (DESIGN.md
-    §14); their states are then shared PagedKVCache pools.  ``active``
-    (B,) bool additionally freezes inactive slots' per-slot state and
-    trash-routes their KV writes (mid-prefill slots share the decode
-    batch).  Returns (x, new states, counts): the routed-expert counts
-    (:func:`moe.held_counts`) summed over the layers of kinds with routed
-    experts, int32 (3,), or None where no layer routes experts."""
+    §14); their states are then shared PagedKVCache pools, carried through
+    the layer scan.  ``active`` (B,) bool additionally freezes inactive
+    slots' per-slot state and trash-routes their KV writes (mid-prefill
+    slots share the decode batch).  Returns (x, new states, counts): the
+    routed-expert counts (:func:`moe.held_counts`) summed over the layers
+    of kinds with routed experts, int32 (3,), or None where no layer
+    routes experts."""
     valid = None if active is None else active[:, None]
     new_states = []
     counts = (jnp.zeros((3,), jnp.int32)
@@ -924,32 +954,36 @@ def segment_decode(cfg, seg_params: list, x, states: list, pos, ctx=None,
         routed = block.contract.routed_experts
 
         sliced, whole, layer = _scan_leaves(block, p, n)
+        pool, scanned = _carried(block, st, table)
 
         def body(carry, pst, _block=block, _table=table, _routed=routed,
                  _whole=whole):
-            xc, cnt = carry
+            xc, cnt, pool_c = carry
             pl, stl, i = pst
-            out = _block.decode(cfg, _layer_params(pl, _whole, i), xc, stl,
-                                pos, ctx, table=_table, valid=valid)
+            out = _block.decode(cfg, _layer_params(pl, _whole, i), xc,
+                                _layer_state(_block, pool_c, stl, i), pos,
+                                ctx, table=_table, valid=valid)
             if _routed:
                 xn, stn, c = out
                 cnt = cnt + c
             else:
                 xn, stn = out
-            return (xn, cnt), stn
+            pool_c, stn = _layer_out(_block, pool_c, stn)
+            return (xn, cnt, pool_c), stn
 
         if unroll:
             outs = []
-            carry = (x, counts)
+            carry = (x, counts, pool)
             for i in range(n):
-                pst = jax.tree.map(lambda a: a[i], (sliced, st))
+                pst = jax.tree.map(lambda a: a[i], (sliced, scanned))
                 carry, stn_i = body(carry, (*pst, jnp.int32(i)))
                 outs.append(stn_i)
-            x, counts = carry
+            x, counts, pool = carry
             stn = jax.tree.map(lambda *ls: jnp.stack(ls), *outs)
         else:
-            (x, counts), stn = jax.lax.scan(body, (x, counts),
-                                            (sliced, st, layer))
+            (x, counts, pool), stn = jax.lax.scan(body, (x, counts, pool),
+                                                  (sliced, scanned, layer))
+        stn = _merge_carried(block, pool, stn)
         if active is not None:
             stn = _freeze_inactive(block, st, stn, active)
         new_states.append(stn)
@@ -965,14 +999,15 @@ def segment_chunk(cfg, seg_params: list, x, states: list, slot, pos0,
     ``slot``, optionally reset to their initial values when ``fresh`` (the
     request's first chunk overwrites whatever the previous tenant left),
     run through the chunk, and scattered back; shared PagedKVCache pools
-    pass through whole (the block table confines writes to this slot's
-    blocks).  ``tables`` rows here are (1, W) — just this slot's row.
+    ride whole in the layer scan's carry (the block table confines writes
+    to this slot's blocks).  ``tables`` rows here are (1, W) — just this
+    slot's row.
     """
     new_states = []
     for ((kind, n), p), st in zip(seg_params, states):
         block = registry.get(kind)
         table = _block_table(block, tables)
-        shared, per_slot = block.paged_split(st)
+        pool, per_slot = _carried(block, st, table)
         ps_slot = None
         if per_slot is not None:
             ps_slot = jax.tree.map(
@@ -989,21 +1024,23 @@ def segment_chunk(cfg, seg_params: list, x, states: list, slot, pos0,
 
         sliced, whole, layer = _scan_leaves(block, p, n)
 
-        def body(xc, pst, _block=block, _table=table, _whole=whole):
-            pl, sh_l, ps_l, i = pst
-            st_l = _block.paged_merge(sh_l, ps_l)
+        def body(carry, pst, _block=block, _table=table, _whole=whole):
+            xc, pool_c = carry
+            pl, ps_l, i = pst
             xn, st_n = _block.chunk(cfg, _layer_params(pl, _whole, i), xc,
-                                    st_l, pos0, valid, n_valid, ctx, _table)
-            return xn, _block.paged_split(st_n)
+                                    _layer_state(_block, pool_c, ps_l, i),
+                                    pos0, valid, n_valid, ctx, _table)
+            pool_c, ps_n = _layer_out(_block, pool_c, st_n)
+            return (xn, pool_c), ps_n
 
-        x, (sh_new, ps_new) = jax.lax.scan(body, x,
-                                           (sliced, shared, ps_slot, layer))
+        (x, pool), ps_new = jax.lax.scan(body, (x, pool),
+                                         (sliced, ps_slot, layer))
         if per_slot is not None:
             ps_new = jax.tree.map(
                 lambda full_l, new_l: jax.lax.dynamic_update_slice_in_dim(
                     full_l, new_l.astype(full_l.dtype), slot, axis=1),
                 per_slot, ps_new)
-        new_states.append(block.paged_merge(sh_new, ps_new))
+        new_states.append(_merge_carried(block, pool, ps_new))
     return x, new_states
 
 

@@ -55,7 +55,7 @@ def test_latent_decode_kernel_matches_its_twin(dtype):
                                                   dtype=jnp.int32))
     table = table.reshape(b, w)
     pos = jnp.asarray([5, 15, 300], jnp.int32)
-    args = (q, pool, None, table, pos)
+    args = (q, pool[None], None, jnp.zeros((1,), jnp.int32), table, pos)
     kw = dict(scale=192**-0.5, v_dim=r)
     got = ops.paged_decode(*args, impl="interpret", **kw)
     want = ops.paged_decode(*args, impl="ref", **kw)

@@ -8,8 +8,9 @@ Two layers of contract:
   capacity), ragged table tails (pos mid-block), tables of several
   chunks (live length mid-chunk and on a chunk edge, a prime width,
   zeroed dead rows), GQA/MQA groups, bf16 and the i8 KV pool; the chunk
-  rule against a brute-force count; plus the fused XNOR linear against
-  its unfused chain.  These
+  rule against a brute-force count; a layer-stacked pool read at each of
+  its layers (GQA bf16, i8, a window ring, a latent pool); plus the fused
+  XNOR linear against its unfused chain.  These
   are allclose pins: the online-softmax recurrence equals one-shot softmax
   exactly in real arithmetic but not bit-for-bit in floats.
 
@@ -68,12 +69,17 @@ def _case(*, b=3, kv=2, g=2, dh=16, bs=8, w=5, dtype=jnp.float32, i8=False,
         float(out_scale)
 
 
+LAYER0 = jnp.zeros((1,), jnp.int32)
+
+
 def _parity(q, ck, cv, table, pos, *, window, scale, out_scale, tol):
+    """Kernel against oracle over a one-layer pool (a stack of one)."""
     got = paged_attn.paged_decode_attention(
-        q, ck, cv, table, jnp.asarray(pos, jnp.int32), window=window,
-        scale=scale, out_scale=out_scale, interpret=True)
-    want = ref.paged_decode(q, ck, cv, table, jnp.asarray(pos, jnp.int32),
-                            window=window, scale=scale, out_scale=out_scale)
+        q, ck[None], cv[None], LAYER0, table, jnp.asarray(pos, jnp.int32),
+        window=window, scale=scale, out_scale=out_scale, interpret=True)
+    want = ref.paged_decode(q, ck[None], cv[None], LAYER0, table,
+                            jnp.asarray(pos, jnp.int32), window=window,
+                            scale=scale, out_scale=out_scale)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                rtol=tol, atol=tol)
@@ -151,6 +157,60 @@ def test_kernel_chunked_walk(name):
             out_scale=out_scale, tol=2e-5)
 
 
+# a pool of three layers, each its own draw, read at each layer in turn
+LAYER_CASES = {
+    "gqa_bf16": dict(dtype=jnp.bfloat16, pos=[3, 17, 39], tol=3e-2),
+    "i8": dict(i8=True, pos=[5, 19, 38], tol=2e-5),
+    "ring": dict(pos=[45, 80, 113], window=12, tol=2e-5),
+    "latent": dict(latent=True, pos=[0, 13, 39], tol=2e-5),
+}
+
+
+def _stacked_case(n_layers, *, latent=False, **c):
+    """(q, ck, cv, table, scale, out_scale, v_dim) over a layer-stacked
+    pool whose layers hold different draws.  A latent pool is one slab
+    of 256 lanes per token under 4 query heads, values its first 128."""
+    if latent:
+        rng = np.random.default_rng(0)
+        b, h, lat, bs, w = 3, 4, 256, 8, 5
+        q = jnp.asarray(rng.standard_normal((b, 1, h, lat)), jnp.float32)
+        ck = jnp.asarray(rng.standard_normal(
+            (n_layers, 1 + b * w, 1, bs, lat)), jnp.float32)
+        table = jnp.asarray(rng.permutation(b * w).reshape(b, w) + 1,
+                            jnp.int32)
+        return q, ck, None, table, lat ** -0.5, 1.0, 128
+    draws = [_case(seed=l, **c) for l in range(n_layers)]
+    q, _, _, table, scale, out_scale = draws[0]
+    ck = jnp.stack([d[1] for d in draws])
+    cv = jnp.stack([d[2] for d in draws])
+    return q, ck, cv, table, scale, out_scale, 0
+
+
+@pytest.mark.parametrize("name", list(LAYER_CASES))
+def test_kernel_reads_the_layer_it_is_given(name):
+    """The kernel handed the whole layer-stacked pool and layer l equals
+    the oracle over layer l's pool alone, for every l; the layers'
+    outputs differ, so a kernel reading another layer would fail."""
+    c = dict(LAYER_CASES[name])
+    pos = jnp.asarray(c.pop("pos"), jnp.int32)
+    window, tol = c.pop("window", 0), c.pop("tol")
+    q, ck, cv, table, scale, out_scale, v_dim = _stacked_case(3, **c)
+    kw = dict(window=window, scale=scale, out_scale=out_scale, v_dim=v_dim)
+    outs = []
+    for layer in range(ck.shape[0]):
+        got = paged_attn.paged_decode_attention(
+            q, ck, cv, jnp.asarray([layer], jnp.int32), table, pos,
+            interpret=True, **kw)
+        want = ref.paged_decode(q, ck[layer][None],
+                                None if cv is None else cv[layer][None],
+                                LAYER0, table, pos, **kw)
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=tol, atol=tol)
+        outs.append(np.asarray(got, np.float32))
+    assert not np.allclose(outs[0], outs[1], rtol=tol, atol=tol)
+
+
 def _brute_walk(pos, *, p, bs, w, window):
     """(blocks a walk must read, blocks holding attended keys) of one row
     at ``pos``, from the oracle's key mask."""
@@ -199,12 +259,12 @@ def test_kernel_is_one_dispatch():
     import functools
     q, ck, cv, table, scale, out_scale = _case()
     pos = jnp.asarray([3, 17, 39], jnp.int32)
+    args = (q, ck[None], cv[None], LAYER0, table, pos)
     fused = functools.partial(paged_attn.paged_decode_attention, window=0,
                               scale=scale, interpret=True)
     unfused = functools.partial(ref.paged_decode, window=0, scale=scale)
-    nf = analysis.dispatch_count(jax.make_jaxpr(fused)(q, ck, cv, table, pos))
-    nu = analysis.dispatch_count(
-        jax.make_jaxpr(unfused)(q, ck, cv, table, pos))
+    nf = analysis.dispatch_count(jax.make_jaxpr(fused)(*args))
+    nu = analysis.dispatch_count(jax.make_jaxpr(unfused)(*args))
     assert nf == 1
     assert nu > nf
 
@@ -216,11 +276,11 @@ def test_ops_paged_decode_dispatch(impl, monkeypatch):
     monkeypatch.setattr(ops, "_FORCE", "")
     q, ck, cv, table, scale, out_scale = _case()
     pos = jnp.asarray([3, 17, 39], jnp.int32)
-    want = ref.paged_decode(q, ck, cv, table, pos, scale=scale)
-    got = ops.paged_decode(q, ck, cv, table, pos, scale=scale, impl=impl)
+    args = (q, ck[None], cv[None], LAYER0, table, pos)
+    want = ref.paged_decode(*args, scale=scale)
+    got = ops.paged_decode(*args, scale=scale, impl=impl)
     nf = analysis.dispatch_count(jax.make_jaxpr(
-        lambda *a: ops.paged_decode(*a, scale=scale, impl=impl))(
-            q, ck, cv, table, pos))
+        lambda *a: ops.paged_decode(*a, scale=scale, impl=impl))(*args))
     if impl == "ref":
         assert np.array_equal(np.asarray(got), np.asarray(want))
         assert nf > 1

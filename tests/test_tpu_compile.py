@@ -10,7 +10,8 @@ dh 128, with the serve geometry of ``chip_smoke.py`` (8 slots, 16-token
 blocks, 50-block tables); and Moonlight-16B-A3B's latent attention (16
 heads over one latent of 576 values stored at 640 lanes, 352-block tables, 32 slots) and held
 experts (8 of d 2048, ff 1408).  Each test asserts that the kernel is in the
-compiled program (``tpu_custom_call``).
+compiled program (``tpu_custom_call``); the served programs' tests also pin
+what the compiled step holds of the layer-stacked KV pool.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports this
@@ -79,23 +80,24 @@ def compile_text(one_chip, monkeypatch):
 
 @pytest.mark.parametrize("pool", [jnp.bfloat16, jnp.int8], ids=["bf16", "i8"])
 def test_paged_decode_attention_compiles(compile_text, pool):
-    def fn(q, ck, cv, table, pos):
-        return ops.paged_decode(q, ck, cv, table, pos, scale=DH**-0.5,
-                                impl="pallas")
+    def fn(q, ck, cv, layer, table, pos):
+        return ops.paged_decode(q, ck, cv, layer, table, pos,
+                                scale=DH**-0.5, impl="pallas")
     text = compile_text(fn, ((SLOTS, KV, G, DH), jnp.bfloat16),
-                        ((N_BLOCKS, KV, BS, DH), pool),
-                        ((N_BLOCKS, KV, BS, DH), pool),
-                        ((SLOTS, W), jnp.int32), ((SLOTS,), jnp.int32))
+                        ((36, N_BLOCKS, KV, BS, DH), pool),
+                        ((36, N_BLOCKS, KV, BS, DH), pool),
+                        ((1,), jnp.int32), ((SLOTS, W), jnp.int32),
+                        ((SLOTS,), jnp.int32))
     assert "tpu_custom_call" in text
 
 
 def test_latent_paged_decode_attention_compiles(compile_text):
-    def fn(q, ck, table, pos):
-        return ops.paged_decode(q, ck, None, table, pos, scale=192**-0.5,
-                                v_dim=ML_R, impl="pallas")
+    def fn(q, ck, layer, table, pos):
+        return ops.paged_decode(q, ck, None, layer, table, pos,
+                                scale=192**-0.5, v_dim=ML_R, impl="pallas")
     text = compile_text(fn, ((ML_SLOTS, 1, ML_H, ML_LAT), jnp.bfloat16),
-                        ((1024, 1, BS, ML_LAT), jnp.bfloat16),
-                        ((ML_SLOTS, ML_W), jnp.int32),
+                        ((27, 1024, 1, BS, ML_LAT), jnp.bfloat16),
+                        ((1,), jnp.int32), ((ML_SLOTS, ML_W), jnp.int32),
                         ((ML_SLOTS,), jnp.int32))
     assert "tpu_custom_call" in text
 
@@ -217,3 +219,135 @@ def test_decode_step_names_its_kernels_and_scopes(compile_text, one_chip,
                             r'|mlp|head|sample)/', text))
     assert scopes == {"embed", "attn.kv_write", "attn.core", "mlp", "head",
                       "sample"}
+
+
+def _serve_fn(cfg, program):
+    """The engine's decode or chunk program body (``serve/engine.py``):
+    the model step, then greedy sampling."""
+    from repro.models import lm
+    from repro.serve import engine
+
+    def decode(params, tokens, state, tables, active, key, seeds):
+        logits, state, counts = lm.paged_decode_step(cfg, params, tokens,
+                                                     state, tables,
+                                                     active=active)
+        return (engine._sample_tokens(cfg, logits, key, seeds, 0.0), state,
+                counts)
+
+    def chunk(params, tokens, state, slot, n_valid, tables, fresh, start,
+              key, seeds):
+        logits, state = lm.prefill_chunk_step(cfg, params, tokens, state,
+                                              slot, n_valid, tables,
+                                              fresh=fresh, start=start)
+        return engine._sample_tokens(cfg, logits, key, seeds, 0.0), state
+    return decode if program == "decode" else chunk
+
+
+def _serve_specs(cfg, program, slots, w, n_blocks):
+    """Abstract arguments of :func:`_serve_fn`'s program at a pool of
+    ``n_blocks`` blocks."""
+    from repro.models import lm
+
+    i32 = jax.ShapeDtypeStruct((), jnp.int32)
+    s_max = w * BS
+    rows = slots if program == "decode" else 1
+    tables = {c: jax.ShapeDtypeStruct((rows, tw), jnp.int32) for c, tw in
+              lm.paged_table_widths(cfg, s_max, BS, cfg.prefill_chunk).items()}
+    state = lm.paged_decode_state_spec(cfg, slots, s_max, n_blocks=n_blocks,
+                                       block_size=BS)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32)
+    if program == "decode":
+        return (lm.packed_abstract_params(cfg),
+                jax.ShapeDtypeStruct((slots, 1), jnp.int32), state, tables,
+                jax.ShapeDtypeStruct((slots,), jnp.bool_), key,
+                jax.ShapeDtypeStruct((slots,), jnp.int32))
+    return (lm.packed_abstract_params(cfg),
+            jax.ShapeDtypeStruct((1, cfg.prefill_chunk), jnp.int32), state,
+            i32, i32, tables, jax.ShapeDtypeStruct((), jnp.bool_), i32, key,
+            jax.ShapeDtypeStruct((1,), jnp.int32))
+
+
+def _compile_serve(one_chip, cfg, program, slots, w, n_blocks):
+    """(compiled program, its state spec), the state donated as the engine
+    donates it."""
+    specs = _serve_specs(cfg, program, slots, w, n_blocks)
+    args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), specs)
+    compiled = jax.jit(_serve_fn(cfg, program),
+                       donate_argnums=(2,)).lower(*args).compile()
+    return compiled, specs[2]
+
+
+def _pool_shapes(state):
+    """Every shape a layer-stacked pool leaf (L, n_blocks, heads, bs,
+    width) or one layer of it takes, also as rows (heads·bs, width)."""
+    shapes = set()
+    for leaf in jax.tree.leaves(state):
+        if leaf.ndim != 5 or not leaf.size:
+            continue
+        n, blocks, heads, bs, width = leaf.shape
+        for rows in ((heads, bs), (heads * bs,)):
+            shapes |= {(n, blocks, *rows, width), (blocks, *rows, width)}
+    return shapes
+
+
+def _pool_sized_ops(text, shapes):
+    """Instructions of a compiled program whose result has a pool's shape
+    and which are not a view of it (parameter, tuple element, bitcast) or
+    the in-place token write (a scatter, alone or as a fusion's root)."""
+    import re
+
+    inst = re.compile(r"\s*(ROOT )?%\S+ = \w+\[([\d,]*)\]\S* ([\w\-]+)\(")
+    roots, ops_, comp = {}, [], None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?(%\S+) .*\{$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        m = inst.match(line)
+        if not m:
+            continue
+        root, dims, op = m.groups()
+        if root:
+            roots[comp] = op
+        calls = re.search(r"calls=(%[\w.\-]+)", line)
+        ops_.append((tuple(int(d) for d in dims.split(",") if d), op,
+                     calls and calls.group(1), line.strip()[:160]))
+    return [line for dims, op, calls, line in ops_ if dims in shapes and not (
+        op in ("parameter", "get-tuple-element", "bitcast", "scatter")
+        or op == "fusion" and roots.get(calls) == "scatter")]
+
+
+POOL_BLOCKS = (3000, 6000)
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+@pytest.mark.parametrize("arch,layers,slots,w", [
+    ("qwen3-4b+xnor", 2, SLOTS, W),
+    ("moonlight-16b-a3b", 3, ML_SLOTS, ML_W)],
+    ids=["qwen3-4b-xnor", "moonlight-16b-a3b"])
+def test_serve_programs_hold_the_pool_once(one_chip, monkeypatch, arch,
+                                           layers, slots, w, program):
+    """The served decode and chunk programs carry the layer-stacked KV pool
+    through their layer scans and write each token in place: compiled at
+    two pool sizes, their temporary memory does not grow with the pool,
+    and no instruction makes a pool-sized or layer-sized copy, slice or
+    restack of it; only the token write has its shape.  Moonlight takes
+    three layers so that its MoE segment scans two."""
+    import dataclasses
+
+    from repro import configs
+
+    monkeypatch.setattr(ops, "_FORCE", "pallas")
+    cfg = dataclasses.replace(configs.get(arch), n_layers=layers)
+    temp, pool = {}, {}
+    for n_blocks in POOL_BLOCKS:
+        compiled, state = _compile_serve(one_chip, cfg, program, slots, w,
+                                         n_blocks)
+        temp[n_blocks] = compiled.memory_analysis().temp_size_in_bytes
+        pool[n_blocks] = sum(l.size * l.dtype.itemsize
+                             for l in jax.tree.leaves(state) if l.ndim == 5)
+        assert _pool_sized_ops(compiled.as_text(), _pool_shapes(state)) == []
+    small, large = POOL_BLOCKS
+    assert temp[large] - temp[small] < 0.02 * (pool[large] - pool[small])
+
