@@ -153,7 +153,7 @@ def _run_smoke_engine(fused: str):
     try:
         cfg = configs.get("qwen3-4b").smoke(dtype=jnp.float32)
         params = lm.init_params(cfg, jax.random.PRNGKey(7))
-        eng = ServeEngine(cfg, params, slots=2, s_max=24, paged=True)
+        eng = ServeEngine(cfg, params, slots=2, s_max=24)
         for r in synthetic_trace(4, cfg.vocab, seed=7):
             eng.submit(r)
         rep = eng.run()

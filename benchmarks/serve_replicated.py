@@ -65,7 +65,7 @@ def run_single(cfg, params, trace, slots: int, s_max: int, seed: int,
     from repro.serve import ServeEngine
 
     eng = ServeEngine(cfg, params, slots=slots, s_max=s_max, seed=seed,
-                      pack=pack, paged=True)
+                      pack=pack)
     for r in trace:
         eng.submit(r)
     return eng.run()

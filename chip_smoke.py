@@ -75,8 +75,8 @@ ATTN_RTOL = ATTN_ATOL = 2.0**-6
 # round apart).
 LINEAR_RTOL = 2.0**-7
 # End to end, the fused step's logits against the unfused chain's
-# (``fused_decode="off"``) are read at each depth cut and held at the first
-# layer only.  A binarized layer turns a rounding-level difference in an
+# (``REPRO_FUSED_DECODE=off``) are read at each depth cut and held at the
+# first layer only.  A binarized layer turns a rounding-level difference in an
 # input near zero into a flipped sign bit, which moves its outputs by about
 # 2/sqrt(K) of their scale, and random binary layers compound flipped bits
 # with depth: on a v5e two routes with no kernel at all (the oracles at
@@ -119,13 +119,13 @@ def _close(what: str, got, want, rtol: float, atol: float) -> float:
 # ---------------------------------------------------------------------------
 
 
-def device_check(cfg):
+def device_check():
     import jax
     from repro.kernels import ops
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         raise SystemExit(f"chip_smoke: no TPU (JAX found {dev.platform})")
-    impl, fused = ops._resolve("auto"), ops.fused_mode(cfg.fused_decode)
+    impl, fused = ops._resolve("auto"), ops.fused_mode()
     if impl != "pallas" or fused != "kernel":
         raise SystemExit(f"chip_smoke: kernel impl {impl!r}, fused decode "
                          f"{fused!r}; the smoke run needs pallas / kernel")
@@ -568,7 +568,7 @@ def main(argv=None) -> int:
     import repro.configs as configs
     cfg = configs.get(ARCH)
 
-    info = _phase("device", device_check, cfg)
+    info = _phase("device", device_check)
     if args.chips == 4:
         _phase("four_chips", four_chips, cfg, args.seed)
     else:

@@ -93,8 +93,8 @@ shared = synthetic_trace(6, cfg.vocab, seed=11, prompt_lens=(4, 7),
                          prefix_len=6 * cfg.block_size + 3)
 reports = {}
 for on in (True, False):
-    eng3 = ServeEngine(cfg, params, slots=2, s_max=64, seed=0, paged=True,
-                       n_blocks=40, prefix_cache=on)
+    eng3 = ServeEngine(cfg, params, slots=2, s_max=64, seed=0, n_blocks=40,
+                       prefix_cache=on)
     for r in shared:
         eng3.submit(r)
     reports[on] = eng3.run()

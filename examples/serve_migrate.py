@@ -26,7 +26,7 @@ cfg = configs.get("qwen3-4b").smoke()
 params = lm.init_params(cfg, jax.random.PRNGKey(0))
 trace = synthetic_trace(3, cfg.vocab, seed=5, prompt_lens=(6, 10),
                         new_tokens=(8, 12))
-KW = dict(slots=2, s_max=32, seed=0, paged=True)
+KW = dict(slots=2, s_max=32, seed=0)
 
 # --- 1. the baseline: one engine, never migrated ----------------------------
 

@@ -87,11 +87,6 @@ class ArchConfig:
                                    # ~2% rounding)
     block_size: int = 16           # paged KV-cache tokens per block
     prefill_chunk: int = 32        # chunked-prefill piece size (serve)
-    fused_decode: str = "auto"     # decode-path kernel fusion (DESIGN.md §18):
-                                   # "auto" (fused Pallas kernels on real TPU,
-                                   # unfused bit-exact twin elsewhere) |
-                                   # "on"/"kernel" | "off"/"ref"; the
-                                   # REPRO_FUSED_DECODE env var overrides
     supports_long_context: bool = False
     notes: str = ""
 

@@ -93,18 +93,17 @@ def xnor_linear(x: jnp.ndarray, w: jnp.ndarray, *, packed: bool = False,
 
 
 def xnor_linear_prepacked(x: jnp.ndarray, pb: jnp.ndarray, beta: jnp.ndarray,
-                          valid_k: int, *, impl: str = "auto",
-                          mode: str = "auto") -> jnp.ndarray:
+                          valid_k: int, *, impl: str = "auto") -> jnp.ndarray:
     """Inference with weights already packed offline.
 
     ``pb``: (N, Kw) uint32, ``beta``: (N,) f32.  The weight matrix never
     exists in float form at serve time — a 16x memory-footprint reduction vs
     bf16 (the CiM array storing binary filters in the paper).
 
-    ``mode`` (resolved by :func:`ops.fused_mode`) selects between the fused
-    single-dispatch kernel (binarize + popcount GEMM + alpha/beta epilogue
-    in one pass, DESIGN.md §18) and the unfused three-dispatch chain below —
-    the fused path's bit-exact-twin reference on ref/interpret backends.
+    :func:`ops.fused_mode` selects between the fused single-dispatch kernel
+    (binarize + popcount GEMM + alpha/beta epilogue in one pass, DESIGN.md
+    §18) and the unfused three-dispatch chain below — the fused path's
+    bit-exact-twin reference on ref/interpret backends.
     """
     lead, k = x.shape[:-1], x.shape[-1]
     if k != valid_k:
@@ -114,7 +113,7 @@ def xnor_linear_prepacked(x: jnp.ndarray, pb: jnp.ndarray, beta: jnp.ndarray,
         raise ValueError(
             f"activation width {k} != packed weight's true K {valid_k}")
     x2 = x.reshape(-1, k)
-    if ops.fused_mode(mode) == "kernel":
+    if ops.fused_mode() == "kernel":
         y = ops.xnor_linear_fused(x2, pb, beta, valid_k, impl=impl)
         return y.reshape(*lead, pb.shape[0]).astype(x.dtype)
     alpha = jnp.mean(jnp.abs(x2), axis=-1)

@@ -43,29 +43,29 @@ def _resolve(impl: str) -> str:
     return impl
 
 
-def fused_mode(mode: str = "auto") -> str:
+def fused_mode() -> str:
     """Resolve the fused-decode dispatch: ``"kernel"`` or ``"ref"``.
 
     ``"kernel"`` routes the decode hot path through the single-dispatch
-    Pallas kernels (paged_attn / the fused packed linear); ``"ref"`` keeps
-    the unfused jnp chain, which is the kernels' bit-exact reference twin
-    (DESIGN.md §18).  ``"auto"`` picks the kernel exactly when the base
-    dispatch resolves to real-TPU pallas — on ref/interpret backends the
-    chain stays unfused so every cross-layout token pin (paged == dense,
-    prefix on == off, migration identity) remains bitwise across both
-    ``REPRO_KERNEL_IMPL`` CI modes.  The ``REPRO_FUSED_DECODE`` env var
-    (read per call, so tests can monkeypatch) overrides ``mode``:
-    on/kernel/fused force the kernel, off/ref/unfused force the chain.
+    Pallas kernels (paged_attn / the fused packed linear / the grouped
+    expert FFN); ``"ref"`` keeps the unfused jnp chain, which is the
+    kernels' bit-exact reference twin (DESIGN.md §18).  The platform
+    decides: the kernel exactly when the base dispatch resolves to
+    real-TPU pallas — on ref/interpret backends the chain stays unfused so
+    every token pin (paged == reference loop, prefix on == off, migration
+    identity) remains bitwise across both ``REPRO_KERNEL_IMPL`` CI modes.
+    The ``REPRO_FUSED_DECODE`` env var (read per call, so tests can
+    monkeypatch) overrides that: ``on`` forces the kernel, ``off`` the
+    chain; unset or empty leaves the platform's choice.
     """
-    mode = os.environ.get("REPRO_FUSED_DECODE", "") or mode
-    if mode in ("on", "kernel", "fused"):
+    mode = os.environ.get("REPRO_FUSED_DECODE", "")
+    if mode == "on":
         return "kernel"
-    if mode in ("off", "ref", "unfused"):
+    if mode == "off":
         return "ref"
-    if mode != "auto":
+    if mode:
         raise ValueError(
-            f"unknown fused-decode mode {mode!r} (from REPRO_FUSED_DECODE or"
-            " cfg.fused_decode); expected auto|on|kernel|fused|off|ref|unfused")
+            f"unknown REPRO_FUSED_DECODE value {mode!r}; expected on|off")
     return "kernel" if _resolve("auto") == "pallas" else "ref"
 
 

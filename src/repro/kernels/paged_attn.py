@@ -77,7 +77,7 @@ real arithmetic but not bit-for-bit in floats (association/rounding).  The
 model-level dispatch therefore routes this kernel on real TPU backends and
 keeps the jnp chain — which doubles as this kernel's reference twin
 (:func:`repro.kernels.ref.paged_decode` is the semantic oracle) — on
-ref/interpret backends, so every cross-layout token pin (paged == dense,
+ref/interpret backends, so every token pin (paged == the reference loop,
 prefix on == off, migration identity) stays bit-exact in both CI modes.
 ``REPRO_FUSED_DECODE=on`` forces the kernel everywhere (parity tests and
 the microbenchmark do this explicitly).

@@ -4,8 +4,7 @@ mixed-length request trace (container scale uses --smoke reduced configs).
 Requests draw prompt length and token budget independently, so slots free
 at staggered times and admission (prefill interleaved with decode) runs
 throughout.  Reports aggregate throughput and per-request latency
-quantiles; ``--static`` runs the legacy one-batch ``generate`` path
-instead, for an A/B on the same machine.
+quantiles.
 
 ``--prefix-len N`` gives a ``--prefix-frac`` fraction of the trace a
 shared N-token leading prefix (the system-prompt regime); the paged
@@ -34,7 +33,6 @@ import argparse
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 import repro.configs as configs
@@ -43,7 +41,6 @@ from repro.models import lm
 from repro.serve import (ClassifierService, ServeEngine,
                          TranscriptionService, synthetic_audio_trace,
                          synthetic_trace)
-from repro.train import serve_step
 
 
 def _run_transcribe(cfg, params, args) -> int:
@@ -103,10 +100,6 @@ def _run_replicated(cfg, params, trace, s_max, args) -> int:
 
     from repro.serve import Router
 
-    if args.dense:
-        raise SystemExit("--replicas > 1 needs the paged layout "
-                         "(drop --dense): migration extracts state "
-                         "through per-slot block tables")
     with contextlib.ExitStack() as stack:
         ckpt_dir = args.ckpt_dir or stack.enter_context(
             tempfile.TemporaryDirectory(prefix="serve_mig_"))
@@ -161,10 +154,6 @@ def main() -> int:
     ap.add_argument("--eos-id", type=int, default=None)
     ap.add_argument("--no-pack", action="store_true",
                     help="serve quant archs from float weights (A/B)")
-    ap.add_argument("--static", action="store_true",
-                    help="legacy static-batch generate() instead")
-    ap.add_argument("--dense", action="store_true",
-                    help="slot-dense KV layout instead of block-paged (A/B)")
     ap.add_argument("--block-size", type=int, default=0,
                     help="paged-KV tokens per block (0: cfg.block_size)")
     ap.add_argument("--prefill-chunk", type=int, default=0,
@@ -222,36 +211,13 @@ def main() -> int:
         prefix_frac=args.prefix_frac, prefix_len=args.prefix_len)
     s_max = args.s_max or (args.prefix_len + pl + nt)
 
-    if args.static:
-        # the TRUE legacy path (generate_static, not the engine wrapper):
-        # one fixed batch, uniform shapes, eager per-token dispatch.
-        # independent streams for prompt / ctx / sampling, per the PR-2 fix
-        # (one shared key correlates generated tokens with the inputs).
-        prompt_key, ctx_key, sample_key = jax.random.split(
-            jax.random.PRNGKey(args.seed + 1), 3)
-        prompt = jax.random.randint(prompt_key, (args.slots, pl), 0,
-                                    cfg.vocab)
-        ctx = None
-        if cfg.n_ctx_tokens:
-            ctx = jax.random.normal(
-                ctx_key, (args.slots, cfg.n_ctx_tokens, cfg.d_model),
-                jnp.float32) * 0.1
-        t0 = time.time()
-        out = serve_step.generate_static(
-            cfg, params, prompt, nt, ctx=ctx, temperature=args.temperature,
-            key=sample_key if args.temperature > 0 else None)
-        dt = time.time() - t0
-        print(f"arch={cfg.name} static generate {out.shape} in {dt:.2f}s "
-              f"({args.slots * nt / dt:.1f} tok/s)")
-        return 0
-
     if args.replicas > 1:
         return _run_replicated(cfg, params, trace, s_max, args)
 
     eng = ServeEngine(cfg, params, slots=args.slots, s_max=s_max,
                       eos_id=args.eos_id, temperature=args.temperature,
                       seed=args.seed, pack=not args.no_pack,
-                      paged=not args.dense, block_size=args.block_size,
+                      block_size=args.block_size,
                       prefill_chunk=args.prefill_chunk,
                       n_blocks=args.n_blocks,
                       prefix_cache=not args.no_prefix_cache)
@@ -263,7 +229,7 @@ def main() -> int:
     qwait = report.queue_wait_quantiles((0.5, 0.95))
     packed = (not args.no_pack) and cfg.quant == "xnor"
     print(f"arch={cfg.name} slots={args.slots} requests={len(trace)} "
-          f"packed={packed} layout={'dense' if args.dense else 'paged'}")
+          f"packed={packed}")
     print(f"  generated {report.generated} tokens in {report.wall:.2f}s "
           f"-> {report.tok_per_s:.1f} tok/s "
           f"({report.prefills} prefills, {report.decode_steps} decode steps)")
@@ -275,7 +241,7 @@ def main() -> int:
           f"(queue-wait p50={qwait[0.5]*1e3:.0f}ms "
           f"p95={qwait[0.95]*1e3:.0f}ms)")
     st = report.stats
-    if not args.dense and st.blocks_total:
+    if st.blocks_total:
         print(f"  blocks: peak {st.blocks_peak}/{st.blocks_total} "
               f"mean {st.blocks_mean:.1f} "
               f"(util {st.block_utilization:.0%}); "

@@ -393,18 +393,15 @@ def decode_attention(cfg, p: dict, x: jnp.ndarray, cache: KVCache,
                      pos: jnp.ndarray, window: int = 0):
     """One-token attention against a resident cache (compact GQA form).
 
-    x: (B, 1, d). pos: int32 — current position (cache holds pos valid
-    entries before this call).  Either a scalar (homogeneous batch: one
-    slice-update covers all rows) or a (B,) vector (continuous-batching
-    serve: each slot advances independently, writes scatter per row).
-    Returns (out (B, 1, d), new cache).  For local layers the cache is a
-    rolling buffer of size window and the write position wraps
+    x: (B, 1, d). pos: int32 scalar — the batch's current position (cache
+    holds pos valid entries before this call; one slice-update covers all
+    rows).  Returns (out (B, 1, d), new cache).  For local layers the cache
+    is a rolling buffer of size window and the write position wraps
     (pos % window).
     """
     b = x.shape[0]
     s_max = cache.k.shape[2]
     pos = jnp.asarray(pos, jnp.int32)
-    per_slot = pos.ndim == 1
     pos_b = jnp.broadcast_to(pos, (b,))
     positions = pos_b[:, None]
     if cfg.latent:
@@ -426,17 +423,11 @@ def decode_attention(cfg, p: dict, x: jnp.ndarray, cache: KVCache,
     i8 = cache.k.dtype == jnp.int8
     if i8:  # fixed-point low-bit cache (paper-domain: quantized residency)
         knew, vnew = i8_encode(cfg, knew), i8_encode(cfg, vnew)
-    if per_slot:
-        upd = jax.vmap(lambda c, new, s:
-                       jax.lax.dynamic_update_slice_in_dim(c, new, s, axis=1))
-        ck = upd(cache.k, knew.astype(cache.k.dtype), slot_b)
-        cv = upd(cache.v, vnew.astype(cache.v.dtype), slot_b)
-    else:
-        slot = pos % s_max if window else pos
-        ck = jax.lax.dynamic_update_slice_in_dim(
-            cache.k, knew.astype(cache.k.dtype), slot, axis=2)
-        cv = jax.lax.dynamic_update_slice_in_dim(
-            cache.v, vnew.astype(cache.v.dtype), slot, axis=2)
+    slot = pos % s_max if window else pos
+    ck = jax.lax.dynamic_update_slice_in_dim(
+        cache.k, knew.astype(cache.k.dtype), slot, axis=2)
+    cv = jax.lax.dynamic_update_slice_in_dim(
+        cache.v, vnew.astype(cache.v.dtype), slot, axis=2)
 
     scale = cfg.d_head ** -0.5
     if i8:
@@ -563,7 +554,7 @@ def paged_attention(cfg, p: dict, x: jnp.ndarray, cache: LayerOf,
     scale = cfg.d_head ** -0.5
     if i8:
         scale = scale / cfg.kv_i8_scale
-    if c == 1 and ops.fused_mode(cfg.fused_decode) == "kernel":
+    if c == 1 and ops.fused_mode() == "kernel":
         # single-dispatch decode: the Pallas kernel walks the block table via
         # scalar prefetch and streams the layer's pool blocks through VMEM
         # from the stack in place (DESIGN.md §18) — the gather/mask/
@@ -647,7 +638,7 @@ def _paged_latent(cfg, p, x, cache: LayerOf, table, qpos, valid):
         phys = _write_blocks(table, qpos, bs, valid)
         ck = _write_tokens(pool.k, layer, phys, qpos % bs, lat[:, :, None])
     new = LayerOf(PagedKVCache(ck, pool.v), layer)
-    if c == 1 and ops.fused_mode(cfg.fused_decode) == "kernel":
+    if c == 1 and ops.fused_mode() == "kernel":
         o_lat = ops.paged_decode(q[:, 0][:, None], ck, None,
                                  jnp.reshape(layer, (1,)), table, qpos[:, 0],
                                  scale=mla_scale(cfg), v_dim=cfg.kv_lora_rank)

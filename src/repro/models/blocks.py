@@ -358,7 +358,7 @@ class MoeBlock(AttnBlock):
         # the serving paths take the grouped expert kernel where the
         # kernels are on (DESIGN.md §18); training keeps the dense twin
         h = layers.rms_norm(x, p["ln2"], cfg.norm_eps)
-        grouped = serve and ops.fused_mode(cfg.fused_decode) == "kernel"
+        grouped = serve and ops.fused_mode() == "kernel"
         y, aux, counts = moe.moe_ffn(cfg, _sub(p, "moe_"), h, valid=valid,
                                      grouped=grouped)
         return x + y, aux, counts

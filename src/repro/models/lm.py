@@ -175,14 +175,11 @@ class DecodeState(NamedTuple):
     ctx: Any = None           # encoded modality context (or None)
 
 
-def decode_state_spec(cfg, batch: int, s_max: int, abstract: bool = True,
-                      per_slot_pos: bool = False):
-    """The resident serving state for (arch, batch, cache length).
-
-    ``per_slot_pos=True`` gives the continuous-batching layout: ``pos`` is a
-    (batch,) vector so heterogeneous requests can share the batch, each slot
-    advancing independently (repro.serve).
-    """
+def decode_state_spec(cfg, batch: int, s_max: int, abstract: bool = True):
+    """The slot-dense decode state for (arch, batch, cache length), one
+    position for the whole batch: what :func:`prefill` builds and
+    :func:`decode_step` advances (the dry-run lowers it).  The serve engine
+    keeps :func:`paged_decode_state_spec` resident instead."""
     seg_states = blocks.segment_states(cfg, cfg.segments(), batch, s_max,
                                        abstract)
     ctx = None
@@ -190,9 +187,8 @@ def decode_state_spec(cfg, batch: int, s_max: int, abstract: bool = True,
         shp = (batch, cfg.n_ctx_tokens, cfg.d_model)
         ctx = (jax.ShapeDtypeStruct(shp, cfg.dtype) if abstract
                else jnp.zeros(shp, cfg.dtype))
-    pshape = (batch,) if per_slot_pos else ()
-    pos = (jax.ShapeDtypeStruct(pshape, jnp.int32) if abstract
-           else jnp.zeros(pshape, jnp.int32))
+    pos = (jax.ShapeDtypeStruct((), jnp.int32) if abstract
+           else jnp.zeros((), jnp.int32))
     return DecodeState(pos, tuple(seg_states), ctx)
 
 
@@ -296,25 +292,17 @@ def prefill(cfg, params, tokens: jnp.ndarray, ctx: jnp.ndarray | None,
 
 
 def decode_step(cfg, params, token: jnp.ndarray, state: DecodeState,
-                unroll: bool = False, active: jnp.ndarray | None = None):
-    """token (B, 1) int32 -> (logits (B, 1, V), new state).
-
-    ``state.pos`` may be a scalar (homogeneous batch) or a (B,) vector
-    (continuous batching: per-slot positions).  ``active`` (B,) bool gates
-    the position advance per slot: an inactive slot's pos freezes, so its
-    (dead) cache line is rewritten in place each step instead of walking
-    forward — the slot state stays inert until an admission overwrites it.
-    Inactive rows still flow through the network (their logits are garbage
-    the scheduler ignores).
-    """
+                unroll: bool = False):
+    """token (B, 1) int32 -> (logits (B, 1, V), new state): one token for
+    every row of a slot-dense state (:func:`prefill`'s), all at
+    ``state.pos``."""
     x = layers.embed(params["embed"], token).astype(cfg.dtype)
     x, new_states, _ = blocks.segment_decode(cfg, _seg_params(cfg, params),
                                              x, list(state.seg_states),
                                              state.pos, state.ctx,
                                              unroll=unroll)
     logits = layers.logits(cfg, params["embed"], x)
-    inc = 1 if active is None else active.astype(jnp.int32)
-    return logits, DecodeState(state.pos + inc, tuple(new_states), state.ctx)
+    return logits, DecodeState(state.pos + 1, tuple(new_states), state.ctx)
 
 
 def paged_decode_step(cfg, params, token: jnp.ndarray, state: DecodeState,

@@ -6,19 +6,17 @@ on top of the token-level serve path.  This module provides it:
 
 * a FIFO request queue and a fixed pool of batch **slots** over one resident
   :class:`repro.models.lm.DecodeState` (per-slot position vector);
-* a **block-paged KV cache** (default, DESIGN.md §14): attention state
-  lives in a shared block pool addressed through host-owned per-slot block
-  tables (:class:`repro.serve.pools.BlockPool` allocates; tables are device
+* a **block-paged KV cache** (DESIGN.md §14): attention state lives in a
+  shared block pool addressed through host-owned per-slot block tables
+  (:class:`repro.serve.pools.BlockPool` allocates; tables are device
   *data*), so cache memory is proportional to tokens actually held, not
-  ``slots x s_max``; ``paged=False`` keeps the slot-dense layout — the two
-  are token-identical;
-* **admission**: a freed slot is immediately refilled.  Paged: the
-  request's worst-case blocks are reserved (OOM backpressure holds the
-  FIFO head otherwise) and the prompt is consumed by **chunked prefill** —
-  fixed ``prefill_chunk``-sized pieces through ONE jitted program, so
-  prefill compiles once for any prompt-length mix and long prompts
-  interleave with decode in bounded slices.  Dense: exact-length batch-1
-  prefill scattered into the slot (one trace per distinct length);
+  ``slots x s_max``;
+* **admission**: a freed slot is immediately refilled.  The request's
+  worst-case blocks are reserved (OOM backpressure holds the FIFO head
+  otherwise) and the prompt is consumed by **chunked prefill** — fixed
+  ``prefill_chunk``-sized pieces through ONE jitted program, so prefill
+  compiles once for any prompt-length mix and long prompts interleave
+  with decode in bounded slices;
 * **eviction** on EOS or max-token budget: the slot is marked free and its
   blocks return to the pool; dead rows are inert (position frozen via the
   active mask, table rows zeroed so frozen re-writes land in the reserved
@@ -37,8 +35,7 @@ on top of the token-level serve path.  This module provides it:
 
 With ``pack=True`` (default) and a ``quant="xnor"`` arch the resident
 params are the packed form (:func:`repro.models.lm.pack_params`): binary
-filter planes + beta, float weights absent — packed-weight residency (runs
-on both cache layouts).
+filter planes + beta, float weights absent — packed-weight residency.
 
 Scheduling bookkeeping lives in :mod:`repro.serve.pools` (pure host logic,
 unit-testable without a model), the content-addressed prefix index in
@@ -50,7 +47,6 @@ engine loop that drives them.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import time
 from typing import Any
 
@@ -87,43 +83,6 @@ def _sample_tokens(cfg, logits, key, seeds, temperature: float):
                               jnp.float32)
         return jnp.argmax(row / temperature + g, axis=-1).astype(jnp.int32)
     return jax.vmap(one)(lg, seeds)[:, None]
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("cfg", "s_max", "temperature"))
-def _prefill_program(cfg, params, tokens, ctx, key, seeds, *, s_max: int,
-                     temperature: float):
-    """(1, P) prompt -> (first sampled token (1, 1), DecodeState for B=1)."""
-    logits, state = lm.prefill(cfg, params, tokens, ctx, s_max=s_max)
-    return _sample_tokens(cfg, logits, key, seeds, temperature), state
-
-
-@functools.partial(jax.jit, static_argnames=("cfg", "temperature"),
-                   donate_argnames=("state",))
-def _decode_program(cfg, params, tokens, state, active, key, seeds, *,
-                    temperature: float):
-    """One token for every slot; inactive slots' positions stay frozen."""
-    logits, state = lm.decode_step(cfg, params, tokens, state, active=active)
-    return _sample_tokens(cfg, logits, key, seeds, temperature), state
-
-
-@functools.partial(jax.jit, donate_argnames=("resident",))
-def _insert_program(resident: lm.DecodeState, one: lm.DecodeState, slot):
-    """Scatter a freshly prefilled B=1 state into resident slot ``slot``.
-
-    Segment-state leaves are layer-stacked with batch at axis 1
-    ((n_layers, B, ...)); ``ctx`` and ``pos`` carry batch at axis 0.  The
-    resident tree follows ``lm.decode_state_spec``: for enc-dec archs its
-    ``ctx`` is None (cross-attn KV lives inside the per-layer states; the
-    decode path never reads ``DecodeState.ctx``), so the prefill state's
-    encoder output is dropped rather than kept resident.
-    """
-    seg = jax.tree.map(lambda r, o: r.at[:, slot].set(o[:, 0]),
-                       resident.seg_states, one.seg_states)
-    pos = resident.pos.at[slot].set(one.pos)
-    ctx = (resident.ctx if resident.ctx is None
-           else resident.ctx.at[slot].set(one.ctx[0]))
-    return lm.DecodeState(pos, seg, ctx)
 
 
 @dataclasses.dataclass
@@ -167,10 +126,9 @@ class ServeEngine:
       seed: engine sampling seed.
       pack: keep binarizable linears packed-resident (xnor archs only).
       prefix_cache: content-addressed prefix sharing over the paged pool
-        (DESIGN.md §15; paged engines only).  Auto-disabled for archs whose
-        state cannot be rebuilt from cached blocks (recurrent carries,
-        local window rings) — ``engine.prefix_caching`` reports the
-        effective setting.
+        (DESIGN.md §15).  Auto-disabled for archs whose state cannot be
+        rebuilt from cached blocks (recurrent carries, local window rings)
+        — ``engine.prefix_caching`` reports the effective setting.
       device: the device that holds the resident params and decode state
         (None: leave them where JAX puts them).  Every program then runs
         there, since host inputs follow committed arrays.
@@ -178,10 +136,9 @@ class ServeEngine:
 
     def __init__(self, cfg, params, *, slots: int, s_max: int,
                  eos_id: int | None = None, temperature: float = 0.0,
-                 seed: int = 0, pack: bool = True, paged: bool = True,
-                 block_size: int = 0, prefill_chunk: int = 0,
-                 n_blocks: int = 0, prefix_cache: bool = True,
-                 device=None):
+                 seed: int = 0, pack: bool = True, block_size: int = 0,
+                 prefill_chunk: int = 0, n_blocks: int = 0,
+                 prefix_cache: bool = True, device=None):
         if cfg.latent and cfg.quant != "none":
             raise ValueError(
                 f"{cfg.name}: latent attention is served from float weights "
@@ -198,74 +155,61 @@ class ServeEngine:
         self.pool = SlotPool(slots)
         self.sessions: dict[int, Session] = {}
         self._key = jax.random.PRNGKey(seed)
-        self.paged = bool(paged)
         self.stats = EngineStats()
         self._step_idx = 0                 # engine steps since construction
         # the engine's host time since the last decode token read (None
         # before the first), and when the current stretch of it began
         self._turn_ns: int | None = None
         self._t_mark = 0
-        if self.paged:
-            self.block_size = block_size or cfg.block_size
-            self.prefill_chunk = prefill_chunk or cfg.prefill_chunk
-            self._widths = lm.paged_table_widths(cfg, s_max, self.block_size,
-                                                 self.prefill_chunk)
-            per_slot_worst = sum(self._widths.values())
-            if n_blocks <= 0:
-                # default: enough for every slot at full table width (the
-                # paged layout is then never *smaller* than dense; callers
-                # shrink n_blocks to oversubscribe slots at equal memory)
-                n_blocks = 1 + slots * max(per_slot_worst, 1)
-            self.n_blocks = n_blocks
-            self.blocks = BlockPool(n_blocks) if self._widths else None
-            self.stats.blocks_total = n_blocks - 1 if self.blocks else 0
-            # prefix caching (DESIGN.md §15): only for archs whose whole
-            # sequential state is reconstructible from the paged pools —
-            # prefix_cache_eligible is fail-closed over each kind's
-            # declared prefix_shareable contract flag (recurrent carries
-            # and local window *rings* don't declare it).  The table class
-            # shared prefixes register under comes from the same contracts.
-            self._share_cls = lm.prefix_table_class(cfg)
-            self._prefix = (PrefixIndex(self.block_size)
-                            if prefix_cache and self.blocks is not None
-                            and self._share_cls is not None
-                            and lm.prefix_cache_eligible(cfg) else None)
-            # host-owned block tables, mirrored to device on change
-            self._tables = {c: np.zeros((slots, w), np.int32)
-                            for c, w in self._widths.items()}
-            self._dev_tables = None
-            # the decode attention kernel's walk of each table class:
-            # (W, P table blocks per grid step, window), for the counters
-            kv_item = (1 if cfg.kv_cache_dtype == "i8"
-                       else jnp.dtype(cfg.dtype).itemsize)
-            slab = paged_attn.slab_bytes(cfg.cache_heads, self.block_size,
-                                         cfg.cache_k_dim, kv_item,
-                                         1 if cfg.latent else 2)
-            windows = lm.paged_table_windows(cfg)
-            self._walks = {
-                c: (w, paged_attn.chunk_blocks(w, self.block_size, slab),
-                    windows[c])
-                for c, w in self._widths.items()}
-            self._state = lm.paged_decode_state_spec(
-                cfg, slots, s_max, n_blocks=n_blocks,
-                block_size=self.block_size, abstract=False)
-            self._build_paged_programs()
-        else:
-            # the single source of truth for the resident layout is
-            # lm.decode_state_spec (the same tree the dry-run lowers)
-            self._state = lm.decode_state_spec(cfg, slots, s_max,
-                                               abstract=False,
-                                               per_slot_pos=True)
-            self._dense_prefill_lens: set[int] = set()
-            self._prefix = None
-            self._share_cls = None
+        self.block_size = block_size or cfg.block_size
+        self.prefill_chunk = prefill_chunk or cfg.prefill_chunk
+        self._widths = lm.paged_table_widths(cfg, s_max, self.block_size,
+                                             self.prefill_chunk)
+        per_slot_worst = sum(self._widths.values())
+        if n_blocks <= 0:
+            # default: enough for every slot at full table width
+            # (callers shrink n_blocks to oversubscribe slots)
+            n_blocks = 1 + slots * max(per_slot_worst, 1)
+        self.n_blocks = n_blocks
+        self.blocks = BlockPool(n_blocks) if self._widths else None
+        self.stats.blocks_total = n_blocks - 1 if self.blocks else 0
+        # prefix caching (DESIGN.md §15): only for archs whose whole
+        # sequential state is reconstructible from the paged pools —
+        # prefix_cache_eligible is fail-closed over each kind's declared
+        # prefix_shareable contract flag (recurrent carries and local
+        # window *rings* don't declare it).  The table class shared
+        # prefixes register under comes from the same contracts.
+        self._share_cls = lm.prefix_table_class(cfg)
+        self._prefix = (PrefixIndex(self.block_size)
+                        if prefix_cache and self.blocks is not None
+                        and self._share_cls is not None
+                        and lm.prefix_cache_eligible(cfg) else None)
+        # host-owned block tables, mirrored to device on change
+        self._tables = {c: np.zeros((slots, w), np.int32)
+                        for c, w in self._widths.items()}
+        self._dev_tables = None
+        # the decode attention kernel's walk of each table class:
+        # (W, P table blocks per grid step, window), for the counters
+        kv_item = (1 if cfg.kv_cache_dtype == "i8"
+                   else jnp.dtype(cfg.dtype).itemsize)
+        slab = paged_attn.slab_bytes(cfg.cache_heads, self.block_size,
+                                     cfg.cache_k_dim, kv_item,
+                                     1 if cfg.latent else 2)
+        windows = lm.paged_table_windows(cfg)
+        self._walks = {
+            c: (w, paged_attn.chunk_blocks(w, self.block_size, slab),
+                windows[c])
+            for c, w in self._widths.items()}
+        self._state = lm.paged_decode_state_spec(
+            cfg, slots, s_max, n_blocks=n_blocks,
+            block_size=self.block_size, abstract=False)
+        self._build_paged_programs()
         if device is not None:
             self._state = jax.device_put(self._state, device)
         # host-side mirrors of the device batch (tiny, moved every step)
         self._tokens = np.zeros((slots, 1), np.int32)
         self._active = np.zeros((slots,), bool)
-        # slots mid-chunked-prefill: slot -> _PrefillProgress (paged only;
-        # dense prefill is a single exact-length program, nothing to slice)
+        # slots mid-chunked-prefill: slot -> _PrefillProgress
         self._prefilling: dict[int, _PrefillProgress] = {}
         # memoized FIFO-head prefix plan: ((rid, index generation), plan)
         self._plan_cache: tuple[tuple[int, int], tuple] | None = None
@@ -338,7 +282,7 @@ class ServeEngine:
             raise ValueError(
                 f"request {request.rid} needs {need} cache positions, "
                 f"engine capacity is s_max={self.s_max}")
-        if self.paged and self.blocks is not None:
+        if self.blocks is not None:
             nb = self._blocks_needed(request.prompt.shape[0],
                                      request.max_new_tokens)
             if nb > self.blocks.capacity:
@@ -362,19 +306,18 @@ class ServeEngine:
             self.pool.evict(slot)
             self._active[slot] = False
             self._tokens[slot] = 0   # dead slots feed a constant token id
-            if self.paged:
-                # eviction returns every block the request held; the zeroed
-                # table row routes the dead slot's frozen re-writes to the
-                # trash block so reallocated blocks are never corrupted.
-                # Cached blocks (registered below / during prefill) park in
-                # the pool's idle tier instead of freeing.
-                if self.blocks is not None:
-                    if self._prefix is not None:
-                        self._register_finished(session, slot)
-                    self.blocks.free(session.request.rid)
-                for t in self._tables.values():
-                    t[slot, :] = 0
-                self._dev_tables = None
+            # eviction returns every block the request held; the zeroed
+            # table row routes the dead slot's frozen re-writes to the
+            # trash block so reallocated blocks are never corrupted.
+            # Cached blocks (registered below / during prefill) park in
+            # the pool's idle tier instead of freeing.
+            if self.blocks is not None:
+                if self._prefix is not None:
+                    self._register_finished(session, slot)
+                self.blocks.free(session.request.rid)
+            for t in self._tables.values():
+                t[slot, :] = 0
+            self._dev_tables = None
 
     def _register_finished(self, session: Session, slot: int) -> None:
         """Register the request's full written blocks on release — prompt
@@ -399,7 +342,7 @@ class ServeEngine:
     def _ctx_for(self, req: Request):
         if req.ctx is not None:
             ctx = jnp.asarray(np.asarray(req.ctx)[None])
-            if self.paged and self.cfg.is_encdec():
+            if self.cfg.is_encdec():
                 # encode once at admission; chunks consume the frames
                 ctx = self._encode_program(self.params, ctx)
             return ctx
@@ -409,9 +352,9 @@ class ServeEngine:
                 f"{req.rid} has none")
         return None
 
-    def _post_prefill(self, session: Session, slot: int, tok) -> bool:
-        """Record the prefill-sampled token; returns True when the request
-        survives into the decode batch."""
+    def _post_prefill(self, session: Session, slot: int, tok) -> None:
+        """Record the prefill-sampled token; the request joins the decode
+        batch unless that token already finishes it."""
         with TraceAnnotation("serve.prefill.sync", rid=session.request.rid):
             t = int(np.asarray(tok)[0, 0])
         session.tokens.append(t)
@@ -419,13 +362,11 @@ class ServeEngine:
         session.step_first = self._step_idx
         if self.eos_id is not None and t == self.eos_id:
             self._finish(session, "eos")
-            return False
-        if session.request.max_new_tokens == 1:
+        elif session.request.max_new_tokens == 1:
             self._finish(session, "length")
-            return False
-        self._tokens[slot, 0] = t
-        self._active[slot] = True
-        return True
+        else:
+            self._tokens[slot, 0] = t
+            self._active[slot] = True
 
     def resident_devices(self) -> set:
         """Devices holding any leaf of the resident params or decode state."""
@@ -439,12 +380,6 @@ class ServeEngine:
 
     # -- session migration (DESIGN.md §17) -----------------------------------
 
-    def _require_paged(self, what: str) -> None:
-        if not self.paged:
-            raise RuntimeError(
-                f"{what} requires the block-paged layout: the dense layout "
-                "has no per-slot block addressing to extract state through")
-
     def export_session(self, rid: int) -> dict:
         """Lift a live admitted session out of the engine as a flat wire
         tree: paged KV blocks gathered through the slot's table rows,
@@ -455,7 +390,6 @@ class ServeEngine:
         (cfg, engine geometry, request) only, so the destination can derive
         the restore spec via :meth:`export_spec` without trusting the wire.
         """
-        self._require_paged("export_session")
         session = self.sessions[rid]
         slot = session.slot
         if slot is None or slot not in self.pool.active:
@@ -493,7 +427,6 @@ class ServeEngine:
         engine's geometry — the ``like`` tree the migration checkpoint is
         restored against (shapes come from (cfg, geometry, request), never
         from the stored file)."""
-        self._require_paged("export_spec")
         spec = {
             "meta": jax.ShapeDtypeStruct((8,), np.int64),
             "times": jax.ShapeDtypeStruct((5,), np.float64),
@@ -514,7 +447,6 @@ class ServeEngine:
         still in flight, just not here).  Blocks this request's prefill
         already registered as a donor stay cached: their pool contents are
         untouched by release, so the index's content promise still holds."""
-        self._require_paged("release_migrated")
         session = self.sessions.pop(rid)
         slot = session.slot
         self._prefilling.pop(slot, None)
@@ -538,7 +470,6 @@ class ServeEngine:
         same (cfg, s_max, block_size, prefill_chunk, temperature, seed) as
         the source; geometry that differs only in slots/n_blocks is fine
         (the schedule-independent (rid, step) seed contract)."""
-        self._require_paged("import_session")
         rid = request.rid
         if rid in self.sessions:
             raise ValueError(f"duplicate request id {rid}")
@@ -609,7 +540,6 @@ class ServeEngine:
     def gather_block(self, bid: int):
         """Host copy of physical block ``bid`` across every shared pool —
         the scrubber's unit of verification for idle cached blocks."""
-        self._require_paged("gather_block")
         out = self._gather_block_program(self._state, jnp.int32(bid))
         return jax.tree.map(np.asarray, out)
 
@@ -680,7 +610,7 @@ class ServeEngine:
             self._plan_cache = (tag, self._prefix_plan(req))
         return self._plan_cache[1]
 
-    def _admissible_paged(self) -> tuple | None:
+    def _admissible(self) -> tuple | None:
         """The FIFO head's prefix plan when it can be admitted, else None.
         OOM backpressure gates on *fresh* blocks needed (shared blocks are
         free) against free + evictable-idle — the head waits, no skipping
@@ -714,8 +644,8 @@ class ServeEngine:
         return {c: jnp.asarray(t[slot:slot + 1])
                 for c, t in self._tables.items()}
 
-    def _admit_paged(self) -> None:
-        """Admission under the block-paged layout: map the request's shared
+    def _admit(self) -> None:
+        """Fill free slots from the queue: map the request's shared
         prefix blocks read-only, reserve fresh blocks for the remainder
         (evicting idle cached blocks LRU-first under pressure), COW the
         divergence block if the first write would land in shared cache, and
@@ -726,7 +656,7 @@ class ServeEngine:
         blocking it head-of-line."""
         while (head := self.pool.peek()) is not None and self.pool.free_slots:
             with TraceAnnotation("serve.admit", rid=head.request.rid):
-                plan = self._admissible_paged()
+                plan = self._admissible()
                 if plan is None:
                     return
                 self._admit_head(plan)
@@ -831,28 +761,6 @@ class ServeEngine:
                 del self._prefilling[slot]
                 self._post_prefill(prog.session, slot, tok)
 
-    def _admit(self) -> None:
-        """Fill every free slot from the queue (prefill + scatter insert)."""
-        if self.paged:
-            return self._admit_paged()
-        while self.pool.admissible():
-            session, slot = self.pool.admit()
-            req = session.request
-            with TraceAnnotation("serve.admit", rid=req.rid):
-                session.t_admit = time.monotonic()
-                tokens = jnp.asarray(req.prompt[None, :], jnp.int32)
-                ctx = self._ctx_for(req)
-                seeds = jnp.asarray([self._seed_for(req.rid, 0)], jnp.int32)
-                self._dense_prefill_lens.add(req.prompt.shape[0])
-                self.stats.prefill_traces = len(self._dense_prefill_lens)
-                tok, one = _prefill_program(
-                    self.cfg, self.params, tokens, ctx, self._key, seeds,
-                    s_max=self.s_max, temperature=self.temperature)
-                self.stats.prefills += 1
-                if self._post_prefill(session, slot, tok):
-                    self._state = _insert_program(self._state, one,
-                                                  jnp.int32(slot))
-
     def _device_tables(self) -> dict:
         if self._dev_tables is None:
             self._dev_tables = {c: jnp.asarray(t)
@@ -875,10 +783,8 @@ class ServeEngine:
         byte floor (``roofline_bytes``), ``achieved_bytes`` and the jaxpr
         ``dispatches`` count.  The serve benchmarks render this via
         ``report.serve_decode_row``; the fused/unfused comparison is the
-        same engine audited under different ``cfg.fused_decode`` settings.
+        same engine audited under each ``REPRO_FUSED_DECODE`` setting.
         """
-        if not self.paged:
-            raise ValueError("decode_roofline needs the paged layout")
         from repro.roofline import analysis
         cfg, temperature = self.cfg, self.temperature
 
@@ -920,7 +826,7 @@ class ServeEngine:
                         for s in rows.values()], np.int64)
         st.decode_rows += len(rows)
         st.decode_ctx_tokens += int(ctx.sum())
-        if not self.paged or self.blocks is None:
+        if self.blocks is None:
             return
         for w, p, window in self._walks.values():
             read, live = paged_attn.walk_blocks(
@@ -951,21 +857,13 @@ class ServeEngine:
             for slot, sess in active_sessions.items():
                 seeds[slot] = self._seed_for(sess.request.rid,
                                              len(sess.tokens))
-            if self.paged:
-                program = self._paged_decode_program
-                args = (*self._decode_args()[:-1], jnp.asarray(seeds))
-            else:
-                program = functools.partial(_decode_program, self.cfg,
-                                            temperature=self.temperature)
-                args = (self.params, jnp.asarray(self._tokens), self._state,
-                        jnp.asarray(self._active), self._key,
-                        jnp.asarray(seeds))
+            args = (*self._decode_args()[:-1], jnp.asarray(seeds))
         if self._turn_ns is not None:
             self.stats.host_turns += 1
             self.stats.host_turn_ns += (self._turn_ns + time.perf_counter_ns()
                                         - self._t_mark)
         with TraceAnnotation("serve.decode.dispatch"):
-            toks, self._state, *counts = program(*args)
+            toks, self._state, *counts = self._paged_decode_program(*args)
         self.stats.decode_steps += 1
         self._count_decode(active_sessions)       # while the device runs
         with TraceAnnotation("serve.decode.sync"):

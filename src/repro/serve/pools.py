@@ -62,9 +62,6 @@ class SlotPool:
     def active(self) -> dict[int, Session]:
         return dict(self._active)
 
-    def admissible(self) -> bool:
-        return bool(self._queue) and bool(self._free)
-
     def admit(self) -> tuple[Session, int]:
         """Pop the oldest queued session into the lowest free slot."""
         if not self._queue:

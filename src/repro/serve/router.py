@@ -93,7 +93,7 @@ class IntegrityScrubber:
             bad += sum(1 for k, v in digs.items()
                        if v != self._weight_baseline[k])
         st.scrub_weight_leaves += len(digs)
-        if eng.paged and eng.blocks is not None:
+        if eng.blocks is not None:
             idle = set(eng.blocks.idle_blocks)
             for bid in list(self._block_baseline):
                 if bid not in idle:
@@ -229,8 +229,7 @@ class Router:
             dev = mesh.devices.flat[0]
             eng = ServeEngine(cfg, resident, slots=slots, s_max=s_max,
                               eos_id=eos_id, temperature=temperature,
-                              seed=seed, pack=pack, paged=True,
-                              block_size=block_size,
+                              seed=seed, pack=pack, block_size=block_size,
                               prefill_chunk=prefill_chunk,
                               n_blocks=n_blocks,
                               prefix_cache=prefix_cache, device=dev)

@@ -19,13 +19,9 @@ from repro.serve.session import Session
 class EngineStats:
     """Engine-side counters, including block-pool occupancy (peak / mean
     blocks in use) so benchmarks can report memory utilization alongside
-    tok/s.  ``prefill_traces`` counts the distinct prefill programs this
-    engine demanded: actual compilations of the paged engine's per-engine
-    chunk program (pinned to exactly 1 for any mix of prompt lengths), vs
-    one per distinct prompt length on the dense path (whose module-level
-    jit cache may already hold some of them from an earlier engine in the
-    same process — the count is this engine's shape demand, not a process
-    compile count)."""
+    tok/s.  ``prefill_traces`` counts compilations of the engine's
+    per-engine chunk program (pinned to exactly 1 for any mix of prompt
+    lengths)."""
 
     decode_steps: int = 0
     prefills: int = 0
@@ -54,15 +50,15 @@ class EngineStats:
     # decode dispatch, while no decode program is queued
     host_turns: int = 0
     host_turn_ns: int = 0
-    blocks_total: int = 0       # allocatable blocks (0: dense layout)
+    blocks_total: int = 0       # allocatable blocks (0: no KV cache)
     blocks_in_use: int = 0
     blocks_peak: int = 0
-    # prefix caching (DESIGN.md §15; all zero when disabled / dense)
+    # prefix caching (DESIGN.md §15; all zero when disabled)
     cow_copies: int = 0             # divergence-block copy-on-write copies
     prefix_hits: int = 0            # admissions that mapped >= 1 shared block
     prefix_shared_blocks: int = 0   # total blocks mapped read-only
     prefix_tokens: int = 0          # prompt tokens skipped via the cache
-    prompt_tokens: int = 0          # prompt tokens admitted (paged path)
+    prompt_tokens: int = 0          # prompt tokens admitted
     fresh_blocks: int = 0           # blocks newly allocated at admission
     prefix_evictions: int = 0       # cached blocks reclaimed under pressure
     prefix_cached_blocks: int = 0   # current index size (registered blocks)
@@ -99,7 +95,7 @@ class EngineStats:
 
     @property
     def block_utilization(self) -> float:
-        """Mean fraction of the pool in use (0 when dense)."""
+        """Mean fraction of the pool in use (0 with no KV cache)."""
         if not self.blocks_total:
             return 0.0
         return self.blocks_mean / self.blocks_total

@@ -1,11 +1,6 @@
-"""Serving programs lowered by the dry-run and used by examples/serve.py:
-
-  prefill_step — consume a full prompt, build the resident decode state.
-  decode_step  — one token for the whole batch against resident state.
-  sample       — greedy / temperature sampling from the last-token logits.
-  generate     — compatibility wrapper over the continuous-batching engine
-                 (:mod:`repro.serve`): the historical static-batch API,
-                 now served by the same jitted slot-pool decode program.
+"""``generate``: the historical static-batch generation API, kept as a
+compatibility wrapper over the continuous-batching engine
+(:mod:`repro.serve`), which serves it on its paged programs.
 """
 
 from __future__ import annotations
@@ -14,58 +9,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.models import lm
-
-
-def prefill_step(cfg, params, batch: dict, s_max: int, q_chunk: int = 0):
-    """batch: {tokens (B, S), [ctx]} -> (first sampled token, DecodeState)."""
-    logits, state = lm.prefill(cfg, params, batch["tokens"],
-                               batch.get("ctx"), s_max=s_max,
-                               q_chunk=q_chunk)
-    return logits, state
-
-
-def decode_step(cfg, params, token: jnp.ndarray, state: lm.DecodeState):
-    """token (B, 1) -> (logits (B, 1, V), state)."""
-    return lm.decode_step(cfg, params, token, state)
-
-
-def sample(logits: jnp.ndarray, key=None, temperature: float = 0.0):
-    if temperature <= 0.0:
-        return jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
-    g = jax.random.gumbel(key, logits[:, -1].shape, jnp.float32)
-    return jnp.argmax(logits[:, -1] / temperature + g, axis=-1).astype(
-        jnp.int32)[:, None]
-
-
-def generate_static(cfg, params, prompt: jnp.ndarray, n_new: int,
-                    ctx: jnp.ndarray | None = None, temperature: float = 0.0,
-                    key=None):
-    """The pre-engine static-batch loop, preserved verbatim: batch prefill +
-    eager per-token decode, uniform shapes, jit dispatch per step.  This is
-    the baseline ``benchmarks/serve_throughput.py`` and ``launch/serve.py
-    --static`` measure the engine against — :func:`generate` itself now
-    routes through the engine, so an A/B against it would be engine vs
-    engine.
-
-    Logits are sliced to the true vocab (the table is padded to
-    256-multiples for TP; pad ids must never be sampled)."""
-    s_max = prompt.shape[1] + n_new
-    batch = {"tokens": prompt}
-    if ctx is not None:
-        batch["ctx"] = ctx
-    logits, state = prefill_step(cfg, params, batch, s_max=s_max)
-    logits = logits[..., :cfg.vocab]
-    tok = sample(logits, key, temperature)
-    out = [tok]
-    for i in range(n_new - 1):
-        if key is not None:
-            key = jax.random.fold_in(key, i)
-        logits, state = decode_step(cfg, params, tok, state)
-        tok = sample(logits[..., :cfg.vocab], key, temperature)
-        out.append(tok)
-    return jnp.concatenate(out, axis=1)
-
 
 def generate(cfg, params, prompt: jnp.ndarray, n_new: int,
              ctx: jnp.ndarray | None = None, temperature: float = 0.0,
@@ -73,11 +16,11 @@ def generate(cfg, params, prompt: jnp.ndarray, n_new: int,
     """Greedy/temperature generation — compatibility wrapper.
 
     Each prompt row becomes one engine request (a single-trace B-request
-    run); greedy outputs are token-identical to the historical static loop
-    (per-row math is batch-composition independent).  ``pack=False`` keeps
-    the float sign path for quant archs, matching the old numerics exactly;
-    use :class:`repro.serve.ServeEngine` directly for packed residency and
-    heterogeneous traces.
+    run); greedy outputs are token-identical to the per-request
+    ``lm.prefill`` + ``lm.decode_step`` loop (per-row math is
+    batch-composition independent).  ``pack=False`` keeps the float sign
+    path for quant archs; use :class:`repro.serve.ServeEngine` directly for
+    packed residency and heterogeneous traces.
 
     Logits are sliced to the true vocab inside the engine (the table is
     padded to 256-multiples for TP; pad ids must never be sampled).
@@ -88,12 +31,8 @@ def generate(cfg, params, prompt: jnp.ndarray, n_new: int,
     seed = 0
     if key is not None:
         seed = int(np.asarray(jax.random.randint(key, (), 0, 2**31 - 1)))
-    # paged=False: the compat contract is bit-level fidelity to the old
-    # static loop, so the wrapper stays on the slot-dense layout (chunked
-    # prefill re-chunks recurrences, which is allclose- but not bit-exact).
     eng = ServeEngine(cfg, params, slots=b, s_max=p + n_new,
-                      temperature=temperature, seed=seed, pack=False,
-                      paged=False)
+                      temperature=temperature, seed=seed, pack=False)
     prompt_h = np.asarray(prompt, np.int32)
     ctx_h = None if ctx is None else np.asarray(ctx)
     for i in range(b):

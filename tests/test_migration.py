@@ -36,8 +36,7 @@ def _setup(arch: str):
 
 
 def _mk(cfg, params, **kw):
-    base = dict(slots=2, s_max=48, seed=0, pack=False, paged=True,
-                temperature=0.8)
+    base = dict(slots=2, s_max=48, seed=0, pack=False, temperature=0.8)
     base.update(kw)
     return ServeEngine(cfg, params, **base)
 

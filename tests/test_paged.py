@@ -3,7 +3,8 @@
 
 The contract under test: the paged serve path — shared block pool,
 per-slot block tables, fixed-size chunked prefill — is *token-identical*
-to the slot-dense path for every arch family that caches attention state
+to the per-request reference loop (``lm.prefill`` + ``lm.decode_step``)
+for every arch family that caches attention state
 (dense / local-window / enc-dec / vlm) and for the pure-recurrent archs
 (whose per-slot state stays dense by design) and for MoE archs, whose
 dropless routing makes each token's output its own (softmax-routed GQA,
@@ -30,6 +31,7 @@ import repro.configs as configs
 from repro.models import lm
 from repro.serve import (BlockPool, PrefixIndex, Request, ServeEngine,
                          synthetic_trace)
+from serve_reference import ref_decode, ref_tokens
 
 # dense / local+recurrent / enc-dec / vlm / pure-recurrent / MoE (GQA, and
 # latent attention with held and shared experts) — the identity sweep the
@@ -45,10 +47,10 @@ def _setup(name, **over):
     return cfg, lm.init_params(cfg, key)
 
 
-def _run(cfg, params, trace, *, paged, slots=2, s_max=24, pack=True,
+def _run(cfg, params, trace, *, slots=2, s_max=24, pack=True,
          n_blocks=0, seed=0, temperature=0.0, prefix_cache=True):
     eng = ServeEngine(cfg, params, slots=slots, s_max=s_max, pack=pack,
-                      paged=paged, n_blocks=n_blocks, seed=seed,
+                      n_blocks=n_blocks, seed=seed,
                       temperature=temperature, prefix_cache=prefix_cache)
     for r in trace:
         eng.submit(r)
@@ -93,23 +95,22 @@ def test_block_pool_oom_and_free_reclaims_all():
 
 
 # ---------------------------------------------------------------------------
-# paged == dense token identity (the tentpole contract)
+# paged == reference token identity (the tentpole contract)
 # ---------------------------------------------------------------------------
 
 
 @pytest.mark.parametrize("name", SWEEP_ARCHS)
 def test_paged_matches_dense_tokens(name):
     """Property-style sweep: same seeded mixed-length trace through the
-    slot-dense and block-paged engines -> identical tokens per request
-    (chunked prefill + table gather/scatter vs exact-length prefill +
-    contiguous cache)."""
+    block-paged engine and the per-request reference loop -> identical
+    tokens per request (chunked prefill + table gather/scatter vs
+    exact-length prefill + contiguous cache)."""
     cfg, params = _setup(name)
     trace = synthetic_trace(5, cfg.vocab, seed=2, prompt_lens=(4, 6, 9),
                             new_tokens=(3, 6), n_ctx_tokens=cfg.n_ctx_tokens,
                             d_model=cfg.d_model)
-    dense, _ = _run(cfg, params, trace, paged=False)
-    paged, eng = _run(cfg, params, trace, paged=True)
-    assert dense == paged
+    paged, eng = _run(cfg, params, trace)
+    assert paged == ref_tokens(cfg, params, trace, 24)
     assert eng.stats.prefills == len(trace)
     if eng.blocks is not None:
         assert eng.blocks.in_use == 0        # every eviction returned blocks
@@ -118,56 +119,56 @@ def test_paged_matches_dense_tokens(name):
 
 def test_paged_packed_residency_matches_dense_and_float():
     """Both resident modes run on the paged layout: packed-paged equals
-    float-paged equals packed-dense token-for-token."""
+    float-paged equals the packed reference loop token-for-token."""
     cfg, params = _setup("qwen2-7b+xnor")
     trace = synthetic_trace(4, cfg.vocab, seed=6, prompt_lens=(4, 7),
                             new_tokens=(3, 5))
-    dense_packed, _ = _run(cfg, params, trace, paged=False, pack=True)
-    paged_packed, _ = _run(cfg, params, trace, paged=True, pack=True)
-    paged_float, _ = _run(cfg, params, trace, paged=True, pack=False)
-    assert dense_packed == paged_packed == paged_float
+    ref_packed = ref_tokens(cfg, lm.pack_params(cfg, params), trace, 24)
+    paged_packed, _ = _run(cfg, params, trace, pack=True)
+    paged_float, _ = _run(cfg, params, trace, pack=False)
+    assert ref_packed == paged_packed == paged_float
 
 
 @pytest.mark.parametrize("name", ["qwen3-4b", "whisper-tiny"])
 def test_paged_matches_dense_i8_cache(name):
-    """The fixed-point i8 cache runs on both layouts and stays identical —
-    including enc-dec, whose dense resident self-cache must be allocated
-    i8 for _kv_from_seq's scaled words to be decoded with the correction."""
+    """The fixed-point i8 cache runs on the paged layout and in the
+    reference loop and stays identical — including enc-dec, whose
+    reference self-cache must be allocated i8 for _kv_from_seq's scaled
+    words to be decoded with the correction."""
     cfg, params = _setup(name)
     cfg = dataclasses.replace(cfg, kv_cache_dtype="i8")
     trace = synthetic_trace(4, cfg.vocab, seed=8, prompt_lens=(4, 7),
                             new_tokens=(3, 5), n_ctx_tokens=cfg.n_ctx_tokens,
                             d_model=cfg.d_model)
-    dense, deng = _run(cfg, params, trace, paged=False)
-    paged, peng = _run(cfg, params, trace, paged=True)
-    assert dense == paged
-    for eng in (deng, peng):
-        kv = jax.tree.leaves(eng._state.seg_states)[0]
+    paged, eng = _run(cfg, params, trace)
+    ref = {r.rid: ref_decode(cfg, params, r, 24) for r in trace}
+    assert paged == {rid: toks for rid, (toks, _) in ref.items()}
+    for state in [eng._state] + [st for _, st in ref.values()]:
+        kv = jax.tree.leaves(state.seg_states)[0]
         assert kv.dtype == jnp.int8
 
 
 def test_paged_moe_deterministic_across_slot_counts():
-    """Dropless MoE is schedule-independent and layout-independent:
-    identical tokens whatever the slot count, greedy and sampled, and the
-    same on the slot-dense layout."""
+    """Dropless MoE is schedule-independent: identical tokens whatever the
+    slot count, greedy and sampled."""
     cfg, params = _setup("llama4-scout-17b-a16e")
     trace_args = dict(seed=3, prompt_lens=(4, 6, 9), new_tokens=(3, 5))
 
-    def run(slots, temperature, paged=True):
+    def run(slots, temperature):
         trace = synthetic_trace(5, cfg.vocab, **trace_args)
-        toks, _ = _run(cfg, params, trace, paged=paged, slots=slots,
+        toks, _ = _run(cfg, params, trace, slots=slots,
                        temperature=temperature, seed=11)
         return toks
 
-    assert run(1, 0.0) == run(2, 0.0) == run(4, 0.0) == run(2, 0.0, False)
-    assert run(1, 0.7) == run(3, 0.7) == run(3, 0.7, False)
+    assert run(1, 0.0) == run(2, 0.0) == run(4, 0.0)
+    assert run(1, 0.7) == run(3, 0.7)
 
 
 def test_paged_local_window_ring_recycles_blocks():
     """A prompt much longer than the window: the ring holds only
     ceil((window + C - 1) / bs) blocks however long the prompt — blocks
     that fall out of the window are recycled, never accumulated — and the
-    tokens still match the dense rolling-buffer path."""
+    tokens still match the reference loop's rolling buffer."""
     cfg, params = _setup("recurrentgemma-2b", local_window=8)
     widths = lm.paged_table_widths(cfg, 32, cfg.block_size,
                                    cfg.prefill_chunk)
@@ -179,9 +180,8 @@ def test_paged_local_window_ring_recycles_blocks():
                      max_new_tokens=5),
              Request(rid=1, prompt=rng.integers(0, cfg.vocab, 23),
                      max_new_tokens=4)]
-    dense, _ = _run(cfg, params, trace, paged=False, s_max=32)
-    paged, eng = _run(cfg, params, trace, paged=True, s_max=32)
-    assert dense == paged
+    paged, eng = _run(cfg, params, trace, s_max=32)
+    assert paged == ref_tokens(cfg, params, trace, 32)
     # 2 slots x 2-block ring is the whole worst case, prompt length be damned
     assert eng.stats.blocks_peak <= 2 * widths["win"]
 
@@ -193,27 +193,24 @@ def test_paged_local_window_ring_recycles_blocks():
 
 def test_chunked_prefill_traces_one_program():
     """A mixed-length trace compiles exactly one prefill program and one
-    decode program under the paged engine; the dense engine traces prefill
-    once per distinct prompt length."""
+    decode program."""
     cfg, params = _setup("qwen3-4b")
     trace = synthetic_trace(6, cfg.vocab, seed=4, prompt_lens=(3, 5, 9, 11),
                             new_tokens=(2, 4))
     lens = {r.prompt.shape[0] for r in trace}
     assert len(lens) >= 3                    # the mix is genuinely mixed
-    _, eng = _run(cfg, params, trace, paged=True)
+    _, eng = _run(cfg, params, trace)
     assert eng.stats.prefill_traces == 1
     assert eng.stats.decode_traces == 1
     assert eng.stats.prefill_chunks >= eng.stats.prefills
-    _, dense_eng = _run(cfg, params, trace, paged=False)
-    assert dense_eng.stats.prefill_traces == len(lens)
 
 
 def test_chunked_prefill_interleaves_with_decode():
     """A long prompt admitted beside a decoding request is consumed one
     chunk per engine step — the decode batch advances between chunks
-    instead of stalling head-of-line — and tokens still match the dense
-    engine (the mid-prefill slot rides the decode batch inertly: recurrent
-    state frozen, KV writes trash-routed)."""
+    instead of stalling head-of-line — and tokens still match the
+    reference loop (the mid-prefill slot rides the decode batch inertly:
+    recurrent state frozen, KV writes trash-routed)."""
     from repro.serve import Request
 
     cfg, params = _setup("recurrentgemma-2b")       # recurrent + local attn
@@ -225,9 +222,7 @@ def test_chunked_prefill_interleaves_with_decode():
              Request(rid=1, prompt=rng.integers(0, cfg.vocab, long_p),
                      max_new_tokens=3)]
     s_max = long_p + 8
-    dense, _ = _run(cfg, params, trace, paged=False, s_max=s_max)
-
-    eng = ServeEngine(cfg, params, slots=2, s_max=s_max, paged=True)
+    eng = ServeEngine(cfg, params, slots=2, s_max=s_max)
     for r in trace:
         eng.submit(r)
     eng.step()
@@ -245,7 +240,7 @@ def test_chunked_prefill_interleaves_with_decode():
     while eng.step():
         pass
     paged = {rid: eng.sessions[rid].tokens for rid in eng.sessions}
-    assert paged == dense
+    assert paged == ref_tokens(cfg, params, trace, s_max)
 
 
 def test_paged_oom_backpressure_serializes_and_completes():
@@ -257,8 +252,8 @@ def test_paged_oom_backpressure_serializes_and_completes():
                             new_tokens=(4, 6))
     need = max(-(-(r.prompt.shape[0] + r.max_new_tokens - 1)
                  // cfg.block_size) for r in trace)
-    free_run, _ = _run(cfg, params, trace, paged=True, slots=2)
-    tight, eng = _run(cfg, params, trace, paged=True, slots=2,
+    free_run, _ = _run(cfg, params, trace, slots=2)
+    tight, eng = _run(cfg, params, trace, slots=2,
                       n_blocks=need + 1)
     assert tight == free_run
     assert all(s.done for s in eng.sessions.values())
@@ -273,7 +268,7 @@ def test_paged_oom_backpressure_serializes_and_completes():
 def test_paged_submit_rejects_impossible_request():
     cfg, params = _setup("qwen3-4b")
     from repro.serve import Request
-    eng = ServeEngine(cfg, params, slots=1, s_max=64, paged=True,
+    eng = ServeEngine(cfg, params, slots=1, s_max=64,
                       n_blocks=3)
     with pytest.raises(ValueError, match="blocks"):
         eng.submit(Request(rid=0, prompt=np.arange(30), max_new_tokens=20))
@@ -321,7 +316,7 @@ def test_report_ttft_and_queue_wait_quantiles():
     cfg, params = _setup("qwen3-4b")
     trace = synthetic_trace(3, cfg.vocab, seed=1, prompt_lens=(4, 6),
                             new_tokens=(3,))
-    eng = ServeEngine(cfg, params, slots=1, s_max=16, paged=True)
+    eng = ServeEngine(cfg, params, slots=1, s_max=16)
     for r in trace:
         eng.submit(r)
     report = eng.run()
@@ -483,8 +478,8 @@ def test_prefix_sharing_identity_sweep(name):
     recurrent/window-ring archs take the documented disabled path."""
     cfg, params = _setup(name)
     trace = _shared_trace(cfg)
-    off, _ = _run(cfg, params, trace, paged=True, prefix_cache=False)
-    on, eng = _run(cfg, params, trace, paged=True, prefix_cache=True)
+    off, _ = _run(cfg, params, trace, prefix_cache=False)
+    on, eng = _run(cfg, params, trace, prefix_cache=True)
     assert on == off
     assert eng.prefix_caching == ELIGIBLE[name]
     if ELIGIBLE[name]:
@@ -508,11 +503,11 @@ def test_prefix_sharing_identity_packed_residency():
     packed-on == packed-off == float-off."""
     cfg, params = _setup("qwen2-7b+xnor")
     trace = _shared_trace(cfg, seed=21)
-    packed_off, _ = _run(cfg, params, trace, paged=True, pack=True,
+    packed_off, _ = _run(cfg, params, trace, pack=True,
                          prefix_cache=False)
-    packed_on, eng = _run(cfg, params, trace, paged=True, pack=True,
+    packed_on, eng = _run(cfg, params, trace, pack=True,
                           prefix_cache=True)
-    float_off, _ = _run(cfg, params, trace, paged=True, pack=False,
+    float_off, _ = _run(cfg, params, trace, pack=False,
                         prefix_cache=False)
     assert packed_on == packed_off == float_off
     assert eng.stats.prefix_hits > 0
@@ -526,8 +521,8 @@ def test_prefix_sharing_identity_i8_cache(name):
     cfg, params = _setup(name)
     cfg = dataclasses.replace(cfg, kv_cache_dtype="i8")
     trace = _shared_trace(cfg, seed=8)
-    off, _ = _run(cfg, params, trace, paged=True, prefix_cache=False)
-    on, eng = _run(cfg, params, trace, paged=True, prefix_cache=True)
+    off, _ = _run(cfg, params, trace, prefix_cache=False)
+    on, eng = _run(cfg, params, trace, prefix_cache=True)
     assert on == off
     assert eng.stats.prefix_hits > 0
 
@@ -541,7 +536,7 @@ def test_prefix_sharing_moe_deterministic_replay():
 
     def run(prefix_cache=True):
         toks, eng = _run(cfg, params, _shared_trace(cfg, seed=3),
-                         paged=True, slots=2, prefix_cache=prefix_cache)
+                         slots=2, prefix_cache=prefix_cache)
         return toks, eng
 
     t1, e1 = run()
@@ -559,7 +554,7 @@ def test_prefix_sharing_moe_deterministic_replay():
 
 def _engine(cfg, params, *, slots=1, s_max=40, prefix_cache=True,
             n_blocks=0):
-    return ServeEngine(cfg, params, slots=slots, s_max=s_max, paged=True,
+    return ServeEngine(cfg, params, slots=slots, s_max=s_max,
                        prefix_cache=prefix_cache, n_blocks=n_blocks)
 
 
@@ -648,9 +643,9 @@ def test_prefix_disabled_inside_local_window_ring():
                          [shared, rng.integers(0, cfg.vocab, 3)
                           .astype(np.int32)]),
                      max_new_tokens=4) for i in range(3)]
-    off, _ = _run(cfg, params, trace, paged=True, s_max=32,
+    off, _ = _run(cfg, params, trace, s_max=32,
                   prefix_cache=False)
-    on, eng = _run(cfg, params, trace, paged=True, s_max=32,
+    on, eng = _run(cfg, params, trace, s_max=32,
                    prefix_cache=True)
     assert on == off
     assert not eng.prefix_caching

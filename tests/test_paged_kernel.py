@@ -18,10 +18,10 @@ Two layers of contract:
   (the Pallas kernel on the decode path) produces the same tokens as with
   ``off`` (the unfused chain) across the paged arch families, float and
   packed residency, and the i8 KV cache.  With the env var unset the
-  dispatch itself guarantees bitwise identity on CPU CI (``auto`` resolves
-  to the unfused twin — ``test_fused_mode_resolution``), so the existing
-  cross-layout pins (paged == dense, prefix on == off, migration identity)
-  are untouched in both ``REPRO_KERNEL_IMPL`` modes.
+  dispatch itself guarantees bitwise identity on CPU CI (the platform picks
+  the unfused twin — ``test_fused_mode_resolution``), so the existing
+  token pins (paged == the reference loop, prefix on == off, migration
+  identity) are untouched in both ``REPRO_KERNEL_IMPL`` modes.
 
 Runs in whichever kernel mode CI selects.
 """
@@ -294,18 +294,15 @@ def test_fused_mode_resolution(monkeypatch):
     monkeypatch.delenv("REPRO_FUSED_DECODE", raising=False)
     # with no override, CPU backends keep the bit-exact unfused twin
     if jax.default_backend() != "tpu":
-        assert ops.fused_mode("auto") == "ref"
-    assert ops.fused_mode("off") == "ref"
-    assert ops.fused_mode("unfused") == "ref"
-    assert ops.fused_mode("on") == "kernel"
-    assert ops.fused_mode("fused") == "kernel"
-    with pytest.raises(ValueError):
-        ops.fused_mode("bogus")
-    # env var wins over the config value, and is read per call
+        assert ops.fused_mode() == "ref"
+    # the env var is the one switch, read per call
     monkeypatch.setenv("REPRO_FUSED_DECODE", "on")
-    assert ops.fused_mode("off") == "kernel"
+    assert ops.fused_mode() == "kernel"
     monkeypatch.setenv("REPRO_FUSED_DECODE", "off")
-    assert ops.fused_mode("on") == "ref"
+    assert ops.fused_mode() == "ref"
+    monkeypatch.setenv("REPRO_FUSED_DECODE", "fused")
+    with pytest.raises(ValueError):
+        ops.fused_mode()
 
 
 # ---------------------------------------------------------------------------
@@ -370,11 +367,14 @@ def test_prepacked_layer_fused_mode(monkeypatch):
 
 
 def _engine_tokens(name, monkeypatch, fused, *, pack=False, **over):
-    monkeypatch.setenv("REPRO_FUSED_DECODE", fused)
+    if fused is None:                   # the platform's choice
+        monkeypatch.delenv("REPRO_FUSED_DECODE", raising=False)
+    else:
+        monkeypatch.setenv("REPRO_FUSED_DECODE", fused)
     cfg = configs.get(name).smoke(dtype=jnp.float32, **over)
     key = jax.random.PRNGKey(zlib.crc32(name.encode()) % 2**31)
     params = lm.init_params(cfg, key)
-    eng = ServeEngine(cfg, params, slots=2, s_max=24, pack=pack, paged=True)
+    eng = ServeEngine(cfg, params, slots=2, s_max=24, pack=pack)
     for r in synthetic_trace(4, cfg.vocab, seed=3,
                              n_ctx_tokens=cfg.n_ctx_tokens,
                              d_model=cfg.d_model):
@@ -405,12 +405,12 @@ def test_fused_engine_tokens_i8(monkeypatch):
 
 
 def test_auto_mode_is_bitwise_off_on_cpu(monkeypatch):
-    """The production default: with no override and no TPU, ``auto`` decodes
-    through the identical program as ``off`` — this is what keeps every
-    pre-existing cross-layout token pin bitwise in both CI modes."""
+    """The production default: with no override and no TPU, the engine
+    decodes through the identical program as ``off`` — this is what keeps
+    every token pin bitwise in both CI modes."""
     if jax.default_backend() == "tpu":
-        pytest.skip("auto resolves to the kernel on TPU")
-    auto = _engine_tokens("qwen3-4b", monkeypatch, "auto")
+        pytest.skip("the platform picks the kernel on TPU")
+    auto = _engine_tokens("qwen3-4b", monkeypatch, None)
     off = _engine_tokens("qwen3-4b", monkeypatch, "off")
     assert auto == off
 

@@ -3,7 +3,8 @@
 Every registered kind — present and future — gets the same contract
 coverage for free: state_spec abstract/concrete round-trip, the
 contract-generated paged split/merge inverse, fwd-vs-decode parity, and
-chunk ragged-tail exactness (paged == dense through the real engine).
+chunk ragged-tail exactness (the real engine == the per-request reference
+loop).
 Registration-time validation and the fail-closed prefix gate (a kind that
 doesn't declare ``prefix_shareable`` disables sharing for any arch that
 contains it) are pinned here too.
@@ -25,6 +26,7 @@ from repro.configs.base import ArchConfig
 from repro.models import blocks, lm, registry
 from repro.models.registry import BlockContract
 from repro.serve import ServeEngine, synthetic_trace
+from serve_reference import ref_tokens
 
 KINDS = registry.kinds()   # configs import above registers satellite kinds
 
@@ -125,7 +127,7 @@ def test_fwd_decode_parity(kind):
 def test_chunked_paged_matches_dense(kind):
     """Chunk ragged-tail exactness through the real engine: prompt lengths
     straddling the C=8 chunk size (5, 8, 13) serve token-identically on
-    the paged and dense layouts."""
+    the paged engine and in the reference loop."""
     c = registry.contract(kind)
     if not c.decodes:
         pytest.skip("encoder-only kind never runs the decode path")
@@ -134,15 +136,12 @@ def test_chunked_paged_matches_dense(kind):
                             new_tokens=(3, 5),
                             n_ctx_tokens=cfg.n_ctx_tokens,
                             d_model=cfg.d_model)
-    outs = []
-    for paged in (False, True):
-        eng = ServeEngine(cfg, params, slots=2, s_max=24, paged=paged)
-        for r in trace:
-            eng.submit(r)
-        report = eng.run()
-        outs.append({rid: report.tokens(rid).tolist()
-                     for rid in report.sessions})
-    assert outs[0] == outs[1], kind
+    eng = ServeEngine(cfg, params, slots=2, s_max=24)
+    for r in trace:
+        eng.submit(r)
+    report = eng.run()
+    got = {rid: report.tokens(rid).tolist() for rid in report.sessions}
+    assert got == ref_tokens(cfg, params, trace, 24), kind
 
 
 @pytest.mark.parametrize("kind", KINDS)

@@ -22,6 +22,7 @@ from repro.core.xnor_layers import PackedLinear
 from repro.models import lm
 from repro.models import params as pdefs
 from repro.serve import Request, ServeEngine, Session, SlotPool, synthetic_trace
+from serve_reference import ref_generate
 
 ARCHS = sorted(configs.ALL)
 
@@ -31,21 +32,6 @@ def _setup(name, seed_salt="", **over):
     key = jax.random.PRNGKey(zlib.crc32((name + seed_salt).encode()) % 2**31)
     params = lm.init_params(cfg, key)
     return cfg, params
-
-
-def _ref_generate(cfg, params, req, s_max):
-    """Static per-request greedy reference (eager prefill + decode loop)."""
-    ctx = None if req.ctx is None else jnp.asarray(np.asarray(req.ctx)[None])
-    lg, st = lm.prefill(cfg, params, jnp.asarray(req.prompt[None]), ctx,
-                        s_max=s_max)
-    tok = jnp.argmax(lg[..., :cfg.vocab][:, -1], -1).astype(jnp.int32)[:, None]
-    out = [int(tok[0, 0])]
-    for _ in range(req.max_new_tokens - 1):
-        lg, st = lm.decode_step(cfg, params, tok, st)
-        tok = jnp.argmax(lg[..., :cfg.vocab][:, -1],
-                         -1).astype(jnp.int32)[:, None]
-        out.append(int(tok[0, 0]))
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -66,7 +52,7 @@ def test_slot_pool_fifo_admission_lowest_slot():
     s1, slot1 = pool.admit()
     assert (s0.request.rid, slot0) == (0, 0)
     assert (s1.request.rid, slot1) == (1, 1)
-    assert not pool.admissible()          # full
+    assert pool.peek() is not None and not pool.free_slots   # full
     pool.evict(slot0)
     assert pool.free_slots == [0]
     s2, slot2 = pool.admit()
@@ -128,7 +114,7 @@ def test_engine_matches_static_reference(name):
     report = eng.run()
     assert report.prefills == len(trace)
     for r in trace:
-        want = _ref_generate(cfg, params, r, s_max=24)
+        want = ref_generate(cfg, params, r, s_max=24)
         assert report.tokens(r.rid).tolist() == want, r.rid
         sess = report.sessions[r.rid]
         assert sess.finish_reason == "length"
@@ -147,7 +133,7 @@ def test_engine_single_slot_reuses_and_preserves_order():
     admits = sorted(report.sessions.values(), key=lambda s: s.t_admit)
     assert [s.request.rid for s in admits] == [0, 1, 2]   # FIFO through 1 slot
     for r in trace:
-        assert report.tokens(r.rid).tolist() == _ref_generate(
+        assert report.tokens(r.rid).tolist() == ref_generate(
             cfg, params, r, s_max=16)
 
 
@@ -157,7 +143,7 @@ def test_engine_eos_eviction():
     cfg, params = _setup("qwen3-4b")
     trace = synthetic_trace(4, cfg.vocab, seed=9, prompt_lens=(4, 6, 8),
                             new_tokens=(6,))
-    refs = {r.rid: _ref_generate(cfg, params, r, s_max=20) for r in trace}
+    refs = {r.rid: ref_generate(cfg, params, r, s_max=20) for r in trace}
     eos = refs[0][1]      # second token of request 0 -> it must stop at 2
     eng = ServeEngine(cfg, params, slots=2, s_max=20, eos_id=eos)
     for r in trace:
@@ -256,15 +242,7 @@ def test_generate_wrapper_matches_static_loop():
     got = np.asarray(serve_step.generate(cfg, params, prompt, N))
     for i in range(B):
         req = Request(rid=i, prompt=np.asarray(prompt[i]), max_new_tokens=N)
-        assert got[i].tolist() == _ref_generate(cfg, params, req, s_max=P + N)
-
-
-def test_decode_state_spec_per_slot_pos():
-    cfg = configs.get("qwen3-4b").smoke()
-    st = lm.decode_state_spec(cfg, 3, 16, abstract=True, per_slot_pos=True)
-    assert st.pos.shape == (3,) and st.pos.dtype == jnp.int32
-    st0 = lm.decode_state_spec(cfg, 3, 16, abstract=True)
-    assert st0.pos.shape == ()
+        assert got[i].tolist() == ref_generate(cfg, params, req, s_max=P + N)
 
 
 # ---------------------------------------------------------------------------

@@ -86,20 +86,5 @@ def test_host_turns_leave_out_the_caller_s_time(model):
     assert 0 < eng.stats.host_turn_ns < 0.2e9
 
 
-def test_dense_layout_counts_rows_and_no_blocks(model):
-    cfg, params = model
-    eng = ServeEngine(cfg, params, slots=2, s_max=40, paged=False)
-    for rid, n in ((0, 5), (1, 7)):
-        eng.submit(Request(rid=rid, prompt=np.arange(1, 9, dtype=np.int32)
-                           + rid, max_new_tokens=n))
-    eng.run()
-    st = eng.stats
-    assert st.decode_rows == 4 + 6
-    assert st.decode_ctx_tokens == sum(8 + k for k in range(1, 5)) + \
-        sum(8 + k for k in range(1, 7))
-    assert st.kv_reserved_block_steps == st.kv_live_block_steps == 0
-    assert st.decode_kv_blocks_read == st.decode_kv_blocks_live == 0
-
-
 def test_decode_dispatches_left_the_counters():
     assert "decode_dispatches" not in EngineStats.__dataclass_fields__
